@@ -14,9 +14,13 @@ ENV CEDAR_NATIVE_ARCH=x86-64
 RUN python -c "from cedar_tpu.native.build import ensure_built; print(ensure_built())"
 
 # Stage 2: runtime — jax[cpu] by default; swap the extra for a TPU-enabled
-# jax wheel on TPU node pools (the engine auto-detects the backend).
+# jax wheel on TPU node pools.
 FROM python:3.12-slim
 RUN pip install --no-cache-dir "jax[cpu]" numpy pyyaml
+# This image carries the CPU wheel, so it asks for the CPU plane by name:
+# --backend tpu refuses to start when JAX finds no TPU (it never falls back
+# on its own). A TPU image swaps the wheel above and drops this line.
+ENV JAX_PLATFORMS=cpu
 COPY --from=build /src/cedar_tpu /app/cedar_tpu
 COPY cedarschema/ /app/cedarschema/
 WORKDIR /app

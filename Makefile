@@ -42,8 +42,12 @@ gameday: ## Run a scripted chaos game day (cedar-chaos) against a locally spawne
 	JAX_PLATFORMS=cpu $(PYTHON) -m cedar_tpu.cli.chaos --spawn \
 	    --scenario $${SCENARIO:-kill-decode}
 
+.PHONY: chip-smoke
+chip-smoke: ## The chip check: serve the 10k-policy webhook from one TPU chip and compare every answer with the interpreter (chip_smoke.py; one process holds the chip)
+	$(PYTHON) chip_smoke.py
+
 .PHONY: bench
-bench: ## Run the headline benchmark on the attached device
+bench: ## Run the headline benchmark on the TPU (exits nonzero with a JSON tail when JAX finds none; JAX_PLATFORMS=cpu runs the cpu plane by name)
 	$(PYTHON) bench.py
 
 .PHONY: bench-cache
@@ -55,7 +59,7 @@ bench-pipeline: ## Pipelined vs serial engine: decisions/sec + lone-request p50/
 	JAX_PLATFORMS=cpu $(PYTHON) bench.py --pipeline
 
 .PHONY: bench-steady
-bench-steady: ## Persistent serving loop: e2e >=80% of device-resident rate (hardware), >1 batch in flight + staging occupancy overlap, AOT cold-start-to-warm with zero fresh traces, 1152-body on/off byte differential (device when attached, cpu skip posture otherwise; docs/performance.md)
+bench-steady: ## Persistent serving loop: e2e >=80% of device-resident rate (hardware), >1 batch in flight + staging occupancy overlap, AOT cold-start-to-warm with zero fresh traces, 1152-body on/off byte differential (TPU, or exits nonzero when JAX finds none; JAX_PLATFORMS=cpu runs the cpu plane with the hardware gates reported as skipped; docs/performance.md)
 	$(PYTHON) bench.py --steady
 
 .PHONY: bench-shadow
@@ -119,7 +123,7 @@ bench-trace: ## Observability-plane pay-for-use: unsampled-tracing parity gate +
 	JAX_PLATFORMS=cpu $(PYTHON) bench.py --trace
 
 .PHONY: hw-validate
-hw-validate: ## Measure kernel planes (int8/bf16/pallas/segred) on the attached device
+hw-validate: ## Check the kernel planes (int8/bf16/pallas/segred) compile and agree on the TPU (run it through the chip tool; exits nonzero when JAX finds none)
 	$(PYTHON) tools/hw_validate.py
 
 .PHONY: fuzz-soak
@@ -201,7 +205,7 @@ convert-rbac: ## Convert the cluster's RBAC to Cedar (needs kubeconfig)
 ##@ Demo
 
 .PHONY: demo-server
-demo-server: ## Run the webhook locally against the demo policies
+demo-server: ## Run the webhook locally against the demo policies (CPU plane unless JAX_PLATFORMS says otherwise; --backend tpu never falls back on its own)
 	mkdir -p /tmp/cedar-demo/policies
 	$(PYTHON) -c "import yaml,pathlib; \
 	  docs=[d for p in ('demo/authorization-policy.yaml',) \
@@ -210,7 +214,7 @@ demo-server: ## Run the webhook locally against the demo policies
 	      chr(10).join(d['spec']['content'] for d in docs))"
 	printf 'apiVersion: cedar.k8s.aws/v1alpha1\nkind: StoreConfig\nspec:\n  stores:\n    - type: "directory"\n      directoryStore:\n        path: "/tmp/cedar-demo/policies"\n' \
 	    > /tmp/cedar-demo/config.yaml
-	$(PYTHON) -m cedar_tpu.cli.webhook --config /tmp/cedar-demo/config.yaml \
+	JAX_PLATFORMS=$${JAX_PLATFORMS-cpu} $(PYTHON) -m cedar_tpu.cli.webhook --config /tmp/cedar-demo/config.yaml \
 	    --backend tpu --cert-dir /tmp/cedar-demo/certs
 
 .PHONY: demo-policies

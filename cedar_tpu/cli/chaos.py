@@ -291,7 +291,9 @@ def spawn_server(tmpdir: str, extra_args=()):
     while time.time() < deadline:
         if proc.poll() is not None:
             raise RuntimeError(
-                f"spawned webhook exited rc={proc.returncode} before ready"
+                f"spawned webhook exited rc={proc.returncode} before ready "
+                "(--backend tpu refuses to start without a TPU; set "
+                "JAX_PLATFORMS=cpu to run the game day on the CPU plane)"
             )
         try:
             status, _ = _http("GET", f"{control_url}/readyz", timeout=2.0)

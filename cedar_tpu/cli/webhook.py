@@ -179,12 +179,11 @@ def build_server(args) -> WebhookServer:
             "the fanout tier IS the scale-out layer (each worker may "
             "itself be meshed); pick one"
         )
-    # serving-plane default: the segmented-reduction kernel measurably
-    # wins at serving-chunk batch sizes on the CPU BACKEND (2-6x the
-    # device-side rate at 8-16k rows, BENCH_r05_cpu_backend2 era probes),
+    # serving-plane default: the segmented-reduction kernel wins at
+    # serving-chunk batch sizes on the CPU BACKEND (builders' cpu probes),
     # where the matmul has no MXU and the scan plane's n_groups masked
-    # passes dominate. TPU keeps the scan default until hw_validate's
-    # two-regime numbers justify a flip (docs/Limitations.md). Explicit
+    # passes dominate. TPU keeps the scan default until a benchmark on
+    # the chip justifies a flip (docs/Limitations.md). Explicit
     # CEDAR_TPU_SEGRED always wins; the preference is passed to the
     # engines directly (never via os.environ — a global flip would leak
     # into unrelated engines in the same process).
@@ -1994,6 +1993,23 @@ def make_parser() -> argparse.ArgumentParser:
     return parser
 
 
+def _require_device() -> None:
+    """--backend tpu serves from a TPU or not at all: raise unless JAX's
+    default device is one (jaxenv.require_tpu). ``JAX_PLATFORMS=cpu`` is
+    the operator asking for the CPU plane by name (tests, laptops) and
+    skips the check; the CPU plane is never reached by accident."""
+    from ..jaxenv import cpu_requested, require_tpu
+
+    if cpu_requested():
+        log.info("JAX_PLATFORMS=cpu: serving --backend tpu from the CPU plane")
+        return
+    dev = require_tpu()
+    log.info(
+        "device: platform=%s kind=%s count=%d",
+        dev["platform"], dev["kind"], dev["count"],
+    )
+
+
 def _run_pod_mode(args) -> int:
     """Multi-host pod serving (cedar_tpu/pod): every host of the slice
     runs THIS entry with the same --config and coordinator, its own
@@ -2033,6 +2049,13 @@ def _run_pod_mode(args) -> int:
     try:
         ctx = bootstrap(config)
     except DistributedInitError as e:
+        log.error("pod bring-up refused: %s", e)
+        return 3
+    try:
+        # after bootstrap: jax.devices() before jax.distributed.initialize
+        # would bring the backend up single-process
+        _require_device()
+    except RuntimeError as e:
         log.error("pod bring-up refused: %s", e)
         return 3
 
@@ -2158,8 +2181,18 @@ def main(argv: Optional[List[str]] = None) -> int:
         level=logging.DEBUG if args.verbosity >= 5 else logging.INFO,
         format="%(asctime)s %(name)s %(levelname)s %(message)s",
     )
+    if args.backend == "tpu" or args.pod_num_processes >= 2:
+        from ..jaxenv import configure_compile_cache
+
+        log.info("jax compilation cache: %s", configure_compile_cache())
     if args.pod_num_processes >= 2:
         return _run_pod_mode(args)
+    if args.backend == "tpu":
+        try:
+            _require_device()
+        except RuntimeError as e:
+            log.error("--backend tpu refused: %s", e)
+            return 1
     server = build_server(args)
     server.start()
 

@@ -173,6 +173,9 @@ class SynthCorpus:
     # namespaces this corpus's cluster-local apiGroups — "" keeps every
     # generated byte identical to the single-tenant form
     tenant: str = ""
+    # the policies' Cedar texts, index-aligned with ``policies`` — what a
+    # caller writes to a directory store to serve this corpus from files
+    sources: List[str] = field(default_factory=list, repr=False)
     _tier_cache: Optional[List[PolicySet]] = field(default=None, repr=False)
 
     # ----------------------------------------------------------- policy side
@@ -212,8 +215,11 @@ class SynthCorpus:
         p.policy_id = old.policy_id
         pols = list(self.policies)
         pols[idx] = p
+        srcs = list(self.sources)
+        srcs[idx] = src
         return SynthCorpus(
             policies=pols,
+            sources=srcs,
             params=self.params,
             n=self.n,
             seed=self.seed,
@@ -382,6 +388,7 @@ def synth_corpus(
         probe_index=0,
         probe_effect="permit",
         tenant=tenant,
+        sources=srcs,
     )
 
 

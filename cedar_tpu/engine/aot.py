@@ -254,6 +254,7 @@ def _count(field: str) -> None:
 def _load_aot(name: str, key: str, meta: dict) -> Optional[Callable]:
     """Try to resolve ``key`` from disk. Returns the loaded executable on
     success, None on miss/stale/error (counted + logged)."""
+    import jax
     from jax.experimental import serialize_executable as se
 
     path = _path(name, key)
@@ -281,11 +282,18 @@ def _load_aot(name: str, key: str, meta: dict) -> Optional[Callable]:
         )
         return None
     try:
-        blob, in_tree, out_tree = pickle.loads(payload)
+        blob, in_tree, out_tree, device_ids = pickle.loads(payload)
         # loads the ALREADY-COMPILED executable: no trace (the python
         # kernel body never runs — kernel_trace_count() stays flat) and
-        # no XLA compile, so warm-from-disk cost is IO + linking only
-        return se.deserialize_and_load(blob, in_tree, out_tree)
+        # no XLA compile, so warm-from-disk cost is IO + linking only.
+        # It must load onto the devices it was compiled for: left to its
+        # default, jax binds EVERY visible device and then wants one
+        # argument shard per device on each call.
+        by_id = {d.id: d for d in jax.devices()}
+        return se.deserialize_and_load(
+            blob, in_tree, out_tree,
+            execution_devices=[by_id[i] for i in device_ids],
+        )
     except Exception as e:  # noqa: BLE001 — deserialize failure: fall back
         _count("errors")
         log.warning("aot deserialize failed for %s (%r); recompiling", path, e)
@@ -311,7 +319,10 @@ def _compile_and_export(name, key, meta, jit_fn, args) -> Optional[Callable]:
         return None
     try:
         blob, in_tree, out_tree = se.serialize(compiled)
-        payload = pickle.dumps((blob, in_tree, out_tree))
+        device_ids = [
+            d.id for d in compiled.runtime_executable().local_devices()
+        ]
+        payload = pickle.dumps((blob, in_tree, out_tree, device_ids))
         _write_entry(_path(name, key), meta, payload)
         _count("exports")
     except Exception as e:  # noqa: BLE001 — export is best-effort

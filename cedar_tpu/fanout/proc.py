@@ -257,10 +257,21 @@ def _serve_peers(worker) -> "_PeerServer":
 def _worker_main(worker_id: str, spec: dict, conns, boot_conn) -> None:
     """Spawned-process entry: build the stack, announce the peer port,
     then serve one request lane per pipe until EOF."""
+    # a chip belongs to ONE process, and the parent may hold it: a spawned
+    # worker runs its planes on the CPU platform unless its environment
+    # names another (docs/fleet.md "Cross-host topology")
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     os.environ.setdefault("CEDAR_TPU_WARM_DEFAULT", "off")
     try:
         worker = build_worker_stack(spec, worker_id)
+        import jax
+
+        # WARNING so it shows without a logging config: where a worker's
+        # planes run is the first thing to know about its numbers
+        log.warning(
+            "fanout worker %s (pid %d): planes on jax platform %r",
+            worker_id, os.getpid(), jax.default_backend(),
+        )
         peer_srv = _serve_peers(worker)
         boot_conn.send(("ready", peer_srv.server_address[1]))
     except Exception as e:  # noqa: BLE001 — the parent must see the failure

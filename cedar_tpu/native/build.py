@@ -1,9 +1,11 @@
 """On-demand build of the native encoder library.
 
 Compiles encoder.cpp with the system C++ toolchain into a shared library
-cached under ``cedar_tpu/native/_build/`` keyed by a source hash, so edits
-to the .cpp transparently rebuild and repeated imports are free. No pip
-dependencies: plain g++ (or $CXX) + ctypes."""
+cached under ``cedar_tpu/native/_build/`` keyed by a hash of the source, the
+target arch and — for the default ``-march=native`` — the host CPU's feature
+flags, so edits to the .cpp transparently rebuild, repeated imports are
+free, and a library built on a different machine is never picked up. No
+pip dependencies: plain g++ (or $CXX) + ctypes."""
 
 from __future__ import annotations
 
@@ -30,12 +32,33 @@ def _glue_include() -> str:
     return ""
 
 
+def _host_cpu_id() -> str:
+    """What ``-march=native`` resolves to on THIS host: the CPU's feature
+    flags (Linux) or, failing that, its architecture and model name. Part
+    of the cache key, so a library built on another machine — a copied
+    checkout, a baked image — is never loaded here: it may hold
+    instructions this CPU lacks and die of SIGILL inside the server."""
+    import platform
+
+    try:
+        with open("/proc/cpuinfo") as f:
+            for line in f:
+                if line.startswith(("flags", "Features")):
+                    return " ".join(sorted(line.split(":", 1)[1].split()))
+    except OSError:
+        pass
+    return f"{platform.machine()}|{platform.processor()}"
+
+
 def _source_hash(with_glue: bool) -> str:
     import sysconfig
 
     arch = os.environ.get("CEDAR_NATIVE_ARCH", "native")
     h = hashlib.sha256(_SRC.read_bytes())
     h.update(arch.encode())
+    if arch == "native":
+        h.update(b"cpu:")
+        h.update(_host_cpu_id().encode())
     if with_glue:
         # the glue compiles PyList/PyObject struct-offset macros for THIS
         # interpreter's ABI: key the cache on it so a different

@@ -48,29 +48,10 @@ _TR = 512
 _TK = 2048
 
 
-def _tpu_compiler_params(**kwargs):
-    """Construct the pallas TPU compiler-params object under either API
-    spelling: newer jax exposes ``pltpu.CompilerParams``, older releases
-    ``pltpu.TPUCompilerParams``. Feature-detected (never version-sniffed)
-    so the same wheel works across the drift; unknown fields are dropped
-    rather than raising, since every field we pass is a tuning hint, not a
-    correctness requirement. Returns None when neither class exists —
-    callers then omit compiler_params entirely."""
-    cls = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams", None
-    )
-    if cls is None:
-        return None
-    try:
-        return cls(**kwargs)
-    except TypeError:
-        import dataclasses
-
-        try:
-            names = {f.name for f in dataclasses.fields(cls)}
-        except TypeError:
-            return None
-        return cls(**{k: v for k, v in kwargs.items() if k in names})
+# B tiles are independent; the R and L axes carry the scratch accumulators
+_COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+)
 
 
 def _accum_blocks(
@@ -267,12 +248,6 @@ def pallas_first_match(
     grid = (B // tb, R // tr, L // tk)
     kernel = functools.partial(_kernel, n_groups=n_groups, g_pad=g_pad)
 
-    call_kwargs = {}
-    cp = _tpu_compiler_params(
-        dimension_semantics=("parallel", "arbitrary", "arbitrary"),
-    )
-    if cp is not None:
-        call_kwargs["compiler_params"] = cp
     out, last = pl.pallas_call(
         kernel,
         out_shape=[
@@ -317,7 +292,7 @@ def pallas_first_match(
             transcendentals=0,
         ),
         interpret=interpret,
-        **call_kwargs,
+        compiler_params=_COMPILER_PARAMS,
     )(lit, W, thresh_r, group_r, policy_r)
     return out[:, :n_groups], last[:, :n_groups]
 
@@ -352,12 +327,6 @@ def pallas_match_words(
         has_gate=has_gate,
     )
 
-    call_kwargs = {}
-    cp = _tpu_compiler_params(
-        dimension_semantics=("parallel", "arbitrary", "arbitrary"),
-    )
-    if cp is not None:
-        call_kwargs["compiler_params"] = cp
     words = pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((B, _WORD_LANES), jnp.int32),
@@ -395,7 +364,7 @@ def pallas_match_words(
             transcendentals=0,
         ),
         interpret=interpret,
-        **call_kwargs,
+        compiler_params=_COMPILER_PARAMS,
     )(lit, W, thresh_r, group_r, policy_r)
     return jax.lax.bitcast_convert_type(words[:, 0], jnp.uint32)
 

@@ -18,7 +18,7 @@ tests/test_admission_native.py's AdmissionReview generator (random
 request streams over the demo admission set) through the C++ object walk
 vs the Python handler path.
 
-Runs on the CPU backend regardless of a live device link (the compiler
+Runs on the CPU backend whether or not the host has a chip (the compiler
 and the native encoder — the planes fuzz has caught bugs in — are
 device-independent; the device kernel is exercised identically on cpu).
 """
