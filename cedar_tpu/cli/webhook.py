@@ -879,7 +879,11 @@ def build_server(args) -> WebhookServer:
     # is wired BY DEFAULT at sample rate 0 — the armed-but-unsampled path
     # is bench-gated to parity (make bench-trace), and tail-keep means
     # slow/error/fallback requests land in /debug/traces with zero
-    # configuration exactly when an operator needs them.
+    # configuration exactly when an operator needs them. The request phase
+    # ledger (cedar_request_phase_seconds) and the stall recorder
+    # (obs/stall.py, /debug/stalls) are armed with it: WebhookServer keeps
+    # a phase record per request and starts the recorder only when it is
+    # handed a tracer.
     tracer = None
     if not args.no_trace:
         from ..obs import Tracer
@@ -1856,7 +1860,8 @@ def make_parser() -> argparse.ArgumentParser:
         "--no-trace",
         action="store_true",
         help="disable the tracing plane entirely (no ring, no "
-        "/debug/traces, no per-request span bookkeeping)",
+        "/debug/traces, no per-request span bookkeeping, no request "
+        "phase ledger, no stall recorder and /debug/stalls)",
     )
     obs.add_argument(
         "--audit-log-file",

@@ -36,7 +36,7 @@ import time
 from typing import Callable, List, Optional, Sequence, TypeVar
 
 from ..chaos.registry import chaos_fire
-from ..obs.trace import current_trace
+from ..obs.trace import batch_stage, note_batch_result
 from ..server.supervisor import Heartbeat
 
 log = logging.getLogger(__name__)
@@ -120,12 +120,20 @@ class _StageTimes:
     claimed. ONE source of truth for both the request traces
     (cedar_tpu/obs) and the cedar_pipeline_stage_seconds histograms, so a
     span tree and a dashboard can never disagree about where a batch
-    spent its time. The worker loops only stamp time.monotonic() — all
-    span construction happens later, in the request thread, and only for
-    requests that carry an active trace."""
+    spent its time. The worker loops only stamp time.monotonic() (through
+    obs.trace.batch_stage, which also puts the stage on a running
+    profiler's clock) — all span construction happens later, in the
+    request thread, and only for requests that carry an active trace.
+
+    ``sub`` holds the seconds of the stages inside dispatch and decode
+    (``dispatch.stage`` / ``.launch`` / ``.readback``,
+    ``decode.device_wait`` / ``.host``), split by the obs.trace.sub_stage
+    sites in engine/evaluator.py and engine/fastpath.py while this record
+    is bound to the worker's thread (``part`` / ``part_t0``: the running
+    segment); a stage's parts sum to its window."""
 
     __slots__ = (
-        "claimed", "first_enq",
+        "claimed", "first_enq", "rows", "sub", "part", "part_t0",
         "encode0", "encode1", "dispatch0", "dispatch1",
         "decode0", "decode1", "eval0", "eval1",
     )
@@ -133,6 +141,10 @@ class _StageTimes:
     def __init__(self, claimed: float):
         self.claimed = claimed
         self.first_enq: Optional[float] = None
+        self.rows = 0
+        self.sub: dict = {}
+        self.part: Optional[str] = None
+        self.part_t0 = 0.0
         self.encode0 = self.encode1 = None
         self.dispatch0 = self.dispatch1 = None
         self.decode0 = self.decode1 = None
@@ -421,29 +433,14 @@ class MicroBatcher:
 
     @staticmethod
     def annotate_trace(entry: tuple) -> None:
-        """Attach the entry's batch-stage windows to the calling thread's
-        active request trace (cedar_tpu/obs): queue-wait from the slot's
-        own enqueue stamp, then the claiming batch's encode / dispatch /
-        decode (pipelined) or evaluate (serial) windows — the exact
-        timestamps cedar_pipeline_stage_seconds observed. Runs in the
-        REQUEST thread after the result landed; with tracing disarmed the
-        cost is one thread-local read."""
-        tr = current_trace()
-        if tr is None:
-            return
-        slot = entry[1]
-        times = slot.times
-        if times is None:
-            return  # never claimed (withdrawn / failed before a batch)
-        tr.add_span("batch.queue_wait", slot.t_enq, times.claimed)
-        for name, a, b in (
-            ("batch.encode", times.encode0, times.encode1),
-            ("batch.dispatch", times.dispatch0, times.dispatch1),
-            ("batch.decode", times.decode0, times.decode1),
-            ("batch.evaluate", times.eval0, times.eval1),
-        ):
-            if a is not None and b is not None:
-                tr.add_span(name, a, b)
+        """The request thread has its entry's result: stamp the wake and
+        hand the slot's batch stamps (cedar_tpu/obs pipeline_stamps:
+        queue wait from the slot's own enqueue stamp, then the claiming
+        batch's stages and the waits between them — the exact timestamps
+        cedar_pipeline_stage_seconds observed) to the request's phase
+        record or active trace. With tracing disarmed the cost is two
+        thread-local reads."""
+        note_batch_result(entry[1])
 
     def _record_batch_stages(self, times: "_StageTimes") -> None:
         """Publish one claimed batch's stage windows to the
@@ -467,6 +464,8 @@ class MicroBatcher:
             ):
                 if a is not None and b is not None:
                     record_pipeline_stage(p, stage, b - a)
+            for stage, seconds in times.sub.items():
+                record_pipeline_stage(p, stage, seconds)
         except Exception:  # noqa: BLE001 — metrics must never break serving
             pass
 
@@ -915,15 +914,12 @@ class PipelinedBatcher(MicroBatcher):
 
     def _encode_timed(self, items, times: Optional[_StageTimes]):
         """pipeline_encode with the batch's encode window stamped — the
-        stage traces and histograms read these (two monotonic calls per
-        batch; the encode itself is unchanged)."""
-        if times is not None:
-            times.encode0 = time.monotonic()
-        try:
+        stage traces and histograms read these (the encode itself is
+        unchanged)."""
+        if times is None:
             return self.stages.pipeline_encode(items)
-        finally:
-            if times is not None:
-                times.encode1 = time.monotonic()
+        with batch_stage(times, "encode", len(items)):
+            return self.stages.pipeline_encode(items)
 
     def _stall(self, stage: str, seconds: float) -> None:
         if seconds <= 0:
@@ -1027,16 +1023,13 @@ class PipelinedBatcher(MicroBatcher):
             # time waiting on the encode future = encode stage too slow to
             # keep the device fed
             self._stall("dispatch", time.monotonic() - t0)
-            times = batch[0][1].times
-            times.dispatch0 = time.monotonic()
             try:
-                ctx = self.stages.pipeline_dispatch(ctx)
+                with batch_stage(batch[0][1].times, "dispatch", len(batch)):
+                    ctx = self.stages.pipeline_dispatch(ctx)
             except BaseException as e:  # noqa: BLE001 — per-batch isolation
-                times.dispatch1 = time.monotonic()
                 self._inflight_add(-1, -len(batch))
                 self._fail_batch(batch, e)
                 continue
-            times.dispatch1 = time.monotonic()
             if not self._put(decode_q, (batch, ctx), decoder):
                 self._inflight_add(-1, -len(batch))
                 self._fail_batch(
@@ -1072,18 +1065,16 @@ class PipelinedBatcher(MicroBatcher):
                 return
             batch, ctx = item
             times = batch[0][1].times
-            times.decode0 = time.monotonic()
             # end stamp + histogram BEFORE completing any slot (see the
             # serial loop): a woken waiter reads these stamps immediately
             try:
-                results = self.stages.pipeline_decode(ctx)
-                times.decode1 = time.monotonic()
-                self._record_batch_stages(times)
+                try:
+                    with batch_stage(times, "decode", len(batch)):
+                        results = self.stages.pipeline_decode(ctx)
+                finally:
+                    self._record_batch_stages(times)
                 self._complete_batch(batch, results)
             except BaseException as e:  # noqa: BLE001 — per-batch isolation
-                if times.decode1 is None:
-                    times.decode1 = time.monotonic()
-                    self._record_batch_stages(times)
                 self._fail_batch(batch, e)
             finally:
                 self._inflight_add(-1, -len(batch))

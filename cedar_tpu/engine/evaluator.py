@@ -62,6 +62,7 @@ from ..lang.entities import EntityMap
 from ..lang.eval import Env, Request, policy_matches
 from ..chaos.registry import chaos_fire
 from ..lang.values import EvalError
+from ..obs.trace import sub_stage
 from ..compiler.table import encode_request_codes
 from ..ops.match import (
     CODE_DENY,
@@ -292,11 +293,6 @@ class _WordPacker:
         self._packed = None  # device array after flush
         self._host: Optional[np.ndarray] = None
         self._flushed = False
-
-    @property
-    def parts(self) -> int:
-        """Chunk word arrays registered so far (metrics)."""
-        return len(self._parts)
 
     def add(self, words_dev) -> int:
         """Register one chunk's device word array; returns its part id."""
@@ -1817,91 +1813,112 @@ class TPUPolicyEngine:
         def one(chunk_c, chunk_e, m):
             """-> (words_dev, full_dev_or_None, pack_dev_or_None); m is the
             VALID row count (excludes caller-side staging padding), used
-            only to mask the want_bits compaction."""
+            only to mask the want_bits compaction. Host staging (pad to
+            the bucket, the u8 wire pack) and the launch (the jitted call
+            and the H2D it implies) are timed apart: obs.trace sub_stage
+            `dispatch.stage` — which is also what a dispatch's time counts
+            as outside every sub-stage — and `dispatch.launch`."""
             if cs.mesh is not None:
-                # multi-chip: the pjit step (parallel/mesh.py) shards the
-                # batch over `data` and the rule matmul over `policy`; the
-                # diagnostics bitsets come from the sharded bits step via
-                # resolve_flagged instead of an in-call payload. The
-                # serving (non-full) variant outputs ONLY the packed
-                # word: the per-shard partial verdicts all-reduce on
-                # device and 4 bytes per request come home.
-                chunk_c, chunk_e = self._pad_to_bucket(
-                    chunk_c, chunk_e, packed.L,
-                    data_mult=cs.mesh.shape["data"], held=held,
-                )
-                if self.pod is not None:
-                    # pod regime: broadcast the padded batch so every
-                    # host enters this collective, serialized under the
-                    # pod lock so dispatch order matches fleet-wide
-                    w, full = self.pod.run_match(
-                        self, cs, chunk_c, chunk_e, want_full
+                with sub_stage("dispatch.stage"):
+                    chunk_c, chunk_e = self._pad_to_bucket(
+                        chunk_c, chunk_e, packed.L,
+                        data_mult=cs.mesh.shape["data"], held=held,
                     )
-                    return w, full, None
-                step_args = (
+                with sub_stage("dispatch.launch"):
+                    return mesh_launch(chunk_c, chunk_e)
+            with sub_stage("dispatch.stage"):
+                chunk_c, chunk_e = self._pad_to_bucket(
+                    chunk_c, chunk_e, packed.L, held=held
+                )
+                # want_bits launches stay on the XLA planes: the pallas
+                # kernel has no bits plane, and silently dropping the
+                # in-call compaction payload would buy flagged rows in the
+                # latency regime a SECOND serial device round trip — the
+                # exact cost the in-call plane exists to avoid
+                use_pallas = False
+                if cs.pallas_args is not None and not want_bits:
+                    from ..ops.pallas_match import pallas_supported
+
+                    use_pallas = pallas_supported(
+                        chunk_c.shape[0], packed.L, packed.R
+                    )
+                wire_codes = None
+                if not use_pallas and cs.wire is not None:
+                    try:
+                        wire_codes = cs.pack_wire(chunk_c)
+                    except WireSpanError:
+                        # a span violation means these codes don't fit the
+                        # u8 plan (advisor r5): serve THIS set via the flat
+                        # layout from here on instead of wrapping uint8
+                        # into a wrong activation row. One log; the flat
+                        # kernel is correct, just a fatter transfer.
+                        log.exception(
+                            "u8 wire span violation; disabling the wire "
+                            "layout for this compiled set (flat codes from "
+                            "now on)"
+                        )
+                        cs.wire = None
+            with sub_stage("dispatch.launch"):
+                if use_pallas:
+                    return pallas_launch(chunk_c, chunk_e)
+                return xla_launch(chunk_c, chunk_e, m, wire_codes)
+
+        def mesh_launch(chunk_c, chunk_e):
+            # multi-chip: the pjit step (parallel/mesh.py) shards the
+            # batch over `data` and the rule matmul over `policy`; the
+            # diagnostics bitsets come from the sharded bits step via
+            # resolve_flagged instead of an in-call payload. The
+            # serving (non-full) variant outputs ONLY the packed
+            # word: the per-shard partial verdicts all-reduce on
+            # device and 4 bytes per request come home.
+            if self.pod is not None:
+                # pod regime: broadcast the padded batch so every
+                # host enters this collective, serialized under the
+                # pod lock so dispatch order matches fleet-wide
+                w, full = self.pod.run_match(
+                    self, cs, chunk_c, chunk_e, want_full
+                )
+                return w, full, None
+            step_args = (
+                chunk_c,
+                chunk_e,
+                cs.act_rows_dev,
+                cs.W_dev,
+                cs.thresh_dev,
+                cs.rule_group_dev,
+                cs.rule_policy_dev,
+            )
+            if want_full:
+                w, f, last = self._mesh_step(packed, True)(*step_args)
+                return w, (f, last), None
+            w = self._mesh_step(packed, False)(*step_args)
+            return w, None, None
+
+        def pallas_launch(chunk_c, chunk_e):
+            w, f = aot.dispatch(
+                "pallas",
+                match_rules_codes_pallas,
+                (
                     chunk_c,
                     chunk_e,
                     cs.act_rows_dev,
-                    cs.W_dev,
-                    cs.thresh_dev,
-                    cs.rule_group_dev,
-                    cs.rule_policy_dev,
-                )
-                if want_full:
-                    w, f, last = self._mesh_step(packed, True)(*step_args)
-                    return w, (f, last), None
-                w = self._mesh_step(packed, False)(*step_args)
-                return w, None, None
-            chunk_c, chunk_e = self._pad_to_bucket(
-                chunk_c, chunk_e, packed.L, held=held
+                    *cs.pallas_args,
+                    packed.n_tiers,
+                    want_full,
+                    self._pallas_interpret,
+                    packed.has_gate,
+                ),
+                aot.STATICS["pallas"],
             )
-            B = chunk_c.shape[0]
-            # want_bits launches stay on the XLA planes: the pallas kernel
-            # has no bits plane, and silently dropping the in-call
-            # compaction payload would buy flagged rows in the latency
-            # regime a SECOND serial device round trip — the exact cost
-            # the in-call plane exists to avoid
-            if cs.pallas_args is not None and not want_bits:
-                from ..ops.pallas_match import pallas_supported
+            return w, f, None
 
-                if pallas_supported(B, packed.L, packed.R):
-                    w, f = aot.dispatch(
-                        "pallas",
-                        match_rules_codes_pallas,
-                        (
-                            chunk_c,
-                            chunk_e,
-                            cs.act_rows_dev,
-                            *cs.pallas_args,
-                            packed.n_tiers,
-                            want_full,
-                            self._pallas_interpret,
-                            packed.has_gate,
-                        ),
-                        aot.STATICS["pallas"],
-                    )
-                    return w, f, None
+        def xla_launch(chunk_c, chunk_e, m, wire_codes):
             # shape-aware plane selection: the segmented kernel's win is
             # measured at serving-chunk batch sizes; at super-batch scale
             # the unrolled per-chunk score intermediates cost more than
             # the masked scan saves (docs/Limitations.md). Large batches
             # therefore keep the scan plane even when segs are enabled.
             segs = cs.segs if chunk_c.shape[0] <= SERVING_CHUNK else None
-            wire_codes = None
-            if cs.wire is not None:
-                try:
-                    wire_codes = cs.pack_wire(chunk_c)
-                except WireSpanError:
-                    # a span violation means these codes don't fit the u8
-                    # plan (advisor r5): serve THIS set via the flat
-                    # layout from here on instead of wrapping uint8 into a
-                    # wrong activation row. One log; the flat kernel is
-                    # correct, just a fatter transfer.
-                    log.exception(
-                        "u8 wire span violation; disabling the wire layout "
-                        "for this compiled set (flat codes from now on)"
-                    )
-                    cs.wire = None
             if wire_codes is not None:
                 from ..ops.match import match_rules_codes_wire_donated
 
@@ -1985,28 +2002,32 @@ class TPUPolicyEngine:
             else:
                 w, f, p = one(codes_arr[lo:hi], extras_arr[lo:hi], v)
             part = None
-            if use_pack:
-                part = word_pack.add(w)
-            else:
-                w.copy_to_host_async()
-            if f is not None:
-                f[0].copy_to_host_async()
-                f[1].copy_to_host_async()
+            with sub_stage("dispatch.readback"):
+                if use_pack:
+                    part = word_pack.add(w)
+                else:
+                    w.copy_to_host_async()
+                if f is not None:
+                    f[0].copy_to_host_async()
+                    f[1].copy_to_host_async()
             outs.append((lo, hi - lo, w, f, p, part))
 
         def finish():
             bitmap: dict = {}
-            host = [
-                (
-                    lo,
-                    word_pack.view(part, m)
-                    if part is not None
-                    else np.asarray(w)[:m],
-                    trim_full(f, m) if want_full else None,
-                    p,
-                )
-                for lo, m, w, f, p, part in outs
-            ]
+            # the first materialization blocks until the device is done:
+            # what the decode thread waits for, apart from what it does
+            with sub_stage("decode.device_wait"):
+                host = [
+                    (
+                        lo,
+                        word_pack.view(part, m)
+                        if part is not None
+                        else np.asarray(w)[:m],
+                        trim_full(f, m) if want_full else None,
+                        p,
+                    )
+                    for lo, m, w, f, p, part in outs
+                ]
             # outputs are materialized: the device has fully consumed the
             # staged inputs, so their buffers can serve the next batch
             if held:

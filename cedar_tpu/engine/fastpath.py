@@ -41,6 +41,7 @@ from typing import Callable, List, NamedTuple, Optional, Sequence, Tuple
 import numpy as np
 
 from ..chaos.registry import chaos_fire
+from ..obs.trace import sub_stage
 from ..native import (
     F_ADM_ERROR,
     F_ADM_NS_SKIP,
@@ -315,8 +316,8 @@ class _RawFastPath:
                 (chunk, self._prepare_chunk(snap, chunk, word_pack=pack))
             )
         if pack is not None:
-            pack.flush()
-            self._note_packed(pack)
+            with sub_stage("dispatch.readback"):
+                pack.flush()
         ctxs = [self._finish_words(snap, chunk, pre) for chunk, pre in pending]
         self._resolve_deferred(snap, ctxs)
         if len(ctxs) == 1:
@@ -394,11 +395,10 @@ class _RawFastPath:
                 for chunk, enc in encs
             ]
             if pack is not None:
-                pack.flush()
+                with sub_stage("dispatch.readback"):
+                    pack.flush()
         except Exception:  # noqa: BLE001 — device failure degrades
             return ("direct", self._pipeline_degrade(bodies, "dispatch"))
-        if pack is not None:
-            self._note_packed(pack)
         return ("run", snap, bodies, launched, t0)
 
     def pipeline_decode(self, ctx) -> list:
@@ -423,18 +423,6 @@ class _RawFastPath:
         for c in ctxs:
             out.extend(c["results"].tolist())
         return out
-
-    def _note_packed(self, pack) -> None:
-        """Count one batch's packed word transfer (metrics are advisory:
-        never let a registry hiccup break serving)."""
-        if not pack.parts:
-            return
-        try:
-            from ..server.metrics import record_packed_decode
-
-            record_packed_decode(self._METRIC_PATH, pack.parts)
-        except Exception:  # noqa: BLE001 — metrics never break serving
-            pass
 
     def _pipeline_degrade(self, bodies: Sequence[bytes], stage: str) -> list:
         """A pipelined stage raised: feed the breaker and answer the whole

@@ -21,6 +21,17 @@ budget. This module is the zero-dependency recorder behind that question
     ``/debug/traces`` and (optionally) appended as JSONL to a trace log
     that ``cedar-trace`` reads offline.
 
+  * ``RequestPhases`` — one served request's boundary stamps from the
+    request line to the flushed reply. ``stamps()`` lines them up as the
+    consecutive phases of docs/observability.md ("Request phases") ONCE;
+    the same tuple feeds ``cedar_request_phase_seconds`` and the trace's
+    ``http.*`` / ``batch.*`` spans, so the two cannot disagree.
+  * ``batch_stage`` / ``sub_stage`` / ``profiler_scope`` — the one helper
+    the worker loops use per stage: it stamps ``time.monotonic()`` into
+    the batch's stage record AND, while a ``jax.profiler`` session runs,
+    wraps the stage in a ``TraceAnnotation`` so the program's stages land
+    on the profiler's clock beside the runtime's own events.
+
 Pay-for-use contract: with no tracer wired, the serving path's only cost
 is a thread-local read per annotation site; with a tracer armed but the
 request unsampled, the cost is the span bookkeeping (no device work — the
@@ -32,10 +43,11 @@ from __future__ import annotations
 
 import json
 import logging
+import os
 import random
+import sys
 import threading
 import time
-import uuid
 from collections import deque
 from typing import Optional, Tuple
 
@@ -72,14 +84,24 @@ MAX_SPAN_ATTRS = 16
 MAX_ATTR_CHARS = 200
 
 
+# ids come from a generator of this module's own, seeded once from the
+# OS: uuid4() asks the OS for every id, a call that gives the interpreter
+# up — two of them a request cost a saturated server 1.4 ms of every
+# request's cycle (PERF.md §5, `pre`). W3C ids have to be random, not
+# secret, and never all zero.
+_ids = random.Random(os.urandom(32))
+# a forked child must not repeat its parent's ids
+os.register_at_fork(after_in_child=lambda: _ids.seed(os.urandom(32)))
+
+
 def new_trace_id() -> str:
     """Fresh 32-hex-char W3C trace id."""
-    return uuid.uuid4().hex
+    return f"{_ids.getrandbits(128) or 1:032x}"
 
 
 def new_span_id() -> str:
     """Fresh 16-hex-char W3C span id."""
-    return uuid.uuid4().hex[:16]
+    return f"{_ids.getrandbits(64) or 1:016x}"
 
 
 def parse_traceparent(header: Optional[str]) -> Optional[Tuple[str, str]]:
@@ -183,7 +205,7 @@ class Trace:
         "trace_id",
         "path",
         "root",
-        "spans",
+        "_spans",
         "sampled",
         "parent_span_id",
         "started_unix",
@@ -192,6 +214,7 @@ class Trace:
         "fallback",
         "_stack",
         "_n",
+        "_windows",
     )
 
     def __init__(
@@ -213,9 +236,13 @@ class Trace:
         # degradation): a tail-keep trigger independent of latency
         self.fallback = False
         self.root = Span(path, root_span_id or new_span_id(), parent_span_id)
-        self.spans = [self.root]
+        self._spans = [self.root]
         self._stack = [self.root]
         self._n = 0
+        # (phase names, boundary stamps, batch stage record) added in bulk;
+        # they become Span objects only when somebody reads .spans (a kept
+        # trace)
+        self._windows: list = []
 
     # ------------------------------------------------------------- recording
 
@@ -223,9 +250,35 @@ class Trace:
         self._n += 1
         return f"{self._n:x}"
 
+    @property
+    def spans(self) -> list:
+        """Every span of the tree, the deferred windows materialized."""
+        if self._windows:
+            deferred, self._windows = self._windows, []
+            for names, stamps, times in deferred:
+                rows = span_windows(windows_of(names, stamps), times)
+                for name, t0, t1, attrs in rows:
+                    span = Span(name, self._next_id(), self.root.span_id)
+                    span.t0, span.t1 = t0, t1
+                    if attrs:
+                        for k, v in attrs.items():
+                            span.set_attr(k, v)
+                    self._spans.append(span)
+        return self._spans
+
+    def add_windows(self, names, stamps, times=None) -> None:
+        """Add consecutive completed phases (``pipeline_stamps``,
+        ``RequestPhases.stamps``: names, and one boundary stamp more) as
+        children of the root, under their span names; ``times`` is the
+        batch's stage record, whose sub-stage seconds hang on the dispatch
+        and decode spans. Nothing is built until somebody reads
+        ``.spans``: an unsampled request that is not tail-kept never pays
+        for its spans."""
+        self._windows.append((names, stamps, times))
+
     def begin_span(self, name: str) -> Span:
         span = Span(name, self._next_id(), self._stack[-1].span_id)
-        self.spans.append(span)
+        self._spans.append(span)
         self._stack.append(span)
         return span
 
@@ -251,7 +304,7 @@ class Trace:
         span.t0, span.t1 = t0, t1
         for k, v in attrs.items():
             span.set_attr(k, v)
-        self.spans.append(span)
+        self._spans.append(span)
         return span
 
     def event(self, name: str, **attrs) -> None:
@@ -354,6 +407,320 @@ def annotate(fn) -> None:
     tr = current_trace()
     if tr is not None:
         fn(tr)
+
+
+# ------------------------------------------------------------ request phases
+
+# phase -> span name: the served request's tree says the same thing as the
+# cedar_request_phase_seconds ledger, under the names traces already used
+_SPAN_NAMES = {
+    "read": "http.read",
+    "pre": "http.pre",
+    "parse": "http.parse",
+    "respond": "http.respond",
+    "write": "http.write",
+    "queue": "batch.queue_wait",
+    "encode_wait": "batch.encode_wait",
+    "encode": "batch.encode",
+    "dispatch_wait": "batch.dispatch_wait",
+    "dispatch": "batch.dispatch",
+    "device_wait": "batch.device_wait",
+    "decode": "batch.decode",
+    "evaluate_wait": "batch.evaluate_wait",
+    "evaluate": "batch.evaluate",
+    "wake": "batch.wake",
+}
+
+_PIPELINED = (
+    "queue", "encode_wait", "encode", "dispatch_wait", "dispatch",
+    "device_wait", "decode", "wake",
+)
+_SERIAL = ("queue", "evaluate_wait", "evaluate", "wake")
+# a request's phase names by shape: (it is its connection's first, so has
+# no `between`; the kind of batcher that answered it, if one did)
+_HEAD = ("read", "pre", "parse")
+_TAIL = ("respond", "write")
+_PHASES = {
+    (first, kind): (() if first else ("between",)) + _HEAD + mid + _TAIL
+    for first in (True, False)
+    for kind, mid in (("none", ()), ("pipelined", _PIPELINED), ("serial", _SERIAL))
+}
+
+
+def pipeline_stamps(t_enq: float, times, t_wake: float):
+    """One slot's way through its batch as ``(phase names, boundary
+    stamps)``, one stamp more than names: from its own enqueue to its
+    waiter running again — the batch's shared stage stamps
+    (engine/batcher.py ``_StageTimes``), the ones
+    ``cedar_pipeline_stage_seconds`` observes. None while a stamp is
+    missing (a batch that failed before its last stage)."""
+    if times.eval0 is not None:
+        names = _SERIAL
+        stamps = (t_enq, times.claimed, times.eval0, times.eval1, t_wake)
+    else:
+        names = _PIPELINED
+        stamps = (
+            t_enq, times.claimed, times.encode0, times.encode1,
+            times.dispatch0, times.dispatch1, times.decode0, times.decode1,
+            t_wake,
+        )
+    if None in stamps:
+        return None
+    return names, stamps
+
+
+def windows_of(names, stamps) -> list:
+    """``[(phase, t0, t1)]`` from phase names and their boundary stamps."""
+    return [(n, stamps[i], stamps[i + 1]) for i, n in enumerate(names)]
+
+
+def span_windows(windows, times=None) -> list:
+    """Phase windows -> ``(span name, t0, t1, attrs-or-None)`` rows.
+    ``times`` (the batch's stage record) hangs the sub-stage seconds of
+    dispatch and decode on their spans."""
+    sub = getattr(times, "sub", None) or {}
+    out = []
+    for phase, t0, t1 in windows:
+        name = _SPAN_NAMES.get(phase)
+        if name is None:
+            continue  # `between` precedes the root span: a root attribute
+        attrs = None
+        if sub and phase in ("dispatch", "decode"):
+            attrs = {
+                k.split(".", 1)[1] + "_us": round(v * 1e6, 1)
+                for k, v in sub.items()
+                if k.startswith(phase + ".")
+            }
+        out.append((name, t0, t1, attrs))
+    return out
+
+
+class RequestPhases:
+    """Boundary stamps of one request on a served connection, request line
+    to flushed reply, each taken once with ``time.monotonic()`` where the
+    table in docs/observability.md says. The HTTP handler owns it; the
+    layers below reach it through ``current_phases()``. Nothing is derived
+    while the request is served: ``stamps()`` lines the boundaries up once
+    the reply is out, and both the phase ledger and the trace's spans are
+    cut from that one tuple."""
+
+    __slots__ = (
+        "path", "t_prev", "t_line", "t_body", "t_start", "t_eval",
+        "slot", "t_wake", "t_stop", "t_flush",
+        "trace", "decision", "error",
+    )
+
+    def __init__(self, t_prev: Optional[float]):
+        self.path = ""
+        self.t_prev = t_prev  # previous reply flushed on this connection
+        self.t_line = time.monotonic()  # request line in hand
+        self.t_body = None  # body read
+        self.t_start = None  # the handler's request timer starts
+        self.t_eval = None  # verdict in hand (requests with no batch slot)
+        self.slot = None  # the batcher slot that answered …
+        self.t_wake = None  # … and when its waiter (this thread) ran again
+        self.t_stop = None  # the handler's request timer stops
+        self.t_flush = None  # reply flushed
+        # the request's trace and outcome, finished once the reply is out
+        self.trace = None
+        self.decision = None
+        self.error = False
+
+    @property
+    def times(self):
+        """The stage record of the batch that answered, or None."""
+        return self.slot.times if self.slot is not None else None
+
+    def stamps(self):
+        """``(phase names, boundary stamps)``: consecutive phases, one
+        stamp more than names; on a keep-alive connection they run from
+        one reply flushed to the next."""
+        first = self.t_prev is None
+        head = (self.t_line, self.t_body, self.t_start)
+        if not first:
+            head = (self.t_prev,) + head
+        pipe = None
+        if self.slot is not None and self.slot.times is not None:
+            pipe = pipeline_stamps(self.slot.t_enq, self.slot.times, self.t_wake)
+        if pipe is None:
+            t_run = self.t_eval if self.t_eval is not None else self.t_stop
+            return _PHASES[first, "none"], head + (t_run, self.t_stop, self.t_flush)
+        names, mid = pipe
+        if mid[0] < self.t_start:
+            # a coalesced follower shares a slot enqueued before its own
+            # timer started: its queue wait starts with its timer
+            mid = (self.t_start, max(mid[1], self.t_start)) + mid[2:]
+        kind = "serial" if names is _SERIAL else "pipelined"
+        return _PHASES[first, kind], head + mid + (self.t_stop, self.t_flush)
+
+    def windows(self) -> list:
+        """The request as consecutive ``(phase, t0, t1)`` windows."""
+        return windows_of(*self.stamps())
+
+
+def current_phases() -> Optional[RequestPhases]:
+    """The calling thread's request phase record, or None (a request that
+    did not come over the webhook's HTTP handler, or no tracer)."""
+    return getattr(_current, "phases", None)
+
+
+def set_phases(phases: Optional[RequestPhases]) -> None:
+    _current.phases = phases
+
+
+def note_batch_result(slot) -> None:
+    """The request thread is running again with its slot's result: stamp
+    the wake and leave the slot with whoever reads its batch's stamps —
+    the request's phase record when the HTTP handler keeps one (it cuts
+    the phases once the reply is out), else the active trace."""
+    phases = current_phases()
+    if phases is not None:
+        phases.t_wake = time.monotonic()
+        phases.slot = slot
+        return
+    trace = current_trace()
+    if trace is not None and slot.times is not None:
+        pipe = pipeline_stamps(slot.t_enq, slot.times, time.monotonic())
+        if pipe is not None:
+            trace.add_windows(pipe[0], pipe[1], slot.times)
+
+
+# ---------------------------------------------- stages on the profiler's clock
+
+_ANNOTATION = None  # jax.profiler.TraceAnnotation, False when it cannot load
+_stage_local = threading.local()
+
+
+def _annotation_cls():
+    """``jax.profiler.TraceAnnotation`` once some other module has imported
+    jax — this package never pulls jax into a process that serves from the
+    interpreter alone."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        if "jax" not in sys.modules:
+            return None
+        try:
+            from jax.profiler import TraceAnnotation
+        except Exception:  # noqa: BLE001 — a jax without a profiler
+            log.exception("jax.profiler.TraceAnnotation unavailable")
+            _ANNOTATION = False
+        else:
+            _ANNOTATION = TraceAnnotation
+    return _ANNOTATION or None
+
+
+def _annotation(name: str, rows: int):
+    """``TraceAnnotation(name, batch=rows)`` while a profiler session
+    runs, else None (one atomic load)."""
+    cls = _ANNOTATION or _annotation_cls()
+    if cls is not None and cls.is_enabled():
+        return cls(name, batch=rows)
+    return None
+
+
+def profiler_scope(name: str):
+    """A context manager that puts ``name`` on a running profiler's clock
+    (``jax.profiler.TraceAnnotation``); the shared no-op with no session."""
+    cls = _ANNOTATION or _annotation_cls()
+    if cls is not None and cls.is_enabled():
+        return cls(name)
+    return _NULL_CTX
+
+
+# what a stage's time counts as while no sub_stage is open inside it
+_STAGE_REST = {"dispatch": "dispatch.stage", "decode": "decode.host"}
+
+
+class batch_stage:
+    """``with batch_stage(times, "dispatch", rows):`` — stamp the stage's
+    window into the batch's stage record (``times.dispatch0`` / ``1``),
+    bind the record to this worker thread so the ``sub_stage`` sites below
+    it find it, and put the stage on the profiler's clock as
+    ``cedar.batch.dispatch``. The one thing a worker loop does per stage.
+
+    The stage's seconds are split, by stamps, among its sub-stages
+    (``times.sub``): whatever runs outside every ``sub_stage`` counts as
+    the stage's own rest — ``dispatch.stage`` (host staging and the other
+    Python of a dispatch), ``decode.host`` — so the parts sum to the
+    window."""
+
+    __slots__ = ("_times", "_name", "_scope")
+
+    def __init__(self, times, name: str, rows: int):
+        self._times = times
+        self._name = name
+        times.rows = rows
+
+    def __enter__(self):
+        times = self._times
+        _stage_local.times = times
+        self._scope = _annotation("cedar.batch." + self._name, times.rows)
+        if self._scope is not None:
+            self._scope.__enter__()
+        times.part = _STAGE_REST.get(self._name)
+        times.part_t0 = now = time.monotonic()
+        setattr(times, self._name + "0", now)
+        return times
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        times = self._times
+        now = time.monotonic()
+        setattr(times, self._name + "1", now)
+        if times.part is not None:
+            times.sub[times.part] = (
+                times.sub.get(times.part, 0.0) + now - times.part_t0
+            )
+            times.part = None
+        if self._scope is not None:
+            self._scope.__exit__(exc_type, exc, tb)
+        _stage_local.times = None
+
+
+class _SubStage:
+    __slots__ = ("_name", "_times", "_scope", "_outer")
+
+    def __init__(self, name: str, times, scope):
+        self._name = name
+        self._times = times
+        self._scope = scope  # a TraceAnnotation, or None with no session
+
+    def __enter__(self):
+        if self._scope is not None:
+            self._scope.__enter__()
+        times = self._times
+        if times is not None:
+            # close the enclosing part's running segment, open this one
+            now = time.monotonic()
+            outer = self._outer = times.part
+            if outer is not None:
+                times.sub[outer] = times.sub.get(outer, 0.0) + now - times.part_t0
+            times.part, times.part_t0 = self._name, now
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        times = self._times
+        if times is not None:
+            now = time.monotonic()
+            times.sub[self._name] = (
+                times.sub.get(self._name, 0.0) + now - times.part_t0
+            )
+            times.part, times.part_t0 = self._outer, now
+        if self._scope is not None:
+            self._scope.__exit__(exc_type, exc, tb)
+
+
+def sub_stage(name: str):
+    """``with sub_stage("dispatch.launch"):`` inside a batch stage — the
+    enclosed seconds go to the bound batch record's ``sub[name]`` instead
+    of the enclosing part's (a stage may run once per chunk: they add
+    up), and ``cedar.<name>`` is annotated while a profiler session runs.
+    Outside a batcher's worker threads (the warm ladder, bench.py, tests)
+    nothing is bound and, with no session, the cost is a thread-local read
+    and an atomic load."""
+    times = getattr(_stage_local, "times", None)
+    scope = _annotation("cedar." + name, times.rows if times is not None else 0)
+    if times is None and scope is None:
+        return _NULL_CTX
+    return _SubStage(name, times, scope)
 
 
 class Tracer:
@@ -558,17 +925,27 @@ def span_tree_coverage(doc: dict) -> float:
 
 __all__ = [
     "MAX_SPAN_ATTRS",
+    "RequestPhases",
     "Span",
     "Trace",
     "Tracer",
     "annotate",
+    "batch_stage",
+    "current_phases",
     "current_trace",
     "format_traceparent",
     "ingest_request_id",
     "new_span_id",
     "new_trace_id",
+    "note_batch_result",
     "parse_traceparent",
+    "pipeline_stamps",
+    "profiler_scope",
     "set_current",
+    "set_phases",
     "span",
     "span_tree_coverage",
+    "span_windows",
+    "sub_stage",
+    "windows_of",
 ]

@@ -114,6 +114,7 @@ def _lit_dtype(w_dtype):
     return jnp.int8 if w_dtype == jnp.int8 else jnp.bfloat16
 
 
+@jax.named_scope("cedar.match.score")
 def _scores(lit, Wc):
     """lit [B, L] @ Wc [L, Rc] with the accumulator that keeps the plane
     exact: int32 for the int8 plane, float32 for bf16."""
@@ -121,6 +122,7 @@ def _scores(lit, Wc):
     return jnp.dot(lit, Wc, preferred_element_type=acc)
 
 
+@jax.named_scope("cedar.match.activation")
 def _lit_matrix(active, L: int, dtype=jnp.bfloat16):
     """active [B, A] int -> {0,1} literal matrix [B, L]. Out-of-range
     ids (the pad value) simply never match the iota."""
@@ -129,6 +131,7 @@ def _lit_matrix(active, L: int, dtype=jnp.bfloat16):
     return (a32[:, :, None] == iota[None, None, :]).any(axis=1).astype(dtype)
 
 
+@jax.named_scope("cedar.match.scan")
 def _first_match(
     lit, W_chunks, thresh_c, group_c, policy_c, n_groups: int,
     want_bits: bool = False,
@@ -180,6 +183,7 @@ def _first_match(
     return first, last, bits
 
 
+@jax.named_scope("cedar.match.scan")
 def _first_match_seg(
     lit, W_chunks, thresh_c, policy_c, segs, n_groups: int,
     want_bits: bool = False,
@@ -228,6 +232,7 @@ def _first_match_seg(
     return first, last, bits
 
 
+@jax.named_scope("cedar.match.tier_walk")
 def _tier_walk(first, last, n_tiers: int):
     """Walk tiers on device -> packed uint32 verdict word per request.
     Mirrors TieredPolicyStores semantics (/root/reference
@@ -269,12 +274,13 @@ def _tier_walk(first, last, n_tiers: int):
                 new & sig & (win_first != win_last), jnp.uint32(1), multi
             )
         done = done | sig
-    return (
-        (code << 30)
-        | (err << 29)
-        | (multi << 28)
-        | (pol & jnp.uint32(POLICY_NONE))
-    )
+    with jax.named_scope("cedar.match.word_pack"):
+        return (
+            (code << 30)
+            | (err << 29)
+            | (multi << 28)
+            | (pol & jnp.uint32(POLICY_NONE))
+        )
 
 
 @functools.partial(jax.jit, static_argnames=("n_tiers", "want_full"))
@@ -297,6 +303,7 @@ def match_rules_device(
     return (packed, (first, last)) if want_full else (packed, None)
 
 
+@jax.named_scope("cedar.match.activation")
 def _lit_matrix_codes(codes, extras, act_rows, dtype=jnp.bfloat16):
     """codes [B, S] int (row indices into act_rows [V, L] uint8) + extras
     [B, E] int (raw literal ids, pad >= L) -> {0,1} literal matrix [B, L]
@@ -328,6 +335,7 @@ def _lit_matrix_codes(codes, extras, act_rows, dtype=jnp.bfloat16):
 BITS_TOPK = 128
 
 
+@jax.named_scope("cedar.match.bits_compact")
 def _compact_flagged_bits(bits, flagged, n_valid):
     """Gather the bitset rows of flagged requests into a fixed [K, R/32]
     buffer on device: top_k over a keep-key compacts the (dynamic) flagged
@@ -429,8 +437,9 @@ def _match_from_lit(
         )
     packed = _tier_walk(first, last, n_tiers)
     if has_gate:
-        gate = (first[:, n_tiers * _GPT] != INT32_MAX).astype(jnp.uint32)
-        packed = packed | (gate << 27)
+        with jax.named_scope("cedar.match.word_pack"):
+            gate = (first[:, n_tiers * _GPT] != INT32_MAX).astype(jnp.uint32)
+            packed = packed | (gate << 27)
     if not want_bits:
         return (packed, (first, last)) if want_full else (packed, None)
     if want_full:
@@ -444,6 +453,7 @@ def _match_from_lit(
     return (packed, (first, last) if want_full else None, pack)
 
 
+@jax.named_scope("cedar.match.activation")
 def _lit_matrix_codes_wire(
     codes8, codes_w, lo8, extras, act_rows, dtype=jnp.bfloat16
 ):
@@ -577,6 +587,7 @@ def match_rules_compact(active, W_chunks, thresh_c, group_c, policy_c, n_groups:
     return first
 
 
+@jax.named_scope("cedar.match.bits_pack")
 def _pack_sat_bits(sat):
     """sat [B, Rc] bool -> [B, Rc // 32] uint32, little-endian bit order
     (rule r lives in word r // 32, bit r % 32). Rc is always a multiple of
@@ -607,7 +618,10 @@ def match_rules_codes_bits(
         sat = scores >= tc[None, :]
         return None, _pack_sat_bits(sat)
 
-    _, bits = jax.lax.scan(body, None, (W_chunks, thresh_c, group_c, policy_c))
+    with jax.named_scope("cedar.match.scan"):
+        _, bits = jax.lax.scan(
+            body, None, (W_chunks, thresh_c, group_c, policy_c)
+        )
     # scan stacks per-chunk [B, Rc/32] -> [C, B, Rc/32]; rules are chunked
     # contiguously, so transpose + reshape restores rule order
     C, B, w = bits.shape

@@ -33,12 +33,16 @@ from ..fanout.frontend import FanoutUnavailable
 from ..fleet.router import FleetUnavailable
 from ..load.admission import STATE_SATURATED, RequestShed
 from ..obs.trace import (
+    RequestPhases,
+    current_phases,
     current_trace,
     format_traceparent,
     ingest_request_id,
     new_span_id,
     new_trace_id,
+    profiler_scope,
     set_current,
+    set_phases,
 )
 from ..obs.trace import span as trace_span
 from ..entities.admission import AdmissionRequest
@@ -486,6 +490,9 @@ class WebhookServer:
         # All three are strictly optional — None keeps the serving path
         # at one thread-local read per annotation site.
         self.tracer = tracer
+        # the process's stall recorder (obs/stall.py), watching from
+        # start() to stop() whenever a tracer is wired; /debug/stalls
+        self.stalls = None
         self.audit_log = audit_log
         self.slo = slo
         # canonical-fingerprint memos for the audit log, joinable against
@@ -737,6 +744,12 @@ class WebhookServer:
         if explain:
             return self._handle_authorize_explain(body, request_id)
         start = time.monotonic()
+        # the HTTP handler's phase record for this request (None for
+        # direct embedder calls): the timer's two ends are two of its
+        # boundaries, and it finishes the trace once the reply is out
+        phases = current_phases()
+        if phases is not None:
+            phases.path, phases.t_start = "authorization", start
         if request_id is None:
             request_id = new_trace_id()
         trace = None
@@ -780,6 +793,8 @@ class WebhookServer:
                 decision, reason, error = (
                     DECISION_NO_OPINION, "", str(e),
                 )
+            if phases is not None:
+                phases.t_eval = time.monotonic()
             if error is not None:
                 return sar_response(decision, reason, error)
             if self.rollout is not None and self._cache_usable():
@@ -807,6 +822,8 @@ class WebhookServer:
             _octx_set(None)
             label = "<error>" if error else _DECISION_LABEL[decision]
             latency = time.monotonic() - start
+            if phases is not None:
+                phases.t_stop = start + latency
             metrics.record_request_total(label, protocol=protocol)
             metrics.record_request_latency(label, latency, protocol=protocol)
             if tenant:
@@ -824,14 +841,9 @@ class WebhookServer:
                 except Exception:  # noqa: BLE001 — never break serving
                     log.exception("slo record failed")
             if trace is not None:
-                set_current(None)
-                trace.fallback = trace.fallback or bool(octx.get("fallback"))
-                try:
-                    self.tracer.finish(
-                        trace, decision=label, error=error is not None
-                    )
-                except Exception:  # noqa: BLE001 — never break serving
-                    log.exception("trace finish failed")
+                self._finish_trace(
+                    trace, phases, octx, label, error is not None
+                )
             if self.audit_log is not None:
                 self._audit(
                     "authorization", "authorize", body, request_id,
@@ -1202,6 +1214,9 @@ class WebhookServer:
         if explain:
             return self._handle_admit_explain(body, request_id)
         start = time.monotonic()
+        phases = current_phases()  # see handle_authorize
+        if phases is not None:
+            phases.path, phases.t_start = "admission", start
         trace = None
         if self.tracer is not None:
             trace = self.tracer.begin(
@@ -1221,6 +1236,8 @@ class WebhookServer:
         review = None
         try:
             review = self._handle_admit(body, priority=priority)
+            if phases is not None:
+                phases.t_eval = time.monotonic()
             if self.rollout is not None and self._admission_shadowable():
                 # non-blocking shadow offer; error/fail-mode responses are
                 # filtered by the shadow worker (code != 200), but the
@@ -1231,6 +1248,8 @@ class WebhookServer:
         finally:
             _octx_set(None)
             latency = time.monotonic() - start
+            if phases is not None:
+                phases.t_stop = start + latency
             if tenant:
                 # unconditional, like the authorization path's finally —
                 # per-tenant series must not depend on obs being wired
@@ -1244,11 +1263,55 @@ class WebhookServer:
                 or self.audit_log is not None
             ):
                 self._finish_admit_obs(
-                    body, request_id, review, trace, octx, latency,
+                    body, request_id, review, trace, octx, latency, phases,
                 )
 
+    def _finish_trace(self, trace, phases, octx, label, errored) -> None:
+        """Close a request's trace where the handler's timer stops — or,
+        for a request the HTTP handler keeps a phase record for, leave it
+        with the record: _finish_phases closes it once the reply is
+        flushed, so its tree runs from the request line to the flush."""
+        set_current(None)
+        trace.fallback = trace.fallback or bool(octx.get("fallback"))
+        if phases is not None:
+            phases.trace, phases.decision, phases.error = (
+                trace, label, errored,
+            )
+            return
+        try:
+            self.tracer.finish(trace, decision=label, error=errored)
+        except Exception:  # noqa: BLE001 — never break serving
+            log.exception("trace finish failed")
+
+    def _finish_phases(self, phases) -> None:
+        """The reply is flushed: line the request's stamps up as phases
+        ONCE and hand the same tuple to the phase ledger
+        (cedar_request_phase_seconds) and, as spans to be, to the
+        request's trace, whose root now runs from the request line to the
+        flush."""
+        try:
+            names, stamps = phases.stamps()
+            metrics.record_request_phases(phases.path, names, stamps)
+            trace = phases.trace
+            if trace is None:
+                return
+            root = trace.root
+            trace.started_unix -= root.t0 - phases.t_line
+            root.t0, root.t1 = phases.t_line, phases.t_flush
+            if phases.t_prev is not None:
+                root.set_attr(
+                    "between_us",
+                    round((phases.t_line - phases.t_prev) * 1e6, 1),
+                )
+            trace.add_windows(names, stamps, phases.times)
+            self.tracer.finish(
+                trace, decision=phases.decision, error=phases.error
+            )
+        except Exception:  # noqa: BLE001 — never break serving
+            log.exception("request phase record failed")
+
     def _finish_admit_obs(
-        self, body, request_id, review, trace, octx, latency
+        self, body, request_id, review, trace, octx, latency, phases=None
     ) -> None:
         """Close out the admission request's observability surfaces
         (trace finish + tail-keep, SLO record, audit line) from the
@@ -1264,14 +1327,7 @@ class WebhookServer:
             except Exception:  # noqa: BLE001 — never break serving
                 log.exception("slo record failed")
         if trace is not None:
-            set_current(None)
-            trace.fallback = trace.fallback or bool(octx.get("fallback"))
-            try:
-                self.tracer.finish(
-                    trace, decision=label, error=error is not None
-                )
-            except Exception:  # noqa: BLE001 — never break serving
-                log.exception("trace finish failed")
+            self._finish_trace(trace, phases, octx, label, error is not None)
         if self.audit_log is not None:
             self._audit(
                 "admission", "admit", body, request_id, label,
@@ -1473,7 +1529,36 @@ class WebhookServer:
                 self.end_headers()
                 self.wfile.write(data)
 
+            # Request phases (docs/observability.md): with a tracer wired,
+            # every request on this connection gets a RequestPhases record
+            # whose first stamp is the request line in hand and whose last
+            # is the reply flushed — the next request's `between` starts
+            # there. BaseHTTPRequestHandler calls parse_request right after
+            # it has read the line, and flushes right after do_POST.
+            _phases = None
+            _t_flushed = None
+
+            def parse_request(self):
+                if server.tracer is not None:
+                    self._phases = RequestPhases(self._t_flushed)
+                return super().parse_request()
+
+            def handle_one_request(self):
+                self._phases = None
+                try:
+                    super().handle_one_request()
+                finally:
+                    phases = self._phases
+                    if phases is not None:
+                        phases.t_flush = self._t_flushed = time.monotonic()
+                        if phases.t_stop is not None:
+                            server._finish_phases(phases)
+
             def do_POST(self):
+                with profiler_scope("cedar.http.request"):
+                    self._do_post()
+
+            def _do_post(self):
                 # the drain check and the in-flight increment are one
                 # atomic step: once stop() sets _draining and sees
                 # _inflight == 0 under this lock, no request can slip past
@@ -1517,6 +1602,9 @@ class WebhookServer:
                         self.send_error(413, "request body too large")
                         return
                     body = self.rfile.read(length) if length else b""
+                    phases = self._phases
+                    if phases is not None:
+                        phases.t_body = time.monotonic()
                     if server.tenancy is not None:
                         # tenant front end (docs/multitenancy.md): resolve
                         # path-prefix/header/host → tenant, re-dispatch on
@@ -1595,6 +1683,10 @@ class WebhookServer:
                         if server.load is not None and path_label is not None
                         else contextlib.nullcontext()
                     )
+                    # the layers below stamp their boundaries into this
+                    # thread's phase record (handle_authorize/handle_admit:
+                    # the timer's ends; the batcher: the slot's stages)
+                    set_phases(phases)
                     with tracked:
                         if path == "/v1/authorize":
                             self._write_json(
@@ -1625,6 +1717,7 @@ class WebhookServer:
                         else:
                             self.send_error(404)
                 finally:
+                    set_phases(None)
                     with server._inflight_cv:
                         server._inflight -= 1
                         server._inflight_cv.notify_all()
@@ -2049,6 +2142,16 @@ class WebhookServer:
                         log.exception("trace lookup failed")
                         doc = {"error": "trace lookup failed"}
                     self._send_json(doc)
+                elif self.path == "/debug/stalls":
+                    # the stall recorder (docs/observability.md "Process
+                    # stalls"): the last 32 times this process's watcher
+                    # ran 100 ms late or more, each with its cause, the
+                    # deltas taken across it and every thread's stack as
+                    # it ended; 404 under --no-trace
+                    if server.stalls is None:
+                        self.send_error(404)
+                        return
+                    self._send_json(server.stalls.status())
                 elif self.path == "/debug/analysis":
                     # the last policy-set analysis report (load-time
                     # lowerability/shadowing/conflict findings + capacity);
@@ -2308,6 +2411,10 @@ class WebhookServer:
             self.pdp.start()
         if self.supervisor is not None:
             self.supervisor.start()
+        if self.tracer is not None and self.stalls is None:
+            from ..obs import stall
+
+            self.stalls = stall.acquire()
         scheme = "https" if self.certfile else "http"
         log.info(
             "serving on %s://%s:%d (metrics http://%s:%d)",
@@ -2402,6 +2509,11 @@ class WebhookServer:
                 self.rollout.stop()  # shadow worker; best-effort by design
             except Exception:  # noqa: BLE001 — teardown must finish
                 log.exception("rollout stop failed")
+        if self.stalls is not None:
+            from ..obs import stall
+
+            self.stalls = None
+            stall.release()
         for closer in (self.tracer, self.audit_log):
             if closer is not None:
                 try:

@@ -15,6 +15,7 @@ reference leans on client_golang + component-base legacyregistry).
 from __future__ import annotations
 
 import threading
+from bisect import bisect_left
 from typing import Dict, List, Optional, Sequence, Tuple
 
 SUBSYSTEM = "cedar_authorizer"
@@ -131,13 +132,19 @@ class Histogram:
     def observe(self, value: float, extra: Tuple = (), **labels) -> None:
         # ``extra``: optional appended label pairs, as on Counter.inc
         key = tuple((k, labels.get(k, "")) for k in self.label_names) + tuple(extra)
+        # ``_counts`` holds each bucket's OWN count (the first bucket whose
+        # bound is >= value; none for a value past the last): one increment
+        # here, the cumulative sums of the exposition at collect time
+        i = bisect_left(self.buckets, value)
         with self._lock:
-            counts = self._counts.setdefault(key, [0] * len(self.buckets))
-            for i, b in enumerate(self.buckets):
-                if value <= b:
-                    counts[i] += 1
-            self._sums[key] = self._sums.get(key, 0.0) + value
-            self._totals[key] = self._totals.get(key, 0) + 1
+            counts = self._counts.get(key)
+            if counts is None:
+                counts = self._counts[key] = [0] * (len(self.buckets) + 1)
+                self._sums[key] = 0.0
+                self._totals[key] = 0
+            counts[i] += 1
+            self._sums[key] += value
+            self._totals[key] += 1
 
     def fraction_over(self, bound: float) -> Dict[Tuple[Tuple[str, str], ...], float]:
         """Per-label-set fraction of observations strictly above the
@@ -150,10 +157,9 @@ class Histogram:
                 total = self._totals.get(key, 0)
                 if not total:
                     continue
-                under = 0
-                for b, c in zip(self.buckets, counts):
-                    if b <= bound:
-                        under = c
+                under = sum(
+                    c for b, c in zip(self.buckets, counts) if b <= bound
+                )
                 out[key] = 1.0 - under / total
         return out
 
@@ -161,11 +167,12 @@ class Histogram:
         out = [f"# HELP {self.name} {self.help}", f"# TYPE {self.name} histogram"]
         with self._lock:
             for key in sorted(self._counts):
+                running = 0
                 for i, b in enumerate(self.buckets):
+                    running += self._counts[key][i]
                     labels = key + (("le", _fmt_value(b)),)
                     out.append(
-                        f"{self.name}_bucket{_fmt_label(labels)} "
-                        f"{self._counts[key][i]}"
+                        f"{self.name}_bucket{_fmt_label(labels)} {running}"
                     )
                 inf_labels = key + (("le", "+Inf"),)
                 out.append(
@@ -177,6 +184,59 @@ class Histogram:
                     f"{_fmt_value(self._sums[key])}"
                 )
                 out.append(f"{self.name}_count{_fmt_label(key)} {self._totals[key]}")
+        return out
+
+
+class Summary:
+    """``_sum`` and ``_count`` per label set, no quantiles and no buckets:
+    the cheapest family that still gives a mean over a scrape interval.
+    ``observe_steps`` takes every row of one event under ONE lock, as a
+    tuple of label values and a tuple of boundary stamps — the request
+    phase ledger records a dozen phases per request from the request
+    thread, which has the interpreter to itself for about a millisecond a
+    request at saturation; a Histogram.observe each would double what the
+    ledger costs."""
+
+    def __init__(self, name: str, help_text: str, label_names: Sequence[str]):
+        self.name = name
+        self.help = help_text
+        self.label_names = tuple(label_names)
+        # (first label value, second label values) -> [count, sum, sum, …]
+        self._rows: Dict[Tuple, List[float]] = {}
+        self._lock = threading.Lock()
+
+    def observe_steps(self, first_label: str, seconds: Tuple, stamps: Tuple) -> None:
+        """One observation of ``stamps[i + 1] - stamps[i]`` under
+        ``(first_label, seconds[i])`` for each i (the family has two
+        labels; ``stamps`` is one longer than ``seconds``)."""
+        with self._lock:
+            row = self._rows.get((first_label, seconds))
+            if row is None:
+                row = self._rows[(first_label, seconds)] = [0.0] * (len(seconds) + 1)
+            row[0] += 1
+            prev = stamps[0]
+            for i in range(1, len(stamps)):
+                cur = stamps[i]
+                row[i] += cur - prev
+                prev = cur
+
+    def totals(self) -> Dict[Tuple[str, str], Tuple[float, int]]:
+        """{label values: (sum, count)}."""
+        out: Dict[Tuple[str, str], List[float]] = {}
+        with self._lock:
+            for (first, seconds), row in self._rows.items():
+                for i, second in enumerate(seconds):
+                    cell = out.setdefault((first, second), [0.0, 0])
+                    cell[0] += row[i + 1]
+                    cell[1] += int(row[0])
+        return {k: (v[0], v[1]) for k, v in out.items()}
+
+    def collect(self) -> List[str]:
+        out = [f"# HELP {self.name} {self.help}", f"# TYPE {self.name} summary"]
+        for key, (total, count) in sorted(self.totals().items()):
+            labels = _fmt_label(tuple(zip(self.label_names, key)))
+            out.append(f"{self.name}_sum{labels} {_fmt_value(total)}")
+            out.append(f"{self.name}_count{labels} {count}")
         return out
 
 
@@ -791,6 +851,68 @@ pipeline_stage_seconds = REGISTRY.register(
     )
 )
 
+# Request phase ledger and stall recorder (cedar_tpu/obs, docs/
+# observability.md "Request phases", "Process stalls"): where a served
+# request's time went, socket to socket, and whether the process itself
+# ran. Both are armed with the tracer and off under --no-trace.
+request_phase_seconds = REGISTRY.register(
+    Summary(
+        "cedar_request_phase_seconds",
+        "A served request's time by consecutive phase, one observation "
+        "per request per phase (between, read, pre, parse, queue, "
+        "encode_wait, encode, dispatch_wait, dispatch, device_wait, "
+        "decode, wake, respond, write; a serial batcher reports "
+        "evaluate_wait and evaluate). On a keep-alive connection a "
+        "request's phases sum to the time from one reply flushed to the "
+        "next. Cut from the SAME stamps as the request's /debug/traces "
+        "spans and cedar_pipeline_stage_seconds.",
+        ["path", "phase"],
+    )
+)
+
+interpreter_wait_seconds = REGISTRY.register(
+    Histogram(
+        "cedar_interpreter_wait_seconds",
+        "How late the stall recorder's thread ran after each 20 ms wait: "
+        "what a thread that is due waits to get the interpreter back "
+        "(plus the kernel's timer slack).",
+        [],
+        [
+            0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025,
+            0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0,
+        ],
+    )
+)
+
+process_watch_seconds_total = REGISTRY.register(
+    Counter(
+        "cedar_process_watch_seconds_total",
+        "Seconds the stall recorder has watched this process.",
+        [],
+    )
+)
+
+process_stalls_total = REGISTRY.register(
+    Counter(
+        "cedar_process_stalls_total",
+        "Times the stall recorder's thread ran 100 ms late or more, by "
+        "cause: gc (a collection overlaps at least half), descheduled "
+        "(the whole process used under 10% of the stall in CPU), "
+        "interpreter_held (the process ran, this thread could not). "
+        "/debug/stalls lists the last 32.",
+        ["cause"],
+    )
+)
+
+process_stall_seconds_total = REGISTRY.register(
+    Counter(
+        "cedar_process_stall_seconds_total",
+        "Seconds of lateness in the stalls cedar_process_stalls_total "
+        "counts, by cause.",
+        ["cause"],
+    )
+)
+
 engine_warmup_seconds = REGISTRY.register(
     Gauge(
         "cedar_engine_warmup_seconds",
@@ -848,27 +970,8 @@ pruned_policies = REGISTRY.register(
 )
 
 # Host-side budget metrics (docs/performance.md "Host-side budget"): the
-# packed-decode counters prove the batch-wide word transfer is actually
-# riding one D2H per batch (chunks/transfer > 1 under load), and the
 # encode-threads gauge surfaces the resolved native encoder pool size so
 # a mis-set CEDAR_NATIVE_THREADS is visible without a shell on the host.
-packed_decode_transfers_total = REGISTRY.register(
-    Counter(
-        "cedar_packed_decode_transfers_total",
-        "Batch-wide packed verdict-word D2H transfers (one per native "
-        "batch on the throughput path), partitioned by serving path.",
-        ["path"],
-    )
-)
-packed_decode_chunks_total = REGISTRY.register(
-    Counter(
-        "cedar_packed_decode_chunks_total",
-        "Chunk word arrays folded into packed D2H transfers; divide by "
-        "transfers for the fold factor (1.0 = lone-request regime, no "
-        "packing win; 4+ = saturated batches).",
-        ["path"],
-    )
-)
 native_encode_threads = REGISTRY.register(
     Gauge(
         "cedar_native_encode_threads",
@@ -1410,6 +1513,23 @@ def record_pipeline_stage(path: str, stage: str, seconds: float) -> None:
         pipeline_stage_seconds.observe(seconds, path=path, stage=stage)
 
 
+def record_request_phases(path: str, names, stamps) -> None:
+    """One request's consecutive phases (obs.trace RequestPhases.stamps:
+    names, and one boundary stamp more) into the phase ledger, under one
+    lock."""
+    request_phase_seconds.observe_steps(path, names, stamps)
+
+
+def record_interpreter_wait(late_s: float, watched_s: float) -> None:
+    interpreter_wait_seconds.observe(late_s)
+    process_watch_seconds_total.inc(watched_s)
+
+
+def record_process_stall(cause: str, seconds: float) -> None:
+    process_stalls_total.inc(cause=cause)
+    process_stall_seconds_total.inc(seconds, cause=cause)
+
+
 def record_trace_kept(path: str, reason: str) -> None:
     trace_kept_total.inc(path=path, reason=reason)
 
@@ -1442,12 +1562,6 @@ def set_shard_state(engine: str, shards: int, dirty: int, pruned: int) -> None:
     policy_shards.set(shards, engine=engine)
     dirty_shards.set(dirty, engine=engine)
     pruned_policies.set(pruned, engine=engine)
-
-
-def record_packed_decode(path: str, chunks: int) -> None:
-    packed_decode_transfers_total.inc(path=path)
-    if chunks:
-        packed_decode_chunks_total.inc(chunks, path=path)
 
 
 def set_native_encode_threads(n: int) -> None:

@@ -763,7 +763,10 @@ class TestQueueWaitBreakerAccounting:
         def fn(items):
             seen.append(list(items))
             if "a" in items:
-                time.sleep(0.08)  # slow but completing: the plane MOVES
+                # slow but completing: the plane MOVES. Long enough that a
+                # main thread held up for tens of ms on a loaded host still
+                # enqueues "c" before this batch completes
+                time.sleep(0.11)
             elif "b" in items:
                 gate.wait(2.0)  # the batch behind it stalls
             return [(DECISION_ALLOW, "", None)] * len(items)
@@ -773,7 +776,7 @@ class TestQueueWaitBreakerAccounting:
         claimed.start()
         while not seen:
             time.sleep(0.001)
-        # "b": claimed only once "a" completes (~80ms > half its 150ms
+        # "b": claimed only once "a" completes (~110ms > half its 150ms
         # budget), then stalls — a claim that got the tail end of a
         # spent deadline on a moving plane: queued=True
         holder = {}

@@ -135,6 +135,15 @@ class _SlowFastPath:
         return [(DECISION_ALLOW, "", None) for _ in bodies]
 
 
+def _wait_kept(tracer, trace_id, timeout_s=5.0):
+    """A served request's trace reaches the ring once its reply has been
+    flushed (its tree runs to the flush): a client that asks in the same
+    millisecond it got the reply can be a moment early."""
+    deadline = time.monotonic() + timeout_s
+    while tracer.get(trace_id) is None and time.monotonic() < deadline:
+        time.sleep(0.002)
+
+
 def _post(port, path, body, headers=None):
     req = urllib.request.Request(
         f"http://127.0.0.1:{port}{path}",
@@ -324,6 +333,7 @@ class TestHTTPTracing:
                 # rate 1.0: the recorded flag is honest
                 assert resp.headers["traceparent"].endswith("-01")
                 json.loads(resp.read())
+            _wait_kept(tracer, tid)
             doc = _get_json(
                 server.bound_metrics_port, f"/debug/traces/{tid}"
             )
@@ -357,6 +367,7 @@ class TestHTTPTracing:
             ) as resp:
                 tid = resp.headers["X-Cedar-Trace-Id"]
                 json.loads(resp.read())
+            _wait_kept(tracer, tid)
             doc = _get_json(
                 server.bound_metrics_port, f"/debug/traces/{tid}"
             )
@@ -689,6 +700,7 @@ class TestCedarTraceCLI:
                 server.bound_port, "/v1/authorize", sar_body()
             ) as resp:
                 tid = resp.headers["X-Cedar-Trace-Id"]
+            _wait_kept(tracer, tid)
             base = f"http://127.0.0.1:{server.bound_metrics_port}"
             rc, out, _ = self._run(["--url", base])
             assert rc == 0 and tid in out
