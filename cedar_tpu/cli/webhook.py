@@ -24,6 +24,7 @@ import sys
 import threading
 from typing import List, Optional
 
+from ..obs import logsink
 from ..server.admission import (
     CedarAdmissionHandler,
     allow_all_admission_policy_store,
@@ -2182,9 +2183,11 @@ def _run_pod_mode(args) -> int:
 
 def main(argv: Optional[List[str]] = None) -> int:
     args = make_parser().parse_args(argv)
-    logging.basicConfig(
-        level=logging.DEBUG if args.verbosity >= 5 else logging.INFO,
-        format="%(asctime)s %(name)s %(levelname)s %(message)s",
+    # basicConfig's one handler, its write and its lock taken off the
+    # calling thread (obs/logsink.py): every line kept, none written by a
+    # thread that owes an answer
+    sink = logsink.install(
+        logging.DEBUG if args.verbosity >= 5 else logging.INFO
     )
     if args.backend == "tpu" or args.pod_num_processes >= 2:
         from ..jaxenv import configure_compile_cache
@@ -2212,6 +2215,8 @@ def main(argv: Optional[List[str]] = None) -> int:
     while not stop.wait(1.0):
         pass
     server.stop()
+    if sink is not None:
+        sink.drain()
     return 0
 
 

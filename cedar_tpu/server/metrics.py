@@ -913,6 +913,25 @@ process_stall_seconds_total = REGISTRY.register(
     )
 )
 
+log_records_total = REGISTRY.register(
+    Counter(
+        "cedar_log_records_total",
+        "Log records the serving log's sink (obs/logsink.py) took, by "
+        "outcome: written (reached the stream) or dropped (below WARNING "
+        "and over the queue's cap of 65,536; WARNING and above never are).",
+        ["outcome"],
+    )
+)
+
+log_writes_total = REGISTRY.register(
+    Counter(
+        "cedar_log_writes_total",
+        "Writes the sink's one writer handed the log stream; written "
+        "records over writes is how many lines a write carries.",
+        [],
+    )
+)
+
 engine_warmup_seconds = REGISTRY.register(
     Gauge(
         "cedar_engine_warmup_seconds",
@@ -1528,6 +1547,15 @@ def record_interpreter_wait(late_s: float, watched_s: float) -> None:
 def record_process_stall(cause: str, seconds: float) -> None:
     process_stalls_total.inc(cause=cause)
     process_stall_seconds_total.inc(seconds, cause=cause)
+
+
+def record_log_write(records: int) -> None:
+    log_records_total.inc(records, outcome="written")
+    log_writes_total.inc()
+
+
+def record_log_dropped() -> None:
+    log_records_total.inc(outcome="dropped")
 
 
 def record_trace_kept(path: str, reason: str) -> None:

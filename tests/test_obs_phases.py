@@ -10,7 +10,9 @@ the same thing because they are cut from the same stamps.
 """
 
 import http.client
+import io
 import json
+import logging
 import threading
 import time
 
@@ -21,6 +23,7 @@ from cedar_tpu.engine.evaluator import TPUPolicyEngine
 from cedar_tpu.engine.fastpath import AdmissionFastPath, SARFastPath
 from cedar_tpu.lang import PolicySet
 from cedar_tpu.native import native_available
+from cedar_tpu.obs import logsink
 from cedar_tpu.obs.trace import Tracer, span_tree_coverage
 from cedar_tpu.server import metrics
 from cedar_tpu.server.admission import (
@@ -253,9 +256,10 @@ def test_a_cache_hit_has_no_pipeline_phases():
         s.stop()
 
 
-def test_kept_traces_cover_the_request_under_16_concurrent_callers(served):
-    per_caller, callers = 8, 16
-    errors = []
+def authorize_from_threads(served, callers, per_caller):
+    """Distinct SARs from ``callers`` keep-alive connections at once; the
+    X-Cedar-Trace-Id of every reply, once every request's phases are in."""
+    ids, errors = [], []
 
     def caller(k):
         try:
@@ -263,6 +267,7 @@ def test_kept_traces_cover_the_request_under_16_concurrent_callers(served):
             for i in range(per_caller):
                 resp, _ = post(conn, "/v1/authorize", sar(1000 * k + i))
                 assert resp.status == 200
+                ids.append(resp.headers["X-Cedar-Trace-Id"])
             conn.close()
         except Exception as e:  # noqa: BLE001 — reported below
             errors.append(e)
@@ -279,6 +284,11 @@ def test_kept_traces_cover_the_request_under_16_concurrent_callers(served):
     while len(served.records) < want and time.monotonic() < deadline:
         time.sleep(0.01)
     assert len(served.records) == want
+    return ids
+
+
+def test_kept_traces_cover_the_request_under_16_concurrent_callers(served):
+    authorize_from_threads(served, callers=16, per_caller=8)
     for rec in served.records:
         doc = served.tracer.get(rec.trace.trace_id)
         assert span_tree_coverage(doc) >= 0.95
@@ -299,6 +309,36 @@ def test_kept_traces_cover_the_request_under_16_concurrent_callers(served):
                 (t1 - t0) * 1e6, abs=0.11)
         assert doc["duration_us"] == pytest.approx(
             (rec.t_flush - rec.t_line) * 1e6, abs=0.11)
+
+
+def test_every_served_request_leaves_its_log_line_through_the_sink(served):
+    """The serving log's sink as main() installs it (the root logger's
+    handler): N distinct SARs from 8 threads leave exactly N
+    ``authorize requestId=`` lines, their ids the N X-Cedar-Trace-Ids the
+    callers were handed, and each request still has its ``write`` phase."""
+    want = 8 * 12
+    stream = io.StringIO()
+    sink = logsink.LogSink(stream).start()
+    root = logging.getLogger()
+    level = root.level
+    root.addHandler(sink)
+    root.setLevel(logging.INFO)
+    writes_before = metrics.request_phase_seconds.totals().get(
+        ("authorization", "write"), (0.0, 0))[1]
+    try:
+        ids = authorize_from_threads(served, callers=8, per_caller=12)
+        sink.drain()
+    finally:
+        root.removeHandler(sink)
+        sink.close()
+        root.setLevel(level)
+    logged = [line.split("requestId=")[1].split(" ")[0]
+              for line in stream.getvalue().splitlines()
+              if " cedar_tpu.server.http INFO authorize requestId=" in line]
+    assert len(ids) == len(set(ids)) == want
+    assert sorted(logged) == sorted(ids)
+    assert metrics.request_phase_seconds.totals()[
+        ("authorization", "write")][1] - writes_before == want
 
 
 def test_stage_histograms_and_spans_share_the_sub_stage_stamps(served):
