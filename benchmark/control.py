@@ -39,13 +39,15 @@ from benchmark.refpool import ReferencePool  # noqa: E402
 def control_counts(manifest: Manifest, workload: str, seed: int) -> dict:
     w = manifest.workload(workload)
     cfg = manifest.config(w["config"])
-    corpus = corpus_module(cfg["corpus"]["generator"]).build(cfg["corpus"]["params"], seed)
+    corpus = corpus_module(cfg["corpus"]["generator"], manifest.dir).build(
+        cfg["corpus"]["params"], seed)
     plan = traffic.Plan(corpus, manifest.traffic(w["traffic"]), manifest.cell(w["name"]),
-                        seed, float(manifest.doc["run_seconds"]))
+                        seed, float(manifest.doc["run_seconds"]), bench_dir=manifest.dir)
     indices = plan.precompute_indices()
     answers = {}
     for control in ("",) + CONTROLS:
-        pool = ReferencePool(corpus.files, workers=len(os.sched_getaffinity(0)), control=control)
+        pool = ReferencePool(corpus.files, workers=len(os.sched_getaffinity(0)),
+                             kind_ref=plan.kind_ref, control=control)
         try:
             pool.submit(plan.specs, indices)
             answers[control] = pool.collect()

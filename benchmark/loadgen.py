@@ -23,20 +23,19 @@ import ssl
 import threading
 import time
 
-from benchmark.reference import served_verdict
+from benchmark.manifest import kind_module
 
 REQUEST_TIMEOUT_S = 60.0
-PATH = "/v1/authorize"
 
 
 class Connection:
     """One keep-alive HTTPS connection speaking just enough HTTP/1.1."""
 
-    def __init__(self, host: str, port: int, cafile: str):
+    def __init__(self, host: str, port: int, cafile: str, path: str):
         self.host, self.port = host, port
         self.ctx = ssl.create_default_context(cafile=cafile)
         self.head = (
-            f"POST {PATH} HTTP/1.1\r\nHost: {host}:{port}\r\n"
+            f"POST {path} HTTP/1.1\r\nHost: {host}:{port}\r\n"
             "Content-Type: application/json\r\nContent-Length: "
         ).encode()
         self.sock = None
@@ -97,12 +96,15 @@ def _sleep_until(t: float) -> None:
 
 
 class Worker:
-    """One generator process's share of a plan, run by its threads."""
+    """One generator process's share of a plan, run by its threads.
+    ``spec["kind"]`` is the request kind's (name, directory): this process
+    imports it itself, for its request line and to read the answers."""
 
     def __init__(self, spec: dict):
         self.spec = spec
+        self.kind = kind_module(*spec["kind"])
         self.conns = [
-            Connection(spec["host"], spec["port"], spec["cafile"])
+            Connection(spec["host"], spec["port"], spec["cafile"], self.kind.PATH)
             for _ in range(spec["threads"])
         ]
         self.records = []  # (index, due, sent, done, status, raw body | None)
@@ -178,7 +180,7 @@ class Worker:
             note = ""
             if status == 200:
                 try:
-                    verdict = served_verdict(json.loads(raw))
+                    verdict = self.kind.verdict(json.loads(raw))
                 except ValueError:
                     note = "unreadable body"
             else:

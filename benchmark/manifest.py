@@ -4,7 +4,8 @@ A cell ``<config>.<traffic>`` is one entry of ``workloads`` plus
 ``configs/<config>.json``, ``traffic/<traffic>.json`` and
 ``cells/<cell>.json``; a per-layer metric is one entry of ``per_layer``
 plus ``metrics/<name>.json`` (the same entry, with its reader and the
-reader's parameters).
+reader's parameters). Code comes in as files too: a corpus generator
+(``corpora/``), a reader (``readers/``), a request kind (``kinds/``).
 Everything is found by name, so a later change adds files and entries and
 edits nothing that is here.
 """
@@ -12,6 +13,7 @@ edits nothing that is here.
 from __future__ import annotations
 
 import importlib
+import importlib.util
 import json
 import pathlib
 import re
@@ -24,6 +26,9 @@ UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = ("device_trace", "program_span", "program_counter", "host_clock")
 TOP_KEYS = {"command", "paths", "run_seconds", "configs", "workloads",
             "end_to_end", "per_layer"}
+# the directories of code that is found by name, and what each holds
+CODE = {"corpora": "corpus generator", "readers": "reader", "kinds": "request kind"}
+DEFAULT_KIND = "sar"  # the kind of a mix that names none
 
 
 class ManifestError(Exception):
@@ -71,6 +76,10 @@ class Manifest:
     def metric_file(self, name: str) -> dict:
         return load_json(self.dir / "metrics" / f"{name}.json")
 
+    def kind_of(self, traffic: str) -> str:
+        """The name of the request kind that a mix sends."""
+        return self.traffic(traffic).get("kind", DEFAULT_KIND)
+
     def metrics_for(self, workload: str, group: str) -> list:
         """The entries of ``end_to_end`` or ``per_layer`` that this cell
         reports: those that list it, and those that list no cells."""
@@ -90,18 +99,54 @@ class Manifest:
         return out
 
 
-def corpus_module(name: str):
-    """``benchmark/corpora/<name>.py``, found by name."""
-    if not NAME.match(name):
-        raise ManifestError(f"bad corpus generator name {name!r}")
-    return importlib.import_module(f"benchmark.corpora.{name}")
+_FROM_ROOT: dict = {}  # path -> module, for code that came in under --root
 
 
-def reader_module(name: str):
-    """``benchmark/readers/<name>.py``, found by name."""
-    if not NAME.match(name):
-        raise ManifestError(f"bad reader name {name!r}")
-    return importlib.import_module(f"benchmark.readers.{name}")
+def code_path(sub: str, name: str, bench_dir=None):
+    """Where ``<sub>/<name>.py`` is: in this package, or — for a name the
+    package does not have — under ``bench_dir``; None if in neither. The
+    package's own comes first, so code under ``--root`` adds and never
+    replaces: the yardstick is what is here."""
+    if not isinstance(name, str) or not NAME.match(name):
+        raise ManifestError(f"bad {CODE[sub]} name {name!r}")
+    for base in (HERE, bench_dir):
+        if base is not None:
+            path = pathlib.Path(base) / sub / f"{name}.py"
+            if path.is_file():
+                return path
+    return None
+
+
+def code_module(sub: str, name: str, bench_dir=None):
+    """``<sub>/<name>.py``, found by name (``code_path``'s rule)."""
+    path = code_path(sub, name, bench_dir)
+    if path is None:
+        raise ManifestError(f"no {CODE[sub]} {name!r}: no {sub}/{name}.py")
+    if path.parent.parent == HERE:
+        return importlib.import_module(f"benchmark.{sub}.{name}")
+    path = path.resolve()
+    if path not in _FROM_ROOT:
+        spec = importlib.util.spec_from_file_location(
+            f"benchmark_root.{sub}.{name.replace('.', '_').replace('-', '_')}", path)
+        module = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(module)
+        _FROM_ROOT[path] = module
+    return _FROM_ROOT[path]
+
+
+def corpus_module(name: str, bench_dir=None):
+    """``corpora/<name>.py``: ``build(params, seed)``."""
+    return code_module("corpora", name, bench_dir)
+
+
+def reader_module(name: str, bench_dir=None):
+    """``readers/<name>.py``: ``read(ctx, params)``."""
+    return code_module("readers", name, bench_dir)
+
+
+def kind_module(name: str, bench_dir=None):
+    """``kinds/<name>.py``: what one request is (``kinds/sar.py``)."""
+    return code_module("kinds", name, bench_dir)
 
 
 # ------------------------------------------------------------- validation
@@ -115,6 +160,14 @@ def _check_line(problems: list, what: str, value) -> None:
     if (not isinstance(value, str) or not 1 <= len(value) <= 200
             or "\n" in value or "\t" in value):
         problems.append(f"{what}: not one line of 1 to 200 characters")
+
+
+def _check_code(problems: list, what: str, sub: str, name, bench_dir) -> None:
+    try:
+        if code_path(sub, name, bench_dir) is None:
+            problems.append(f"{what}: no {sub}/{name}.py")
+    except ManifestError as e:
+        problems.append(f"{what}: {e}")
 
 
 def validate(m: Manifest) -> list:
@@ -163,8 +216,11 @@ def validate(m: Manifest) -> list:
         if not under_paths(c["file"]) or c["file"] in files:
             problems.append(f"config {c['name']}: file {c['file']!r}")
         files.add(c["file"])
-        if not (m.root / c["file"]).is_file():
-            problems.append(f"config {c['name']}: {c['file']} does not exist")
+        try:
+            generator = (load_json(m.root / c["file"]).get("corpus") or {}).get("generator")
+            _check_code(problems, f"config {c['name']}", "corpora", generator, m.dir)
+        except ManifestError as e:
+            problems.append(f"config {c['name']}: {e}")
         if len(c["reduced"]) > 16:
             problems.append(f"config {c['name']}: more than 16 reduced keys")
         for key in c["reduced"]:
@@ -194,6 +250,11 @@ def validate(m: Manifest) -> list:
         for sub in ("traffic/" + w["traffic"], "cells/" + w["name"]):
             if not (m.dir / f"{sub}.json").is_file():
                 problems.append(f"workload {w['name']}: no {sub}.json")
+        try:
+            _check_code(problems, f"workload {w['name']}: traffic/{w['traffic']}.json",
+                        "kinds", m.kind_of(w["traffic"]), m.dir)
+        except ManifestError:
+            pass  # no such mix: named above
     for name in configs:
         if not any(w.get("config") == name for w in d["workloads"]):
             problems.append(f"config {name} has no cell")
@@ -267,8 +328,7 @@ def validate(m: Manifest) -> list:
             continue
         if {k: v for k, v in spec.items() if k not in ("reader", "params")} != x:
             problems.append(f"per_layer {x['name']}: metrics/{x['name']}.json and its entry differ")
-        if not (HERE / "readers" / f"{spec.get('reader')}.py").is_file():
-            problems.append(f"per_layer {x['name']}: no readers/{spec.get('reader')}.py")
+        _check_code(problems, f"per_layer {x['name']}", "readers", spec.get("reader"), m.dir)
     for wl in cells:
         got = [x["name"] for x in m.metrics_for(wl, "end_to_end")]
         if "setup_s" not in got or len(got) < 2:

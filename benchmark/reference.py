@@ -1,18 +1,21 @@
-"""The plain reference: Cedar authorization of a SubjectAccessReview.
+"""The plain reference: Cedar authorization of one request's environment.
 
 A straightforward implementation of the semantics the configurations state
 — every answer is the Cedar decision (forbid overrides permit, no matching
 policy is no opinion) with the set of determining policies as its reason —
-written against the Cedar language and the webhook's documented mapping of
-a SubjectAccessReview onto entities, and importing nothing of the program.
-It reads the same ``*.cedar`` files the server loads.
+written against the Cedar language and importing nothing of the program.
+It reads the same ``*.cedar`` files the server loads. What a request *is* —
+the webhook's documented mapping of a review object onto principal, action,
+resource, context and entities, the rules that answer before Cedar is asked,
+and the tuple that is compared — belongs to the request's kind
+(``benchmark/kinds/<kind>.py``), which is part of the reference too.
 
 Covered: ``permit``/``forbid``, scopes (``is``, ``in``, ``==``, action
 lists), ``when``/``unless``, ``&&`` ``||`` ``!`` ``==`` ``!=`` ``has``
 ``in``, attribute access, ``contains`` / ``containsAll`` / ``containsAny``,
 string, integer and boolean literals, sets, records and entity literals.
-Anything else in a policy that could apply to a SubjectAccessReview is an
-error, never a silent skip.
+Anything else in a policy that could apply to a request is an error, never
+a silent skip.
 
 ``Reference(files, control=...)`` breaks one stated guarantee on purpose;
 that is the control the comparison has to fail (``benchmark/control.py``).
@@ -22,15 +25,6 @@ from __future__ import annotations
 
 import json
 import re
-
-USER = "k8s::User"
-GROUP = "k8s::Group"
-NODE = "k8s::Node"
-SERVICE_ACCOUNT = "k8s::ServiceAccount"
-ACTION = "k8s::Action"
-RESOURCE = "k8s::Resource"
-NON_RESOURCE = "k8s::NonResourceURL"
-LABEL_OPS = {"In": "in", "NotIn": "notin", "Exists": "exists", "DoesNotExist": "!"}
 
 CONTROLS = ("first_reason_only", "forbid_blind")
 
@@ -363,67 +357,7 @@ def parse_policies(text: str) -> list:
     return out
 
 
-# ------------------------------------------------- SubjectAccessReview side
-
-def sar_environment(spec: dict) -> dict:
-    """The webhook's mapping of a SubjectAccessReview spec onto Cedar
-    entities (cedar-access-control-for-k8s: users with their groups as
-    parents, the verb as a k8s::Action, resourceAttributes as a
-    k8s::Resource whose empty attributes are absent)."""
-    name = spec.get("user", "")
-    groups = frozenset(Entity((GROUP, g)) for g in spec.get("groups") or ())
-    ptype, attrs = USER, {"name": name}
-    if name.startswith("system:node:") and name.count(":") == 2:
-        ptype, attrs = NODE, {"name": name.split(":")[2]}
-    elif name.startswith("system:serviceaccount:") and name.count(":") == 3:
-        parts = name.split(":")
-        ptype, attrs = SERVICE_ACCOUNT, {"namespace": parts[2], "name": parts[3]}
-    extra = spec.get("extra") or {}
-    if extra:
-        attrs["extra"] = frozenset(
-            record({"key": k, "values": frozenset(v)}) for k, v in extra.items()
-        )
-    principal = Entity((ptype, spec.get("uid") or name))
-    entities = {principal: (record(attrs), groups)}
-    for g in groups:
-        entities[g] = (record({"name": g[1]}), frozenset())
-    ra = spec.get("resourceAttributes")
-    if ra:
-        verb = ra.get("verb", "")
-        if verb == "impersonate":
-            raise ReferenceError_("impersonation requests are not covered by this reference")
-        rattrs = {"apiGroup": ra.get("group", ""), "resource": ra.get("resource", "")}
-        for key in ("name", "subresource", "namespace"):
-            if ra.get(key):
-                rattrs[key] = ra[key]
-        reqs = (ra.get("labelSelector") or {}).get("requirements") or ()
-        selector = frozenset(
-            record({"key": r.get("key", ""),
-                    "operator": LABEL_OPS[r["operator"]],
-                    "values": frozenset(r.get("values") or ())})
-            for r in reqs if r.get("operator") in LABEL_OPS
-        )
-        if selector:
-            rattrs["labelSelector"] = selector
-        if (ra.get("fieldSelector") or {}).get("requirements"):
-            raise ReferenceError_("field selectors are not covered by this reference")
-        resource = Entity((RESOURCE, "resource"))
-    else:
-        nra = spec.get("nonResourceAttributes") or {}
-        verb = nra.get("verb", "")
-        rattrs = {"path": nra.get("path", "")}
-        resource = Entity((NON_RESOURCE, rattrs["path"]))
-    entities[resource] = (record(rattrs), frozenset())
-    return {
-        "principal": principal,
-        "action": Entity((ACTION, verb)),
-        "resource": resource,
-        "context": Record(),
-        "entities": entities,
-        "verb": verb,
-        "user": name,
-    }
-
+# ------------------------------------------------------------- evaluation
 
 def _scope(clause: dict):
     """A scope clause as (type or None, frozenset of entities or None): the
@@ -456,16 +390,11 @@ class Reference:
                     [(kind == "when", cond) for kind, cond in p["conditions"]],
                 ))
 
-    def decide(self, spec: dict) -> tuple:
-        """(allowed, denied, frozenset of determining policy ids)."""
-        user = spec.get("user", "")
-        if (
-            user.startswith("system:")
-            and not user.startswith("system:serviceaccount:")
-            and not user.startswith("system:node:")
-        ):
-            return (False, False, frozenset())
-        env = sar_environment(spec)
+    def evaluate(self, env: dict) -> tuple:
+        """(``"allow"`` | ``"deny"`` | None, the determining policies' ids in
+        store order) for one environment: ``principal``, ``action``,
+        ``resource``, ``context`` and ``entities`` (entity -> (attributes,
+        ancestors)), as the request's kind maps them."""
         # each scope variable as (entity, the entity with its ancestors)
         scoped = []
         for var in ("action", "principal", "resource"):
@@ -492,28 +421,11 @@ class Reference:
         if self.control == "forbid_blind":
             forbids = []
         if forbids:
-            allowed, denied, reasons = False, True, forbids
+            decision, reasons = "deny", forbids
         elif permits:
-            allowed, denied, reasons = True, False, permits
+            decision, reasons = "allow", permits
         else:
-            return (False, False, frozenset())
+            return (None, [])
         if self.control == "first_reason_only":
             reasons = reasons[:1]
-        return (allowed, denied, frozenset(reasons))
-
-
-def served_verdict(response: dict) -> tuple:
-    """A served SubjectAccessReview response in the reference's terms:
-    (allowed, denied, frozenset of policy ids); an evaluationError or an
-    unreadable reason makes a verdict no reference answer equals."""
-    st = response.get("status") or {}
-    reason = st.get("reason", "")
-    ids = frozenset()
-    if reason:
-        try:
-            ids = frozenset(r["policy"] for r in json.loads(reason)["reasons"])
-        except (ValueError, KeyError, TypeError):
-            ids = frozenset({f"unreadable reason: {reason[:80]}"})
-    if st.get("evaluationError"):
-        ids = ids | {f"evaluationError: {st['evaluationError'][:80]}"}
-    return (bool(st.get("allowed")), bool(st.get("denied")), ids)
+        return (decision, reasons)

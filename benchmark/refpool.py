@@ -15,15 +15,17 @@ import multiprocessing
 import os
 import signal
 
+from benchmark.manifest import kind_module
 from benchmark.reference import Reference
 
 
-def _work(files: dict, control: str, cpus, items: list, pipe) -> None:
+def _work(files: dict, control: str, kind_ref: tuple, cpus, items: list, pipe) -> None:
     try:
         if cpus:
             os.sched_setaffinity(0, cpus)
         ref = Reference(files, control=control)
-        pipe.send(("done", [(i, ref.decide(spec)) for i, spec in items]))
+        kind = kind_module(*kind_ref)
+        pipe.send(("done", [(i, kind.expected(ref, spec)) for i, spec in items]))
     except BaseException as e:  # noqa: BLE001 — reported to the parent, then re-raised
         pipe.send(("error", repr(e)))
         raise
@@ -32,8 +34,13 @@ def _work(files: dict, control: str, cpus, items: list, pipe) -> None:
 
 
 class ReferencePool:
-    def __init__(self, files: dict, workers: int, cpus=None, control: str = ""):
+    """``kind_ref`` is the request kind's (name, directory), as a plan
+    carries it: each process imports the kind itself."""
+
+    def __init__(self, files: dict, workers: int, kind_ref: tuple, cpus=None,
+                 control: str = ""):
         self.files, self.workers, self.cpus, self.control = files, workers, cpus, control
+        self.kind_ref = kind_ref
         self.answers: dict = {}
         self.running: list = []  # (process, pipe)
 
@@ -48,7 +55,7 @@ class ReferencePool:
             mine, theirs = ctx.Pipe(duplex=False)
             p = ctx.Process(
                 target=_work, daemon=True,
-                args=(self.files, self.control, self.cpus, todo[k::n], theirs),
+                args=(self.files, self.control, self.kind_ref, self.cpus, todo[k::n], theirs),
             )
             p.start()
             theirs.close()

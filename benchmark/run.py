@@ -318,7 +318,7 @@ class Generators:
                 "host": "127.0.0.1", "port": server.port, "cafile": cafile,
                 "threads": threads, "loop": plan.loop, "items": shares[k],
                 "seconds": plan.seconds, "warmup_s": plan.warmup_s,
-                "cpu": cores["generators"][k],
+                "cpu": cores["generators"][k], "kind": plan.kind_ref,
             }
             mine, theirs = ctx.Pipe()
             p = ctx.Process(target=loadgen.process_main, args=(spec, theirs), daemon=True)
@@ -378,17 +378,11 @@ class Context:
         self.device: dict = {}
 
 
-def gave_up(verdict) -> bool:
-    """The program's own word that it gave up: its deadline, a shed
-    request, a crash (``served_verdict`` puts it among the reason's ids)."""
-    return any(str(i).startswith("evaluationError") for i in verdict[2])
-
-
-def answered(record) -> bool:
-    """A 200 whose body read as a verdict that does not say the program
-    gave up."""
+def answered(record, kind) -> bool:
+    """A 200 whose body read as a verdict that, by the request's kind, does
+    not say the program gave up."""
     verdict = record[5]
-    return record[4] == 200 and verdict is not None and not gave_up(verdict)
+    return record[4] == 200 and verdict is not None and not kind.gave_up(verdict)
 
 
 def window_numbers(plan, records: list, t0: float) -> dict:
@@ -399,13 +393,14 @@ def window_numbers(plan, records: list, t0: float) -> dict:
         window = [r for r in records if r[1] >= t0 - 1e-9]
     else:
         window = [r for r in records if r[3] >= t0]
-    ok = [r for r in window if answered(r)]
+    ok = [r for r in window if answered(r, plan.kind)]
     latencies = [r[3] - r[1] for r in window]
     if len(ok) < len(window):
         # a failed request is in every tail at the window's longest: a
         # refusal that comes back at once is not a fast answer
         longest = max(latencies)
-        latencies = [longest if not answered(r) else v for r, v in zip(window, latencies)]
+        latencies = [longest if not answered(r, plan.kind) else v
+                     for r, v in zip(window, latencies)]
     late = [r[2] - r[1] for r in window]
     out = {
         "attempted": len(window),
@@ -425,8 +420,13 @@ def window_numbers(plan, records: list, t0: float) -> dict:
     return out
 
 
-def compare(records: list, answers: dict) -> dict:
-    """Every answer received against the reference's, one by one."""
+def _printable(verdict) -> list:
+    return [sorted(v) if isinstance(v, (set, frozenset)) else v for v in verdict]
+
+
+def compare(records: list, answers: dict, kind) -> dict:
+    """Every answer received against the reference's, one by one: tuple
+    equality, whatever the request's kind puts in the tuple."""
     mismatched, unanswered, with_error, examples = 0, 0, 0, []
     for idx, _due, sent, done, status, verdict, note in records:
         ms = round(1e3 * (done - sent), 1)
@@ -439,12 +439,11 @@ def compare(records: list, answers: dict) -> dict:
         if tuple(verdict) != tuple(want):
             mismatched += 1
             # told apart from an answer that is plainly another
-            with_error += gave_up(verdict)
+            with_error += kind.gave_up(verdict)
             if len(examples) < 5:
                 examples.append({
                     "index": idx, "ms": ms,
-                    "got": [verdict[0], verdict[1], sorted(verdict[2])],
-                    "want": [want[0], want[1], sorted(want[2])],
+                    "got": _printable(verdict), "want": _printable(want),
                 })
     return {"mismatched": mismatched, "unanswered": unanswered,
             "with_error": with_error, "compared": len(records), "examples": examples}
@@ -471,11 +470,13 @@ def run(args, manifest: Manifest, out: pathlib.Path, state: dict) -> dict:
     corpus_params = dict(cfg["corpus"]["params"])
     if args.policies:
         corpus_params["policies"] = args.policies
-    corpus = corpus_module(cfg["corpus"]["generator"]).build(corpus_params, args.seed)
+    corpus = corpus_module(cfg["corpus"]["generator"], manifest.dir).build(
+        corpus_params, args.seed)
     config_path = write_store(out, corpus)
-    plan = traffic.Plan(corpus, mix, cell, args.seed, seconds)
+    plan = traffic.Plan(corpus, mix, cell, args.seed, seconds, bench_dir=manifest.dir)
     log(f"corpus: {len(corpus.files)} files; plan: {plan.loop} loop, "
-        f"{len(plan.bodies)} bodies, {plan.connections} connections")
+        f"{len(plan.bodies)} bodies of kind {plan.kind_ref[0]} for {plan.kind.PATH}, "
+        f"{plan.connections} connections")
 
     # ---- the server child, and the reference's answers while it loads
     server = state["server"] = Server(
@@ -484,7 +485,8 @@ def run(args, manifest: Manifest, out: pathlib.Path, state: dict) -> dict:
     )
     ctx = Context()
     pool = state["pool"] = ReferencePool(
-        corpus.files, workers=len(pool_cpus) if pool_cpus else 2, cpus=pool_cpus,
+        corpus.files, workers=len(pool_cpus) if pool_cpus else 2,
+        kind_ref=plan.kind_ref, cpus=pool_cpus,
     )
     pool.submit(plan.specs, plan.precompute_indices())
     docs, t_ready = wait_warm(server, READY_DEADLINE_S)
@@ -566,7 +568,7 @@ def run(args, manifest: Manifest, out: pathlib.Path, state: dict) -> dict:
     win = window_numbers(plan, records, t0)
     if not win["attempted"]:
         raise RunFailure("no request fell inside the window")
-    cmp_ = compare(records, answers)
+    cmp_ = compare(records, answers, plan.kind)
     log("window: " + ", ".join(
         f"{k} {win[k]:.3f}" for k in ("latency_p50_ms", "latency_p95_ms",
                                       "client_latency_p99_ms", "client_latency_max_ms",
@@ -622,7 +624,8 @@ def run(args, manifest: Manifest, out: pathlib.Path, state: dict) -> dict:
             if device["platform"] != "tpu" and m["source"] == "device_trace":
                 continue  # no device metric from a CPU run
             spec = manifest.metric_file(m["name"])
-            value = reader_module(spec["reader"]).read(ctx, spec.get("params", {}))
+            value = reader_module(spec["reader"], manifest.dir).read(
+                ctx, spec.get("params", {}))
         if value is not None:
             metrics[m["name"]] = {"value": value, "unit": m["unit"]}
     compared = {

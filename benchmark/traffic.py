@@ -4,10 +4,11 @@ A mix (``benchmark/traffic/<name>.json``) states the loop (``open`` with
 Poisson arrivals at the cell's ``rate_per_s``, or ``closed`` with one
 request in flight per connection), the number of connections and of the
 generator processes that share them, the share of requests aimed at a real
-policy, the warm-up, and how large a pool of distinct bodies a closed loop
-may draw. The corpus supplies the request
-attributes; this file makes them distinct, serializes them and schedules
-them. Everything follows from the seed.
+policy, the warm-up, how large a pool of distinct bodies a closed loop
+may draw, and the ``kind`` of request it sends (``benchmark/kinds/``;
+``sar`` where it names none). The corpus supplies the request attributes;
+the kind wraps them as the review object and says what makes one distinct;
+this file serializes and schedules them. Everything follows from the seed.
 
 Every seed gets the same multiset of inter-arrival gaps — the exponential
 distribution's quantiles at the cell's rate — in another order, so that
@@ -20,6 +21,8 @@ from __future__ import annotations
 import json
 import math
 import random
+
+from benchmark.manifest import DEFAULT_KIND, kind_module
 
 
 def schedule(rate_per_s: float, seconds: float, seed: int) -> list:
@@ -38,8 +41,8 @@ def schedule(rate_per_s: float, seconds: float, seed: int) -> list:
     return due
 
 
-def make_bodies(corpus, mix: dict, seed: int, n: int, tag: str) -> tuple:
-    """``n`` distinct SubjectAccessReview bodies (bytes) and their specs.
+def make_bodies(corpus, mix: dict, seed: int, n: int, tag: str, kind) -> tuple:
+    """``n`` distinct bodies (bytes) of the request ``kind`` and their specs.
     ``tag`` keeps the warm-up's bodies apart from the window's."""
     rng = random.Random(f"{seed}:bodies:{tag}")
     aimed = float(mix.get("aimed_share", 0.8))
@@ -47,23 +50,21 @@ def make_bodies(corpus, mix: dict, seed: int, n: int, tag: str) -> tuple:
     for i in range(n):
         spec = corpus.spec(rng, aimed)
         if mix.get("name_per_request", True):
-            # what makes every body distinct, so that the decision cache
-            # cannot answer: a kube-apiserver's own cache absorbs repeats
-            spec["resourceAttributes"]["name"] = f"{tag}-{i}"
+            kind.distinct(spec, f"{tag}-{i}")
         specs.append(spec)
-        bodies.append(json.dumps({
-            "apiVersion": "authorization.k8s.io/v1",
-            "kind": "SubjectAccessReview",
-            "spec": spec,
-        }).encode())
+        bodies.append(json.dumps(kind.body(spec)).encode())
     return bodies, specs
 
 
 class Plan:
-    """What the generator processes send in one run."""
+    """What the generator processes send in one run. ``bench_dir`` is where
+    a kind that this package does not have is looked for (``--root``)."""
 
     def __init__(self, corpus, mix: dict, cell: dict, seed: int, seconds: float,
-                 tag: str = "w"):
+                 tag: str = "w", bench_dir=None):
+        # (name, directory): what a spawned process needs to import the kind
+        self.kind_ref = (mix.get("kind", DEFAULT_KIND), str(bench_dir) if bench_dir else None)
+        self.kind = kind_module(*self.kind_ref)
         self.loop = mix["loop"]
         self.connections = int(mix["connections"])
         self.processes = int(mix["processes"])
@@ -88,7 +89,7 @@ class Plan:
             self.precompute = min(n_bodies, int(mix["precompute_per_s"] * span))
         else:
             raise ValueError(f"unknown loop {self.loop!r}")
-        self.bodies, self.specs = make_bodies(corpus, mix, seed, n_bodies, tag)
+        self.bodies, self.specs = make_bodies(corpus, mix, seed, n_bodies, tag, self.kind)
 
     def precompute_indices(self) -> list:
         """The bodies whose reference answers are worked out before the

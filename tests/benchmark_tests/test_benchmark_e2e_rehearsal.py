@@ -95,3 +95,19 @@ def test_the_saturate_cell_ends_with_its_end_to_end_metrics(tmp_path):
     assert 0 < line["metrics"]["decisions_per_s"]["value"] * 3 <= line["attempted"]
     assert line["compared"]["compared"]["value"] >= line["attempted"]
     assert "breakdown" not in line
+
+
+def test_the_bars_corpus_under_the_lone_mix_ends_with_the_latency_metrics(tmp_path):
+    """synth-10k.sar-lone, the first cell that came in as files and entries
+    only: the accepted configuration under the accepted lone mix."""
+    proc = run_cell(["--workload", "synth-10k.sar-lone", "--seed", "2900000029",
+                     "--seconds", "3", "--trace", "0", "--out", str(tmp_path / "o")]
+                    + REHEARSAL)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    line = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert line["correct"] is True and line["failed"] == 0
+    assert set(line["metrics"]) == {"latency_p50_ms", "latency_p95_ms", "setup_s"}
+    assert 0 < line["metrics"]["latency_p50_ms"]["value"] <= line["metrics"]["latency_p95_ms"]["value"]
+    assert line["attempted"] > 30
+    assert line["compared"]["compared"]["value"] >= line["attempted"]
+    assert "1 generator processes" in proc.stderr and "of kind sar for /v1/authorize," in proc.stderr

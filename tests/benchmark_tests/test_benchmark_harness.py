@@ -7,6 +7,7 @@ import json
 import pytest
 
 from benchmark import run, serve
+from benchmark.kinds import sar
 
 
 @pytest.mark.parametrize(
@@ -98,7 +99,7 @@ def test_a_stop_without_a_start_is_not_a_pause():
     ],
 )
 def test_what_counts_as_an_answer(status, verdict, want):
-    assert run.answered((0, 0.0, 0.0, 0.1, status, verdict, "")) is want
+    assert run.answered((0, 0.0, 0.0, 0.1, status, verdict, ""), sar) is want
 
 
 def test_the_runs_options_are_the_contracts_and_the_rehearsals():

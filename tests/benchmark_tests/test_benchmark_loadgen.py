@@ -13,6 +13,7 @@ import pytest
 
 from benchmark import loadgen, traffic
 from benchmark.corpora import selector
+from benchmark.kinds import sar
 
 OK_BODY = json.dumps({"status": {"allowed": True, "denied": False, "reason": ""}}).encode()
 
@@ -59,6 +60,7 @@ class FlakyServer:
         self.answers = 0
         self.lock = threading.Lock()
         self.accepted = 0
+        self.request_lines = set()
         ctx = ssl.SSLContext(ssl.PROTOCOL_TLS_SERVER)
         ctx.load_cert_chain(tls_dir / "s.crt", tls_dir / "s.key")
         self.ctx = ctx
@@ -89,6 +91,7 @@ class FlakyServer:
                         return
                     buf += chunk
                 head, _, rest = buf.partition(b"\r\n\r\n")
+                self.request_lines.add(head.split(b"\r\n")[0])
                 n = int([ln.split(b":")[1] for ln in head.split(b"\r\n")
                          if ln.lower().startswith(b"content-length")][0])
                 while len(rest) < n:
@@ -111,10 +114,10 @@ class FlakyServer:
         self.sock.close()
 
 
-def run_worker(server, tls_dir, loop, items, threads, seconds=1.0):
+def run_worker(server, tls_dir, loop, items, threads, seconds=1.0, kind=("sar", None)):
     spec = {"host": "127.0.0.1", "port": server.port, "cafile": str(tls_dir / "s.crt"),
             "threads": threads, "loop": loop, "items": items, "seconds": seconds,
-            "warmup_s": 0.0}
+            "warmup_s": 0.0, "kind": kind}
     w = loadgen.Worker(spec)
     w.connect()
     spec["t0"] = time.monotonic() + 0.05
@@ -134,6 +137,29 @@ def test_connections_are_opened_once_and_reused(tls):
     assert all(r[4] == 200 and r[5] == (True, False, frozenset()) for r in res["records"])
     # each request is timed from when it was due, and is not sent early
     assert all(r[2] >= r[1] and r[3] >= r[2] for r in res["records"])
+
+
+def test_the_request_line_and_the_reading_of_an_answer_are_the_kinds(tls, tmp_path):
+    """The generator knows no endpoint: the worker's spec names a kind, by
+    name and by the directory it came in under, and the process imports it."""
+    (tmp_path / "kinds").mkdir()
+    (tmp_path / "kinds" / "stub_review.py").write_text(
+        'PATH = "/v1/stub?timeout=30s"\n'
+        'def verdict(response):\n'
+        '    return ("stub", response["status"]["allowed"])\n')
+    items = [(i, 0.01 * i, b"{}") for i in range(6)]
+    lines, verdicts = {}, {}
+    for kind in (("sar", None), ("stub_review", str(tmp_path))):
+        server = FlakyServer(tls)
+        try:
+            res = run_worker(server, tls, "open", items, threads=2, kind=kind)
+        finally:
+            server.close()
+        lines[kind[0]] = server.request_lines
+        verdicts[kind[0]] = {r[5] for r in res["records"]}
+    assert lines["sar"] == {b"POST " + sar.PATH.encode() + b" HTTP/1.1"}
+    assert lines["stub_review"] == {b"POST /v1/stub?timeout=30s HTTP/1.1"}
+    assert verdicts == {"sar": {(True, False, frozenset())}, "stub_review": {("stub", True)}}
 
 
 @pytest.mark.parametrize("loop", ["open", "closed"])
@@ -196,6 +222,8 @@ def test_a_stall_reaches_every_request_that_was_due_in_an_open_loop(tls):
 
 
 class _Plan:
+    kind = sar
+
     def __init__(self, loop, seconds):
         self.loop, self.seconds = loop, seconds
 
@@ -256,6 +284,7 @@ def test_the_lone_mix_is_one_connection_of_one_process():
     shares = loadgen.split(plan, plan.processes, 1)
     assert [i for i, _ in shares[0][0]] == list(range(len(plan.bodies)))
     assert len(set(plan.bodies)) == len(plan.bodies) == 5000
+    assert plan.kind_ref == ("sar", None)             # the mix names no kind
     # every request of the selector mix that lists or watches carries a selector
     listing = [s for s in plan.specs if s["resourceAttributes"]["verb"] == "watch"]
     assert listing and all("labelSelector" in s["resourceAttributes"] for s in listing)

@@ -1,14 +1,20 @@
-"""The plain reference: its parser, its mapping of a SubjectAccessReview,
-its agreement with a second witness (the program's own interpreter, at a
-size both can hold), and the controls that must not agree with it."""
+"""The plain reference: its parser, the ``sar`` kind's mapping of a
+SubjectAccessReview, their agreement with a second witness (the program's
+own interpreter, at a size both can hold), the controls that must not agree
+with it, and the digests that pin what the accepted cells send and what the
+reference answers."""
 
+import hashlib
 import json
 import random
 
 import pytest
 
 from benchmark import reference as ref
+from benchmark import traffic
 from benchmark.corpora import selector, synth
+from benchmark.kinds import sar
+from benchmark.manifest import Manifest, corpus_module
 
 DEMO = ["demo-require-owner-label.cedar", "demo-combined-authz-admission.cedar"]
 
@@ -22,7 +28,7 @@ def spec(user="alice", groups=(), verb="get", group="", resource="pods", **ra):
 
 
 def decide(policy_text, s, control=""):
-    return ref.Reference({"p.cedar": policy_text}, control=control).decide(s)
+    return sar.expected(ref.Reference({"p.cedar": policy_text}, control=control), s)
 
 
 PERMIT_PODS = (
@@ -111,7 +117,7 @@ def test_label_selectors_map_to_sets_of_records(requirements, allowed):
 def test_policy_ids_count_within_each_file():
     r = ref.Reference({"b.cedar": PERMIT_PODS + PERMIT_PODS, "a.cedar": PERMIT_PODS,
                        "notes.txt": "not a policy"})
-    assert r.decide(spec())[2] == {"a.cedar.policy0", "b.cedar.policy0", "b.cedar.policy1"}
+    assert sar.expected(r, spec())[2] == {"a.cedar.policy0", "b.cedar.policy0", "b.cedar.policy1"}
 
 
 @pytest.mark.parametrize(
@@ -134,9 +140,9 @@ def test_the_demo_admission_policies_parse_and_never_answer_a_sar():
     assert len(r.policies) == 3
     # the one authorization policy among them: ci-bot may create configmaps
     s = spec(user="ci-bot", verb="create", resource="configmaps")
-    assert r.decide(s) == (True, False, {"demo-combined-authz-admission.cedar.policy0"})
-    assert r.decide(spec(user="bob", groups=["tenants"], verb="create",
-                         resource="configmaps"))[:2] == (False, False)
+    assert sar.expected(r, s) == (True, False, {"demo-combined-authz-admission.cedar.policy0"})
+    assert sar.expected(r, spec(user="bob", groups=["tenants"], verb="create",
+                                resource="configmaps"))[:2] == (False, False)
 
 
 @pytest.mark.parametrize(
@@ -154,7 +160,7 @@ def test_the_demo_admission_policies_parse_and_never_answer_a_sar():
     ],
 )
 def test_served_verdict(response, want):
-    allowed, denied, ids = ref.served_verdict(response)
+    allowed, denied, ids = sar.verdict(response)
     assert (allowed, denied, set(ids)) == want
 
 
@@ -181,7 +187,7 @@ def _program_interpreter(files, tmp_path):
 
     def answer(s):
         decision, reason = auth.authorize(get_authorizer_attributes({"spec": s}))
-        return ref.served_verdict(sar_response(decision, reason))
+        return sar.verdict(sar_response(decision, reason))
 
     def close():
         for s in stores.stores:
@@ -209,7 +215,7 @@ def test_reference_agrees_with_the_programs_interpreter(module, params, seed, tm
         for i in range(150):
             s = corpus.spec(rng, 0.8)
             s["resourceAttributes"]["name"] = f"o-{i}"
-            mine = plain.decide(s)
+            mine = sar.expected(plain, s)
             assert mine == answer(s), s
             decisions.add(mine[:2])
         assert len(decisions) >= 2  # the traffic is not all one answer
@@ -229,7 +235,7 @@ def test_a_control_disagrees_with_the_reference(module, params, control):
     broken = ref.Reference(corpus.files, control=control)
     rng = random.Random(11)
     specs = [corpus.spec(rng, 0.8) for _ in range(600)]
-    differ = sum(1 for s in specs if plain.decide(s) != broken.decide(s))
+    differ = sum(1 for s in specs if sar.expected(plain, s) != sar.expected(broken, s))
     assert differ > 0
 
 
@@ -248,3 +254,89 @@ def test_corpus_is_a_function_of_the_seed(module, params):
     assert a.files != c.files
     ra, rb = random.Random(1), random.Random(1)
     assert [a.spec(ra, 0.8) for _ in range(20)] == [b.spec(rb, 0.8) for _ in range(20)]
+
+
+def test_evaluate_answers_an_environment_whatever_kind_of_request_made_it():
+    """The evaluation proper takes principal, action, resource, context and
+    entities, and knows nothing of a review object: here they are made by
+    hand."""
+    user, ops = ref.Entity(("k8s::User", "u")), ref.Entity(("k8s::Group", "ops"))
+    thing = ref.Entity(("k8s::Resource", "resource"))
+    env = {
+        "principal": user, "action": ref.Entity(("k8s::Action", "get")), "resource": thing,
+        "context": ref.Record(),
+        "entities": {user: (ref.record({"name": "alice"}), frozenset({ops})),
+                     ops: (ref.record({"name": "ops"}), frozenset()),
+                     thing: (ref.record({"resource": "pods"}), frozenset())},
+    }
+    permits = ('permit (principal in k8s::Group::"ops", action, resource);'
+               'permit (principal, action, resource) when { principal.name == "alice" };')
+    forbid = 'forbid (principal, action, resource) when { resource.resource == "pods" };'
+    assert ref.Reference({"p.cedar": permits}).evaluate(env) == (
+        "allow", ["p.cedar.policy0", "p.cedar.policy1"])
+    assert ref.Reference({"p.cedar": permits + forbid}).evaluate(env) == (
+        "deny", ["p.cedar.policy2"])
+    assert ref.Reference({"p.cedar": forbid}).evaluate(dict(env, resource=user)) == (None, [])
+    # the controls live in the evaluation, so every kind's control is the same step
+    assert ref.Reference({"p.cedar": permits}, control="first_reason_only").evaluate(env) == (
+        "allow", ["p.cedar.policy0"])
+    assert ref.Reference({"p.cedar": permits + forbid}, control="forbid_blind").evaluate(env)[0] \
+        == "allow"
+
+
+def test_the_sar_kind_states_its_five_things():
+    assert sar.PATH == "/v1/authorize"
+    s = spec()
+    assert sar.body(s) == {"apiVersion": "authorization.k8s.io/v1",
+                           "kind": "SubjectAccessReview", "spec": s}
+    sar.distinct(s, "w-7")
+    assert s["resourceAttributes"]["name"] == "w-7"
+    gone = sar.verdict({"status": {"allowed": False, "evaluationError": "deadline exceeded"}})
+    assert sar.gave_up(gone) and not sar.gave_up(sar.verdict({"status": {"allowed": True}}))
+    # a non-resource request maps onto a k8s::NonResourceURL named by its path
+    policy = ('permit (principal, action == k8s::Action::"get", resource is k8s::NonResourceURL) '
+              'when { resource.path == "/healthz" };')
+    url = {"user": "alice", "nonResourceAttributes": {"path": "/healthz", "verb": "get"}}
+    assert decide(policy, url) == (True, False, {"p.cedar.policy0"})
+    assert decide(policy, spec()) == (False, False, frozenset())
+
+
+# Recorded from the parent's tree (PR 28, commit e26dc97) before any code moved
+# behind benchmark/kinds/sar.py: the SHA-256 over the concatenated bodies of
+# traffic.Plan at one second of window, and over the reference's answers to the
+# plan's first 200 specs, each as json.dumps([allowed, denied, sorted(ids)]).
+# What the accepted cells send, and what they are compared with, has not moved.
+RECORDED = [
+    ("synth-10k.sar-saturate", 29, 16000,
+     "7d48e65e5e3c7b89475731764ec0fa6202d184730456160d224564013b2d8963",
+     "af1f7bb9f1f6cceb33c1920239939cb3cd6e6b70cda7e222fcbed3ffc9689e88"),
+    ("synth-10k.sar-saturate", 2900000029, 16000,
+     "9112603c3d795b3244f3f0440a86b3e60de321895a77764005e3fe23b2b4ee24",
+     "b4b7cc4142fde62f2bf23ff038c352cf463f26da7ccfc96bd0c9c75a47fb26c4"),
+    ("selector-1k.sar-lone", 29, 4000,
+     "cdf11bfa4858a95bb2e81fb07168b1fceeef7490adf15fbd1a056a905ee2d706",
+     "e6916f6b99cbbebaa0edecd957845746f6ec4b30e0f8c942f6c5794bd77f2d8a"),
+    ("selector-1k.sar-lone", 2900000029, 4000,
+     "5a7817cbed918d422b46983d28ca5da57a461f305f3b4197cfc7a5f6337e662c",
+     "4db1fd3190a7ccea33e8edffe4db09a5e710f1fa81878d00b415f3f35a9fd339"),
+]
+
+
+@pytest.mark.parametrize("cell,seed,n_bodies,bodies,answers", RECORDED,
+                         ids=[f"{r[0]}-{r[1]}" for r in RECORDED])
+def test_the_accepted_cells_send_the_bytes_and_get_the_answers_recorded_before_the_move(
+        cell, seed, n_bodies, bodies, answers):
+    m = Manifest()
+    w = m.workload(cell)
+    cfg = m.config(w["config"])
+    corpus = corpus_module(cfg["corpus"]["generator"]).build(cfg["corpus"]["params"], seed)
+    plan = traffic.Plan(corpus, m.traffic(w["traffic"]), m.cell(cell), seed, 1.0)
+    assert plan.kind is sar
+    assert len(plan.bodies) == n_bodies
+    assert hashlib.sha256(b"".join(plan.bodies)).hexdigest() == bodies
+    plain = ref.Reference(corpus.files)
+    h = hashlib.sha256()
+    for s in plan.specs[:200]:
+        a = sar.expected(plain, s)
+        h.update(json.dumps([a[0], a[1], sorted(a[2])]).encode())
+    assert h.hexdigest() == answers

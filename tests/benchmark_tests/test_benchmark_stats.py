@@ -5,6 +5,7 @@ import math
 import pytest
 
 from benchmark import stats, traffic
+from benchmark.kinds import sar
 from benchmark.run import compare, window_numbers
 
 
@@ -94,6 +95,8 @@ def test_schedule_gaps_are_exponential():
 
 
 class _Plan:
+    kind = sar
+
     def __init__(self, loop, seconds):
         self.loop, self.seconds = loop, seconds
 
@@ -162,7 +165,7 @@ def test_compare_tells_a_given_up_answer_from_another_answer():
                _rec(1, 0.0, 0.0, 2.5, verdict=gave_up),
                _rec(2, 0.0, 0.0, 0.01, verdict=other),
                _rec(3, 0.0, 0.0, 0.01, status=0, verdict=None)]
-    out = compare(records, {0: allow, 1: allow, 2: allow, 3: allow})
+    out = compare(records, {0: allow, 1: allow, 2: allow, 3: allow}, sar)
     assert (out["mismatched"], out["with_error"], out["unanswered"]) == (2, 1, 1)
     assert out["compared"] == 4
     # each example says how long its answer took: a given-up one took the deadline
