@@ -133,7 +133,7 @@ class _StageTimes:
     segment); a stage's parts sum to its window."""
 
     __slots__ = (
-        "claimed", "first_enq", "rows", "sub", "part", "part_t0",
+        "claimed", "first_enq", "rows", "extras_max", "sub", "part", "part_t0",
         "encode0", "encode1", "dispatch0", "dispatch1",
         "decode0", "decode1", "eval0", "eval1",
     )
@@ -142,6 +142,9 @@ class _StageTimes:
         self.claimed = claimed
         self.first_enq: Optional[float] = None
         self.rows = 0
+        # the widest row's set-membership extras, where a native encode ran
+        # (obs.trace.note_encode_extras): `extras_max` on batch.encode
+        self.extras_max: Optional[int] = None
         self.sub: dict = {}
         self.part: Optional[str] = None
         self.part_t0 = 0.0

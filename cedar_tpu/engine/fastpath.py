@@ -41,7 +41,7 @@ from typing import Callable, List, NamedTuple, Optional, Sequence, Tuple
 import numpy as np
 
 from ..chaos.registry import chaos_fire
-from ..obs.trace import sub_stage
+from ..obs.trace import note_encode_extras, sub_stage
 from ..native import (
     F_ADM_ERROR,
     F_ADM_NS_SKIP,
@@ -464,6 +464,18 @@ class _RawFastPath:
         record_row_routing(p, "encoder_fallback", n_fallback)
         record_row_routing(p, "encoder_gate", n - n_fallback - n_ok)
 
+    def _record_extras(self, ok_counts, max_e: int) -> None:
+        """One chunk's set-membership extras (the natively encoded rows'
+        counts) -> cedar_encode_extras and `extras_max` on the
+        batch.encode span: how near the rows come to the encoder's cap. A
+        row past it is an encoder_fallback row of _record_routing."""
+        from ..server.metrics import record_encode_extras
+
+        record_encode_extras(
+            self._METRIC_PATH, int(ok_counts.sum()), len(ok_counts)
+        )
+        note_encode_extras(max_e)
+
     def _encode_chunk(self, snap: _Snapshot, bodies: Sequence[bytes]):
         """Host-only half of chunk preparation: C++ encode STRAIGHT INTO
         bucket-padded buffers acquired from the engine's staging pool —
@@ -535,9 +547,9 @@ class _RawFastPath:
             # trim the extras buffer to the live width (bucketed to avoid
             # retraces): most requests carry zero extras, and every padded
             # column costs a [B, E, L] broadcast-compare on device
-            max_e = int(
-                counts.max(initial=0) if all_ok else counts[idx].max(initial=0)
-            )
+            ok_counts = counts if all_ok else counts[idx]
+            max_e = int(ok_counts.max(initial=0))
+            self._record_extras(ok_counts, max_e)
             if max_e == 0:
                 E = 1
             else:

@@ -491,6 +491,8 @@ def span_windows(windows, times=None) -> list:
                 for k, v in sub.items()
                 if k.startswith(phase + ".")
             }
+        elif phase == "encode" and getattr(times, "extras_max", None) is not None:
+            attrs = {"extras_max": times.extras_max}
         out.append((name, t0, t1, attrs))
     return out
 
@@ -706,6 +708,17 @@ class _SubStage:
             times.part, times.part_t0 = self._outer, now
         if self._scope is not None:
             self._scope.__exit__(exc_type, exc, tb)
+
+
+def note_encode_extras(extras_max: int) -> None:
+    """The widest encoded row's extras onto the batch record bound to this
+    worker thread (a stage may encode several chunks: the largest stays);
+    nothing outside a batcher's encode stage."""
+    times = getattr(_stage_local, "times", None)
+    if times is not None and (
+        times.extras_max is None or extras_max > times.extras_max
+    ):
+        times.extras_max = extras_max
 
 
 def sub_stage(name: str):
@@ -938,6 +951,7 @@ __all__ = [
     "new_span_id",
     "new_trace_id",
     "note_batch_result",
+    "note_encode_extras",
     "parse_traceparent",
     "pipeline_stamps",
     "profiler_scope",

@@ -21,6 +21,7 @@ from __future__ import annotations
 import contextlib
 import json
 import logging
+import re
 import ssl
 import threading
 import time
@@ -109,6 +110,26 @@ def _admit_outcome(review) -> tuple:
         else ("allowed" if resp.get("allowed") else "denied")
     )
     return label, error
+
+
+# the envelope's own fields, which a kube-apiserver writes before the
+# objects: the request's kind ({"group", "version", "kind"}) and operation
+_ADMIT_KIND = re.compile(rb'"kind"\s*:\s*\{[^{}]*?"kind"\s*:\s*"([^"\\]*)"')
+_ADMIT_OPERATION = re.compile(rb'"operation"\s*:\s*"([A-Z]+)"')
+_ADMIT_HEAD_BYTES = 2048
+
+
+def _set_admit_attrs(root, body: bytes) -> None:
+    """``body_bytes``, ``operation`` and ``kind`` on an admission trace's
+    root. The native path never parses the review in Python, so the two
+    names are read from the envelope's head by pattern: absent where the
+    head does not hold them, never a parse."""
+    root.set_attr("body_bytes", len(body))
+    head = body[:_ADMIT_HEAD_BYTES]
+    for name, pattern in (("operation", _ADMIT_OPERATION), ("kind", _ADMIT_KIND)):
+        found = pattern.search(head)
+        if found is not None:
+            root.set_attr(name, found.group(1).decode("utf-8", "replace"))
 
 
 def _octx() -> Optional[dict]:
@@ -1231,8 +1252,10 @@ class WebhookServer:
         if trace is not None or self.audit_log is not None:
             _octx_set(octx)
         tenant = getattr(body, "tenant", "")
-        if tenant and trace is not None:
-            trace.root.set_attr("tenant", tenant)
+        if trace is not None:
+            if tenant:
+                trace.root.set_attr("tenant", tenant)
+            _set_admit_attrs(trace.root, body)
         review = None
         try:
             review = self._handle_admit(body, priority=priority)
@@ -1250,10 +1273,14 @@ class WebhookServer:
             latency = time.monotonic() - start
             if phases is not None:
                 phases.t_stop = start + latency
+            # unconditional, like the authorization path's finally — the
+            # timer and the per-tenant series must not depend on obs
+            # being wired
+            label, error = _admit_outcome(review)
+            metrics.record_admission_latency(
+                "error" if error else label, latency
+            )
             if tenant:
-                # unconditional, like the authorization path's finally —
-                # per-tenant series must not depend on obs being wired
-                label, _error = _admit_outcome(review)
                 metrics.record_tenant_request(
                     "admission", tenant, label, latency
                 )
@@ -1263,7 +1290,8 @@ class WebhookServer:
                 or self.audit_log is not None
             ):
                 self._finish_admit_obs(
-                    body, request_id, review, trace, octx, latency, phases,
+                    body, request_id, review, trace, octx, latency,
+                    label, error, phases,
                 )
 
     def _finish_trace(self, trace, phases, octx, label, errored) -> None:
@@ -1311,16 +1339,16 @@ class WebhookServer:
             log.exception("request phase record failed")
 
     def _finish_admit_obs(
-        self, body, request_id, review, trace, octx, latency, phases=None
+        self, body, request_id, review, trace, octx, latency, label, error,
+        phases=None,
     ) -> None:
         """Close out the admission request's observability surfaces
         (trace finish + tail-keep, SLO record, audit line) from the
-        rendered review — the decision facts are read back out of the
-        response the caller is already returning, so this can never
-        change an answer."""
+        rendered review — the decision facts (``label``, ``error``:
+        _admit_outcome) are read back out of the response the caller is
+        already returning, so this can never change an answer."""
         resp = (review or {}).get("response") or {}
         status = resp.get("status") or {}
-        label, error = _admit_outcome(review)
         if self.slo is not None:
             try:
                 self.slo.record("admission", latency, error is not None)
@@ -1628,6 +1656,8 @@ class WebhookServer:
                         else "admission" if path == "/v1/admit"
                         else None
                     )
+                    if phases is not None and path_label is not None:
+                        metrics.record_request_body_bytes(path_label, length)
                     priority = ""
                     if server.load is not None and path_label is not None:
                         # ingress overload gate (cedar_tpu/load,

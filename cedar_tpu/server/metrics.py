@@ -220,11 +220,25 @@ class Summary:
                 row[i] += cur - prev
                 prev = cur
 
-    def totals(self) -> Dict[Tuple[str, str], Tuple[float, int]]:
+    def observe(self, label: str, total: float, count: int = 1) -> None:
+        """``count`` observations that sum to ``total``, in a family of one
+        label (bytes a request, extras over a batch's rows)."""
+        with self._lock:
+            row = self._rows.get((label, ()))
+            if row is None:
+                row = self._rows[(label, ())] = [0.0, 0.0]
+            row[0] += count
+            row[1] += total
+
+    def totals(self) -> Dict[Tuple[str, ...], Tuple[float, int]]:
         """{label values: (sum, count)}."""
-        out: Dict[Tuple[str, str], List[float]] = {}
+        out: Dict[Tuple[str, ...], List[float]] = {}
         with self._lock:
             for (first, seconds), row in self._rows.items():
+                if not seconds:  # a family of one label: [count, sum]
+                    cell = out.setdefault((first,), [0.0, 0])
+                    cell[0] += row[1]
+                    cell[1] += int(row[0])
                 for i, second in enumerate(seconds):
                     cell = out.setdefault((first, second), [0.0, 0])
                     cell[0] += row[i + 1]
@@ -867,6 +881,52 @@ request_phase_seconds = REGISTRY.register(
         "next. Cut from the SAME stamps as the request's /debug/traces "
         "spans and cedar_pipeline_stage_seconds.",
         ["path", "phase"],
+    )
+)
+
+# The admission path's request timer and the sizes of what a request
+# carries (docs/observability.md "Request phases"): the phase ledger says
+# where an admission request's time went; these say how long the handler
+# held it, how large its body was and how many set-membership extras its
+# row took to the device.
+admission_request_latency = REGISTRY.register(
+    Histogram(
+        "cedar_admission_request_duration_seconds",
+        "Admission request latency in seconds partitioned by decision "
+        "(allowed / denied / error): the /v1/admit twin of "
+        "cedar_authorizer_request_duration_seconds, between the same two "
+        "stamps as the phases parse … respond of "
+        "cedar_request_phase_seconds{path=\"admission\"}.",
+        ["decision"],
+        [
+            0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25,
+            0.5, 1, 2.5, 5, 10,
+        ],
+    )
+)
+
+request_body_bytes = REGISTRY.register(
+    Summary(
+        "cedar_request_body_bytes",
+        "Bytes of a served request's body by path, observed once a "
+        "request where the body is read: _sum over _count is the mean "
+        "body. An AdmissionReview carries whole objects (2-40 KB where a "
+        "SubjectAccessReview is a few hundred bytes), and the native walk "
+        "and the JSON parse scale with it.",
+        ["path"],
+    )
+)
+
+encode_extras = REGISTRY.register(
+    Summary(
+        "cedar_encode_extras",
+        "Set-membership extras the native encoder emitted, by path: _sum "
+        "over the extras of every encoded row, _count the rows. A row "
+        "past the encoder's cap (32) leaves the native path and is "
+        "counted as encoder_fallback in "
+        "cedar_authorizer_row_routing_total; this family says how near "
+        "the rows come to it.",
+        ["path"],
     )
 )
 
@@ -1537,6 +1597,19 @@ def record_request_phases(path: str, names, stamps) -> None:
     names, and one boundary stamp more) into the phase ledger, under one
     lock."""
     request_phase_seconds.observe_steps(path, names, stamps)
+
+
+def record_admission_latency(decision: str, latency_s: float) -> None:
+    admission_request_latency.observe(latency_s, decision=decision)
+
+
+def record_request_body_bytes(path: str, n: int) -> None:
+    request_body_bytes.observe(path, n)
+
+
+def record_encode_extras(path: str, extras: int, rows: int) -> None:
+    if rows:
+        encode_extras.observe(path, extras, rows)
 
 
 def record_interpreter_wait(late_s: float, watched_s: float) -> None:

@@ -171,7 +171,12 @@ def _engine_stack(src=POLICIES, cache=False):
         evaluate=adm_engine.evaluate,
         evaluate_batch=adm_engine.evaluate_batch,
     )
-    server = WebhookServer(authorizer, handler, decision_cache=dc)
+    server = WebhookServer(
+        authorizer, handler, decision_cache=dc,
+        # ports of its own: the defaults are shared with every other test
+        # file that starts a server, and the files run side by side
+        port=0, metrics_port=0,
+    )
     return server, engine, adm_engine, dc
 
 
