@@ -1355,9 +1355,12 @@ def make_parser() -> argparse.ArgumentParser:
         "--pipeline-depth",
         type=int,
         default=2,
-        help="batches in flight through the three-stage evaluation "
-        "pipeline (encode / dispatch / decode overlap, "
-        "docs/performance.md); 0 restores the serial batch loop",
+        help="0 restores the serial batch loop; N > 0 runs the "
+        "three-stage evaluation pipeline (encode / dispatch / decode, "
+        "docs/performance.md) and bounds the batches launched and not "
+        "yet decoded at N. It does not let N encoded batches queue "
+        "before the launch: one claimed batch stands there, and the rest "
+        "of a backlog waits in the submit queue (the late claim)",
     )
     cedar.add_argument(
         "--encode-workers",

@@ -18,12 +18,15 @@ three-stage pipeline (docs/performance.md): batch N+1's host ENCODE runs on
 a small worker pool while batch N's device work is in flight, the DISPATCH
 thread launches each encoded batch asynchronously and immediately moves to
 the next, and a DECODE thread materializes results and completes each
-submitter's slot. Bounded depth-``depth`` queues between the stages provide
-backpressure — a slow device stalls the collector instead of growing an
-unbounded encoded-batch backlog. Submission semantics (deadline withdrawal,
-coalescing, drain-on-stop) are IDENTICAL to the serial batcher: both share
-one queue/slot front end, and the stages are required to produce the same
-results the serial batch fn would.
+submitter's slot. At most ONE claimed batch stands before the dispatch
+thread (the late claim, PipelinedBatcher): until that place is free the
+collector leaves requests in the submit queue, where they can still be
+withdrawn and join what arrives next; a bounded depth-``depth`` queue
+before the decode stage provides the backpressure behind the launch.
+Submission semantics (deadline withdrawal, coalescing, drain-on-stop) are
+IDENTICAL to the serial batcher: both share one queue/slot front end, and
+the stages are required to produce the same results the serial batch fn
+would.
 """
 
 from __future__ import annotations
@@ -89,6 +92,17 @@ def _record_occupancy(path: Optional[str], n: int) -> None:
         from ..server.metrics import record_batch_occupancy
 
         record_batch_occupancy(path, n)
+    except Exception:  # noqa: BLE001 — metrics must never break serving
+        pass
+
+
+def _record_claim(path: Optional[str], held: bool) -> None:
+    if path is None:
+        return
+    try:
+        from ..server.metrics import record_batch_claim
+
+        record_batch_claim(path, held)
     except Exception:  # noqa: BLE001 — metrics must never break serving
         pass
 
@@ -169,6 +183,35 @@ class _Slot:
         # claiming batch's shared stage-stamp record (None until claimed)
         self.t_enq = time.monotonic()
         self.times: Optional[_StageTimes] = None
+
+
+class _Place:
+    """The one standing place before the pipelined batcher's dispatch
+    thread (PipelinedBatcher's docstring). The collector takes it before
+    it claims a batch and waits for it on this object alone; the dispatch
+    thread frees it, stamping when — whether a claim was HELD is decided
+    from that stamp, not from when the collector's thread next ran.
+    Freeing a free place changes nothing, so every path a batch can leave
+    by may free it."""
+
+    __slots__ = ("_free", "freed_at")
+
+    def __init__(self):
+        self._free = threading.Event()
+        self._free.set()
+        self.freed_at = time.monotonic()
+
+    def free(self) -> None:
+        if not self._free.is_set():
+            self.freed_at = time.monotonic()
+            self._free.set()
+
+    def wait(self, timeout: float) -> bool:
+        """True once free (at once where it already is)."""
+        return self._free.wait(timeout)
+
+    def take(self) -> None:
+        self._free.clear()
 
 
 class MicroBatcher:
@@ -542,11 +585,14 @@ class MicroBatcher:
             # batch-forming window: let concurrent submitters pile in.
             # The window is a hook (_linger_window_s): the pipelined
             # batcher returns 0 while batches are already in flight —
-            # the device is the pacing clock then, and arrivals
-            # accumulate in the queue for free while it drains batch N,
-            # so the steady-state tick claims one fused batch with NO
-            # host linger added to its latency (device-side
-            # accumulation, docs/performance.md).
+            # its collector only gets here once the place before the
+            # dispatch thread is free (the late claim), so whatever
+            # arrived while the launch of batch N held that thread is
+            # already in the queue and rides one claim with NO host
+            # linger added to its latency. The pacing clock on the chip
+            # is that launch (5.68 of a dispatch's 6.11 ms at 32
+            # callers, the device 0.130 of them: ledger, PR 30), not
+            # the device (docs/performance.md).
             window = self._linger_window_s()
             if window > 0:
                 deadline = time.monotonic() + window
@@ -672,12 +718,37 @@ class PipelinedBatcher(MicroBatcher):
         that blocks on the device), decode, resolve deferred rows; runs on
         the decode thread, which completes each submitter's slot
 
-    so host decode of batch N overlaps device execution of batch N+1, and
-    encode of batch N+2 overlaps both. The inter-stage queues are bounded
-    at ``depth``: when the device falls behind, the collector blocks
-    putting into the dispatch queue (backpressure) instead of encoding an
-    unbounded backlog; the blocked time is published as
-    cedar_pipeline_stall_seconds_total{stage}.
+    so host decode of batch N overlaps the encode, the launch and the
+    device execution of batch N+1.
+
+    The late claim: at most ONE claimed batch stands before the dispatch
+    thread (being encoded, or encoded and waiting). The collector claims
+    the next batch only once that standing place is free — the dispatch
+    thread frees it once the standing batch's launch has returned and the
+    batch is handed to the decode stage — and until then requests stay in
+    the submit queue, where they cost nothing, can still be withdrawn at
+    their deadline, and join whatever else arrives before the claim. On the chip the one dispatch thread is the slowest
+    stage (162 batches/s x 6.11 ms = 0.99 s of dispatch a second at 32
+    callers; ledger, PR 30): the rule this replaced let ``depth`` encoded
+    batches queue before it plus one in the collector's hands, each a
+    launch of its own that every row behind it waited out (14 ms of a
+    33 ms cycle, 5.9 rows a launch). Freeing the place after the launch
+    costs the dispatch thread one encode of idling a batch and leaves a
+    request no launch to wait out but the one in progress when it
+    arrives and its own: 15 rows a launch and +22 to +25 % decisions a
+    second on that cell; freeing it earlier, as the dispatch thread
+    takes the standing batch, kept the encode overlapped, gave 7 rows a
+    launch and +2 % (my chip runs, PR 31; PERF.md section 6). The
+    collector waits for the place on an object of its own (_Place),
+    never on the submitters' condition and never holding it; the held
+    time is published as
+    cedar_pipeline_stall_seconds_total{stage="collect"} and how often it
+    engages as cedar_batch_claims_total{path, held}.
+
+    ``depth`` bounds the batches launched and not yet decoded (the queue
+    before the decode stage): when the device or the decode falls behind,
+    the dispatch thread blocks there with the place still taken, and the
+    backlog stays in the submit queue instead of in encoded batches.
 
     Error/drain contracts match the serial batcher exactly: a stage
     exception fails that batch's slots with per-waiter wrapped errors (the
@@ -768,8 +839,13 @@ class PipelinedBatcher(MicroBatcher):
         # reason.
         for stage in ("collect", "dispatch", "decode"):
             self.heartbeats.setdefault(stage, Heartbeat())
-        self._dispatch_q = _queue.Queue(maxsize=self.depth)
+        # the standing place before the dispatch thread (class docstring):
+        # one claimed batch, so the hand-off queue holds one. Per
+        # generation like the queues — a superseded stage can neither
+        # free nor hold the fresh generation's place.
+        self._dispatch_q = _queue.Queue(maxsize=1)
         self._decode_q = _queue.Queue(maxsize=self.depth)
+        self._place = _Place()
         epoch = self._epoch
         self._decoder = threading.Thread(
             target=self._run_decode, name="pipe-decode", daemon=True,
@@ -777,11 +853,14 @@ class PipelinedBatcher(MicroBatcher):
         )
         self._dispatcher = threading.Thread(
             target=self._run_dispatch, name="pipe-dispatch", daemon=True,
-            args=(epoch, self._dispatch_q, self._decode_q, self._decoder),
+            args=(
+                epoch, self._dispatch_q, self._decode_q, self._decoder,
+                self._place,
+            ),
         )
         self._thread = threading.Thread(
             target=self._run_collect, name="pipe-collect", daemon=True,
-            args=(epoch, self._dispatch_q, self._dispatcher),
+            args=(epoch, self._dispatch_q, self._dispatcher, self._place),
         )
         self._threads = [self._thread, self._dispatcher, self._decoder]
         for t in self._threads:
@@ -805,6 +884,8 @@ class PipelinedBatcher(MicroBatcher):
             old_threads = list(self._threads)
             old_qs = [self._dispatch_q, self._decode_q]
             self._cv.notify_all()
+            # a superseded collector waiting for the place wakes and exits
+            self._place.free()
         # wake + retire the surviving old stages: a sentinel unblocks a
         # blocked get, and the epoch check exits the loop
         shed = self._shed_queues(old_qs)
@@ -907,10 +988,10 @@ class PipelinedBatcher(MicroBatcher):
     def backlog(self) -> int:
         """Submitted-but-unanswered entries across the whole batcher:
         queued PLUS claimed into the pipeline stages. The adaptive batch
-        tuner's demand signal (cedar_tpu/load/tuner.py) — under
-        saturation most waiting happens inside the stage hand-off
-        queues, which queue_fill() (the router's pre-claim load signal)
-        deliberately excludes."""
+        tuner's demand signal (cedar_tpu/load/tuner.py). Since the late
+        claim a backlog waits in the submit queue, so queue_fill() (the
+        router's pre-claim load signal) sees most of it; the sum here is
+        what it was."""
         with self._inflight_lock:
             entries = self._inflight_entries
         return self.queue_fill() + entries
@@ -945,22 +1026,55 @@ class PipelinedBatcher(MicroBatcher):
 
     # --------------------------------------------------------------- stages
 
-    def _run_collect(self, epoch, dispatch_q, dispatcher) -> None:
+    def _run_collect(self, epoch, dispatch_q, dispatcher, place) -> None:
         try:
-            self._collect_loop(epoch, dispatch_q, dispatcher)
+            self._collect_loop(epoch, dispatch_q, dispatcher, place)
         except BaseException:  # noqa: BLE001 — visibility, then unwind
             _record_worker_death("pipeline.collect", self.replica)
             raise
 
-    def _collect_loop(self, epoch, dispatch_q, dispatcher) -> None:
+    def _take_place(self, epoch, place, dispatcher) -> Optional[float]:
+        """Wait until the standing place before the dispatch thread is
+        free and take it. Returns when the wait began, or 0.0 where the
+        place was free at once; None when this generation was superseded
+        meanwhile. A dead dispatch thread never frees the place: the wait
+        ends and the claimed batch fails fast in _put, as it did before
+        the late claim."""
+        since = 0.0
+        if not place.wait(0):
+            since = time.monotonic()
+            while not place.wait(0.5):
+                if self._epoch != epoch:
+                    return None
+                if not dispatcher.is_alive():
+                    break
+        place.take()
+        return since
+
+    def _collect_loop(self, epoch, dispatch_q, dispatcher, place) -> None:
         hb = self.heartbeats["collect"]
         while True:
             hb.idle()
+            since = self._take_place(epoch, place, dispatcher)
+            if since is None:
+                break
             batch = self._form_batch(epoch)
             if batch is None:
                 break
             if not batch:
+                place.free()  # nothing claimed: nothing stands in it
                 continue
+            # held: the collector had to wait for the place and work was
+            # in the queue before it came free (the batch's oldest member
+            # waited for the place, not for the window); the time so held
+            # is this stage's stall
+            held = 0.0
+            if since:
+                held = place.freed_at - max(
+                    since, batch[0][1].times.first_enq
+                )
+            _record_claim(self.metrics_path, held > 0)
+            self._stall("collect", held)
             # chaos kill seams OUTSIDE the per-batch containment: unwind
             # this stage like a real crash would
             chaos_fire("pipeline.collect")
@@ -974,15 +1088,11 @@ class PipelinedBatcher(MicroBatcher):
                     self._encode_timed, items, batch[0][1].times
                 )
             except RuntimeError as e:  # pool shut down under us
+                place.free()
                 self._fail_batch(batch, e)
                 continue
-            t0 = time.monotonic()
             self._inflight_add(1, len(batch))
-            ok = self._put(dispatch_q, (batch, fut), dispatcher)
-            # time blocked on a full dispatch queue = downstream (device or
-            # decode) backpressure reaching the collector
-            self._stall("collect", time.monotonic() - t0)
-            if not ok:
+            if not self._put(dispatch_q, (batch, fut), dispatcher):
                 self._inflight_add(-1, -len(batch))
                 self._fail_batch(
                     batch, RuntimeError("pipeline dispatch stage died")
@@ -990,14 +1100,18 @@ class PipelinedBatcher(MicroBatcher):
         if self._epoch == epoch:
             self._put(dispatch_q, _SENTINEL, dispatcher)
 
-    def _run_dispatch(self, epoch, dispatch_q, decode_q, decoder) -> None:
+    def _run_dispatch(
+        self, epoch, dispatch_q, decode_q, decoder, place
+    ) -> None:
         try:
-            self._dispatch_loop(epoch, dispatch_q, decode_q, decoder)
+            self._dispatch_loop(epoch, dispatch_q, decode_q, decoder, place)
         except BaseException:  # noqa: BLE001 — visibility, then unwind
             _record_worker_death("pipeline.dispatch", self.replica)
             raise
 
-    def _dispatch_loop(self, epoch, dispatch_q, decode_q, decoder) -> None:
+    def _dispatch_loop(
+        self, epoch, dispatch_q, decode_q, decoder, place
+    ) -> None:
         hb = self.heartbeats["dispatch"]
         while True:
             hb.idle()
@@ -1007,33 +1121,37 @@ class PipelinedBatcher(MicroBatcher):
                 # a real batch this get RACED away from revive's queue
                 # drain must still fail fast, not strand its waiters until
                 # their deadlines
+                place.free()
                 self._shed_superseded(item)
                 return
             # chaos seam after the queue get, outside any per-batch try
             chaos_fire("pipeline.dispatch_q")
             hb.busy()
             if item is _SENTINEL:
+                place.free()
                 self._put(decode_q, _SENTINEL, decoder)
                 return
             batch, fut = item
-            t0 = time.monotonic()
             try:
+                t0 = time.monotonic()
                 ctx = fut.result()  # wait for the encode worker
-            except BaseException as e:  # noqa: BLE001 — per-batch isolation
-                self._inflight_add(-1, -len(batch))
-                self._fail_batch(batch, e)
-                continue
-            # time waiting on the encode future = encode stage too slow to
-            # keep the device fed
-            self._stall("dispatch", time.monotonic() - t0)
-            try:
+                # time waiting on the encode future: since the late claim
+                # this thread idles for one encode a batch by design
+                self._stall("dispatch", time.monotonic() - t0)
                 with batch_stage(batch[0][1].times, "dispatch", len(batch)):
                     ctx = self.stages.pipeline_dispatch(ctx)
             except BaseException as e:  # noqa: BLE001 — per-batch isolation
+                place.free()
                 self._inflight_add(-1, -len(batch))
                 self._fail_batch(batch, e)
                 continue
-            if not self._put(decode_q, (batch, ctx), decoder):
+            # launched: the decode stage is handed its batch first, then
+            # the place is freed and the collector may claim the next one
+            # (class docstring: why not before). A full decode queue keeps
+            # the place taken: the backlog waits in the submit queue
+            handed = self._put(decode_q, (batch, ctx), decoder)
+            place.free()
+            if not handed:
                 self._inflight_add(-1, -len(batch))
                 self._fail_batch(
                     batch, RuntimeError("pipeline decode stage died")

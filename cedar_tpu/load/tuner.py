@@ -137,10 +137,9 @@ class AdaptiveBatchTuner:
         self._ticks += 1
         burn = self.slo.latency_burn(self.path, self.window_s)
         # demand signal: backlog() (queued + claimed-into-the-pipeline
-        # entries) where the batcher provides it — under saturation a
-        # pipelined batcher's submit queue stays short while the demand
-        # sits in its stage hand-off queues; queue_fill() alone would
-        # blind the grow path exactly when it matters
+        # entries) where the batcher provides it — the rows already
+        # claimed (launching, standing before the launch, decoding) are
+        # demand too, and queue_fill() alone leaves them out
         queue = getattr(self.batcher, "backlog", self.batcher.queue_fill)()
         cur_batch = int(self.batcher.max_batch)
         cur_window = float(self.batcher.window_s)

@@ -833,12 +833,27 @@ batch_occupancy = REGISTRY.register(
     )
 )
 
+batch_claims_total = REGISTRY.register(
+    Counter(
+        "cedar_batch_claims_total",
+        "Batches the pipelined batcher's collector claimed, partitioned by "
+        "path and held: yes = work was in the submit queue and had to wait "
+        "for the one standing place before the dispatch thread (the late "
+        "claim engaged: the dispatch stage sets the pace); no = the place "
+        "was free when work came (a lone caller, an idle server). The time "
+        "so held is cedar_pipeline_stall_seconds_total{stage=\"collect\"}.",
+        ["path", "held"],
+    )
+)
+
 pipeline_stall_seconds_total = REGISTRY.register(
     Counter(
         "cedar_pipeline_stall_seconds_total",
         "Seconds a pipeline stage spent stalled, partitioned by path and "
-        "stage: collect = the collector blocked on a full dispatch queue "
-        "(device/decode backpressure); dispatch = the dispatch thread "
+        "stage: collect = requests stood in the submit queue while the "
+        "standing place before the dispatch thread was taken (the "
+        "dispatch stage, the device or the decode behind it sets the "
+        "pace); dispatch = the dispatch thread "
         "waited on an encode worker (encode-bound); decode = the decode "
         "thread sat idle while batches were in flight (pipeline "
         "starvation). Rate > ~0.5 s/s on one stage names the bottleneck "
@@ -1580,6 +1595,10 @@ def set_cache_hit_ratio(path: str, ratio: float) -> None:
 
 def record_batch_occupancy(path: str, n: int) -> None:
     batch_occupancy.observe(n, path=path)
+
+
+def record_batch_claim(path: str, held: bool) -> None:
+    batch_claims_total.inc(path=path, held="yes" if held else "no")
 
 
 def record_pipeline_stall(path: str, stage: str, seconds: float) -> None:

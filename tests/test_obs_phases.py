@@ -256,16 +256,17 @@ def test_a_cache_hit_has_no_pipeline_phases():
         s.stop()
 
 
-def authorize_from_threads(served, callers, per_caller):
-    """Distinct SARs from ``callers`` keep-alive connections at once; the
-    X-Cedar-Trace-Id of every reply, once every request's phases are in."""
+def authorize_from_threads(served, callers, per_caller, path="authorization"):
+    """Distinct requests from ``callers`` keep-alive connections at once;
+    the X-Cedar-Trace-Id of every reply, once every request's phases are
+    in."""
     ids, errors = [], []
 
     def caller(k):
         try:
             conn = served.connection()
             for i in range(per_caller):
-                resp, _ = post(conn, "/v1/authorize", sar(1000 * k + i))
+                resp, _ = post(conn, ENDPOINTS[path], BODIES[path](1000 * k + i))
                 assert resp.status == 200
                 ids.append(resp.headers["X-Cedar-Trace-Id"])
             conn.close()
@@ -339,6 +340,63 @@ def test_every_served_request_leaves_its_log_line_through_the_sink(served):
     assert sorted(logged) == sorted(ids)
     assert metrics.request_phase_seconds.totals()[
         ("authorization", "write")][1] - writes_before == want
+
+
+def claims_on_metrics(served, path):
+    """cedar_batch_claims_total{path, held} as /metrics prints it:
+    (held yes, held no)."""
+    conn = http.client.HTTPConnection(
+        "127.0.0.1", served.server.bound_metrics_port, timeout=30)
+    conn.request("GET", "/metrics")
+    text = conn.getresponse().read().decode()
+    conn.close()
+    found = {"yes": 0.0, "no": 0.0}
+    for line in text.splitlines():
+        if line.startswith("cedar_batch_claims_total{") and f'path="{path}"' in line:
+            held = line.split('held="')[1].split('"')[0]
+            found[held] = float(line.rsplit(" ", 1)[1])
+    return found["yes"], found["no"]
+
+
+@pytest.mark.parametrize("path", sorted(ENDPOINTS))
+def test_a_lone_callers_claims_are_never_held_on_metrics(served, path):
+    yes0, no0 = claims_on_metrics(served, path)
+    conn = served.connection()
+    for i in range(10):
+        resp, _ = post(conn, ENDPOINTS[path], BODIES[path](7000 + i))
+        assert resp.status == 200
+    conn.close()
+    yes1, no1 = claims_on_metrics(served, path)
+    assert (yes1 - yes0, no1 - no0) == (0.0, 10.0)
+
+
+@pytest.mark.parametrize("path", sorted(ENDPOINTS))
+def test_claims_behind_a_slow_launch_are_held_on_metrics(served, path):
+    """16 callers against a launch that takes 20 ms: the dispatch stage
+    sets the pace, the backlog waits in the submit queue for the one
+    standing place, and the counter says so for the path's own batcher."""
+    batcher = (served.server._batcher if path == "authorization"
+               else served.server._adm_raw_batcher)
+    launch = batcher.stages.pipeline_dispatch
+
+    def slow_launch(ctx):
+        time.sleep(0.02)
+        return launch(ctx)
+
+    batcher.stages.pipeline_dispatch = slow_launch
+    other = "admission" if path == "authorization" else "authorization"
+    before, other_before = claims_on_metrics(served, path), claims_on_metrics(served, other)
+    batches_before = batcher.debug_stats()["batches_total"]
+    try:
+        authorize_from_threads(served, callers=16, per_caller=6, path=path)
+    finally:
+        del batcher.stages.pipeline_dispatch
+    after = claims_on_metrics(served, path)
+    yes, no = after[0] - before[0], after[1] - before[1]
+    batches = batcher.debug_stats()["batches_total"] - batches_before
+    assert yes + no == batches < 16 * 6
+    assert yes >= 0.25 * batches
+    assert claims_on_metrics(served, other) == other_before
 
 
 def test_stage_histograms_and_spans_share_the_sub_stage_stamps(served):
