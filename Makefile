@@ -71,7 +71,7 @@ bench-chaos: ## Game-day suite incl. replica-loss: availability/correctness/reco
 	JAX_PLATFORMS=cpu $(PYTHON) bench.py --chaos
 
 .PHONY: bench-encode
-bench-encode: ## Host-side budget: native encode µs/req at 1/2/4 threads, packed-vs-per-chunk decode, pallas/lax parity, 3.5µs encode regression gate (cpu; docs/performance.md)
+bench-encode: ## Host-side budget: native encode µs/req at 1/2/4 threads, packed-vs-per-chunk decode, 3.5µs encode regression gate (cpu; docs/performance.md)
 	JAX_PLATFORMS=cpu $(PYTHON) bench.py --encode
 
 .PHONY: bench-scale
@@ -121,10 +121,6 @@ bench-explain: ## Explain-plane pay-for-use: explain-off p99/throughput parity g
 .PHONY: bench-trace
 bench-trace: ## Observability-plane pay-for-use: unsampled-tracing parity gate + byte differential, 100%-sampled cost (cpu; docs/observability.md)
 	JAX_PLATFORMS=cpu $(PYTHON) bench.py --trace
-
-.PHONY: hw-validate
-hw-validate: ## Check the kernel planes (int8/bf16/pallas/segred) compile and agree on the TPU (run it through the chip tool; exits nonzero when JAX finds none)
-	$(PYTHON) tools/hw_validate.py
 
 .PHONY: fuzz-soak
 fuzz-soak: ## Differential fuzz soak over fresh seed ranges (cpu backend)

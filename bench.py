@@ -2538,7 +2538,7 @@ def run_pod_scenario() -> int:
 def run_encode_scenario() -> int:
     """make bench-encode: the host-side budget microbench (ISSUE 8,
     docs/performance.md "Host-side budget"). Cpu-backend by design — the
-    native encode is pure host C++ and the decode/parity comparisons are
+    native encode is pure host C++ and the decode comparison is
     about the execution model, not device speed. Measures:
 
       * native encode µs/req at 1/2/4 worker-pool threads (persistent
@@ -2546,8 +2546,6 @@ def run_encode_scenario() -> int:
         staging buffers via encode_batch_into)
       * packed vs per-chunk word decode: the full native fast path with
         the batch-wide _WordPacker D2H vs CEDAR_TPU_PACKED_DECODE=0
-      * pallas-vs-lax parity: the fused words kernel (interpret mode on
-        cpu) against the XLA plane's packed words on identical inputs
 
     Regression gate: single-thread native encode above
     CEDAR_BENCH_ENCODE_GATE_US (default 3.5) µs/req fails the run (rc 1,
@@ -2663,53 +2661,6 @@ def run_encode_scenario() -> int:
         "device_wait_us_per_req_packed": round(dec_packed, 3),
         "packed_delta": round(rate_packed / max(rate_perrow, 1) - 1, 4),
     }
-
-    # ---- pallas-vs-lax parity: fused words kernel against the XLA plane
-    # on identical encoder output (interpret mode on cpu). Skipped — and
-    # says so — when the set's (L, R) don't tile (pallas_supported false:
-    # the serving path takes the byte-identical lax fallback there too).
-    from cedar_tpu.ops.pallas_match import pallas_supported
-
-    cs = engine._compiled
-    packed = cs.packed
-    B = 128
-    codes, extras, counts, flags = snap.encoder.encode_batch(bodies[: B * 2])
-    ok = np.nonzero(flags == 0)[0][:B]
-    parity: dict = {
-        "supported": bool(
-            len(ok) == B and pallas_supported(B, packed.L, packed.R)
-        )
-    }
-    if parity["supported"]:
-        pl_engine = TPUPolicyEngine(use_pallas=True)
-        pl_engine.load([ps], warm="off")
-        cs_p = pl_engine._compiled
-        parity["supported"] = cs_p.pallas_args is not None
-    if parity["supported"]:
-        from cedar_tpu.ops.match import match_rules_codes_pallas
-
-        w_lax, _ = engine.match_arrays(codes[ok], extras[ok], cs=cs)
-        w_pl, _ = match_rules_codes_pallas(
-            codes[ok].astype(cs_p.code_dtype),
-            extras[ok].astype(cs_p.active_dtype),
-            cs_p.act_rows_dev,
-            *cs_p.pallas_args,
-            packed.n_tiers,
-            False,
-            pl_engine._pallas_interpret,
-            packed.has_gate,
-        )
-        match = bool(
-            np.array_equal(
-                np.asarray(w_lax).astype(np.uint32),
-                np.asarray(w_pl).astype(np.uint32),
-            )
-        )
-        parity["rows"] = int(B)
-        parity["byte_identical"] = match
-        if not match:
-            result["error"] = "pallas words diverged from the lax plane"
-    result["pallas_parity"] = parity
 
     # ---- regression gate (see docstring)
     gate_us = float(os.environ.get("CEDAR_BENCH_ENCODE_GATE_US", "3.5"))
@@ -6336,8 +6287,7 @@ if __name__ == "__main__":
     if "--encode" in sys.argv:
         # host-side budget microbench (make bench-encode): cpu-only BY
         # DESIGN — native encode is pure host C++, and the packed-decode
-        # A/B + pallas parity checks measure the execution model, not
-        # device speed. Async cpu dispatch so the packed-vs-per-chunk
+        # A/B measures the execution model, not device speed. Async cpu dispatch so the packed-vs-per-chunk
         # comparison sees the same overlap shape as an attached device.
         from cedar_tpu.jaxenv import force_cpu
 

@@ -219,12 +219,6 @@ def build_server(args) -> WebhookServer:
     except Exception:  # noqa: BLE001 — metrics must never block startup
         pass
 
-    # fused pallas serving kernel: auto (None) = the engine's own
-    # backend-aware default (on for TPU-class backends, off on CPU)
-    use_pallas = {"auto": None, "on": True, "off": False}[
-        getattr(args, "pallas", "auto")
-    ]
-
     # serialized-executable cache (engine/aot.py, docs/Operations.md):
     # the flag wins over CEDAR_TPU_AOT_CACHE; either enables warm-from-disk
     # cold starts (zero fresh jit traces when the key matches)
@@ -380,7 +374,7 @@ def build_server(args) -> WebhookServer:
         # production batch can land on, so no request ever pays a trace
         tier_engine = TPUPolicyEngine(
             mesh=mesh, segred=segred, name=name,
-            warm_max_batch=args.max_batch, use_pallas=use_pallas,
+            warm_max_batch=args.max_batch,
             incremental=not args.no_incremental_compile,
             shard_buckets=args.shard_buckets,
             partition=partition_spec,
@@ -522,7 +516,7 @@ def build_server(args) -> WebhookServer:
             r_breaker = _make_breaker(f"authorization-r{i}")
             r_engine = TPUPolicyEngine(
                 mesh=mesh, segred=segred, name=f"authorization-r{i}",
-                warm_max_batch=args.max_batch, use_pallas=use_pallas,
+                warm_max_batch=args.max_batch,
                 incremental=not args.no_incremental_compile,
                 shard_buckets=args.shard_buckets,
                 partition=partition_spec,
@@ -1390,15 +1384,6 @@ def make_parser() -> argparse.ArgumentParser:
         "traces; stale keys recompile loudly. Also CEDAR_TPU_AOT_CACHE; "
         "CEDAR_TPU_AOT=0 disables. The dir must be trusted — entries are "
         "pickled executables (docs/Operations.md)",
-    )
-    cedar.add_argument(
-        "--pallas",
-        default="auto",
-        choices=["auto", "on", "off"],
-        help="fused pallas serving kernel (slot-match + clause-reduce + "
-        "tier walk in one device launch): auto enables it on TPU-class "
-        "backends with byte-identical lax fallback for unsupported "
-        "shapes; off pins the XLA planes (docs/performance.md)",
     )
     cedar.add_argument(
         "--shard-buckets",

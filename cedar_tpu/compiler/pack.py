@@ -76,10 +76,9 @@ GATE_RULE_POLICY = 0  # rule_policy for gate rules: any value != INT32_MAX
 # context slot: every rule of tenant T gets a synthetic FIRST-conjunct EQ
 # literal over ("context", ("tenantId",)) — the same mechanism the
 # partition-spec corpora use for their cluster discriminators — so the
-# slot-match kernel (lax plane, segred plane and the pallas words path
-# alike) masks foreign tenants' rules with zero new kernel code: a request
-# whose context carries tenant A's id satisfies no rule carrying tenant
-# B's literal, INCLUDING B's error clauses (the discriminator precedes
+# slot-match kernel (scan and segred reductions alike) masks foreign
+# tenants' rules with zero new kernel code: a request whose context
+# carries tenant A's id satisfies no rule carrying tenant B's literal, INCLUDING B's error clauses (the discriminator precedes
 # the error indicators, exactly like Cedar's && short-circuit kills a
 # foreign policy's errors). The literal is total and access-free (the
 # encoder reads a slot the front end stamps), so discrimination adds no

@@ -7,8 +7,8 @@ set — a fresh worker process after a rolling restart, a fanout revive, a
 serve. The fanout tier papers over that window with peer cache fills;
 this module removes the window instead.
 
-Every jitted match/words/bits entry point (ops/match.py,
-ops/pallas_match.py) dispatches through :func:`dispatch`, which:
+Every jitted match/bits entry point (ops/match.py) dispatches through
+:func:`dispatch`, which:
 
 * computes a cache key from everything that determines the compiled
   artifact: jax/jaxlib versions, backend platform + device kind + device
@@ -85,9 +85,6 @@ STATICS = {
     #  group_c, policy_c, n_tiers, want_full, want_bits, n_valid,
     #  has_gate, segs)
     "wire": (9, 10, 11, 13, 14),
-    # (codes, extras, act_rows, W2, thresh_r, group_r, policy_r,
-    #  n_tiers, want_full, interpret, has_gate)
-    "pallas": (7, 8, 9, 10),
     # (codes, extras, act_rows, W_chunks, thresh_c, group_c, policy_c)
     "bits": (),
 }
@@ -309,8 +306,9 @@ def _compile_and_export(name, key, meta, jit_fn, args) -> Optional[Callable]:
     try:
         with warnings.catch_warnings():
             # donated twins warn "Some donated buffers were not usable"
-            # on backends that cannot donate — the donation is dropped
-            # (an optimization, not a semantic), which is fine
+            # for the buffers XLA cannot reuse (all of them on backends
+            # that cannot donate) — the donation is dropped (an
+            # optimization, not a semantic), which is fine
             warnings.simplefilter("ignore")
             compiled = jit_fn.lower(*args).compile()
     except Exception as e:  # noqa: BLE001 — lowering quirk: plain jit path

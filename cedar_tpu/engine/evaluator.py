@@ -76,8 +76,9 @@ from ..ops.match import (
     chunk_rules,
     match_rules_codes,
     match_rules_codes_bits,
-    match_rules_codes_pallas,
+    match_rules_codes_donated,
     match_rules_codes_wire,
+    match_rules_codes_wire_donated,
 )
 from . import aot
 
@@ -378,7 +379,7 @@ class _CompiledSet:
     """Immutable device-resident compiled policy set (the swap unit)."""
 
     def __init__(
-        self, packed: PackedPolicySet, device=None, use_pallas=False,
+        self, packed: PackedPolicySet, device=None,
         mesh=None, segred: "Optional[bool]" = None, plane_info=None,
         prior: "Optional[_CompiledSet]" = None,
         max_rules_per_partition: Optional[int] = None,
@@ -425,8 +426,7 @@ class _CompiledSet:
                     tcol,
                     dict(table.scalar_vocab.get(TENANT_SLOT, {})),
                 )
-        self.pallas_args = None
-        # u8 wire plan (set below for the single-device XLA plane): slots
+        # u8 wire plan (set below for the single-device plane): slots
         # whose nonzero row span fits 255 ship ONE byte per request, re-based
         # on device (ops/match.py match_rules_codes_wire): half the code
         # bytes per request over the host->device link.
@@ -436,14 +436,10 @@ class _CompiledSet:
         self._wire_pad8 = 0
         self._wire_padw = 0
         self.segs = None  # segmented-reduction plan (set below; not mesh)
-        # int8 scoring plane (default): W ships as int8 with int32
-        # accumulation — exact (entries are +/-1, sums << 2^24) and 2x bf16
-        # MXU peak on TPU; CEDAR_TPU_INT8=0 restores the bf16 plane
+        # the one scoring plane: W ships as int8 (compiler.pack writes it
+        # so) with int32 accumulation and int32 thresholds — exact
         # (ops/match.py module docstring)
-        int8_plane = os.environ.get("CEDAR_TPU_INT8", "1") != "0"
-        thresh_host = (
-            packed.thresh.astype(np.int32) if int8_plane else packed.thresh
-        )
+        thresh_host = packed.thresh.astype(np.int32)
         # mesh deployments: global column → packed rule index map when the
         # rule axis is laid out by compiler shard (None otherwise); bits
         # decode translates through it (_bits_groups)
@@ -452,8 +448,8 @@ class _CompiledSet:
         if mesh is not None:
             # multi-chip: tensors placed with the (data, policy)
             # shardings; the engine routes evaluation through the pjit
-            # steps in parallel/mesh.py. No chunked/pallas planes — the
-            # policy axis shards replace the scan chunking.
+            # steps in parallel/mesh.py. No chunked plane — the policy
+            # axis shards replace the scan chunking.
             policy_shard = (
                 dict(plane_info.get("policy_shard", ()))
                 if plane_info
@@ -479,7 +475,6 @@ class _CompiledSet:
                     mesh,
                     packed,
                     policy_shard,
-                    int8_plane,
                     prior=prior_planes,
                     max_rules_per_partition=max_rules_per_partition,
                 )
@@ -502,31 +497,28 @@ class _CompiledSet:
             ) = shard_codes_tensors(
                 mesh,
                 packed.table.rows,
-                jax.numpy.asarray(packed.W, jax.numpy.int8)
-                if int8_plane
-                else jax.numpy.asarray(packed.W, jax.numpy.bfloat16),
+                jax.numpy.asarray(packed.W, jax.numpy.int8),
                 thresh_host,
                 packed.rule_group,
                 packed.rule_policy,
             )
             return
         kwargs = {"device": device} if device is not None else {}
-        w_host = packed.W if int8_plane else packed.W.astype(np.float32)
         W3, thresh_c, group_c, policy_c = chunk_rules(
-            w_host, thresh_host,
+            packed.W, thresh_host,
             packed.rule_group, packed.rule_policy,
         )
         # segmented-reduction plane (opt-in, CEDAR_TPU_SEGRED=1): rules
         # are group-contiguous (pack sorts by (group, policy)), so each
         # chunk's per-group first/last-match reduces over one static
         # column slice instead of n_groups masked passes — a candidate
-        # 2-4x cut of the XLA plane's non-matmul device cost; measured by
-        # tools/hw_validate.py before any default flip. COST: segs is a
-        # jit-static tuple derived from the rule layout, so a hot swap to
-        # a differently-laid-out set recompiles the match kernel (in the
-        # background warm ladder, like other shape changes) and each
-        # distinct layout retains its executables in the jit cache —
-        # acceptable for an experimental plane, documented in
+        # 2-4x cut of the scan's non-matmul device cost, not measured on
+        # the chip (a benchmark cell does that before any default flip).
+        # COST: segs is a jit-static tuple derived from the rule layout,
+        # so a hot swap to a differently-laid-out set recompiles the match
+        # kernel (in the background warm ladder, like other shape changes)
+        # and each distinct layout retains its executables in the jit
+        # cache — acceptable for an experimental plane, documented in
         # docs/Limitations.md alongside the flip criteria
         self.segs = None
         use_segred = (
@@ -536,9 +528,7 @@ class _CompiledSet:
         )
         if use_segred:
             self.segs = _segment_plan(group_c, packed.n_rules)
-        self.W_dev = jax.device_put(
-            W3 if int8_plane else W3.astype(jax.numpy.bfloat16), **kwargs
-        )
+        self.W_dev = jax.device_put(W3, **kwargs)
         self.thresh_dev = jax.device_put(thresh_c, **kwargs)
         self.rule_group_dev = jax.device_put(group_c, **kwargs)
         self.rule_policy_dev = jax.device_put(policy_c, **kwargs)
@@ -579,46 +569,6 @@ class _CompiledSet:
                         [lo8, np.ones(self._wire_pad8, np.int32)]
                     ),
                     **kwargs,
-                )
-        # optional pallas layout: unchunked [L, R] W + [1, R] rule tensors
-        # for the fused match kernel (ops/pallas_match.py)
-        if use_pallas:
-            from ..ops.pallas_match import pallas_supported
-
-            if pallas_supported(0, packed.L, packed.R):
-                # the kernel follows its W dtype like the XLA plane;
-                # int8-in-pallas stays opt-in (CEDAR_TPU_PALLAS_INT8=1)
-                # until the Mosaic int8-dot lowering is validated on the
-                # target chip — interpret-mode equality is tested either way
-                pallas_int8 = (
-                    os.environ.get("CEDAR_TPU_PALLAS_INT8", "0") == "1"
-                )
-                if pallas_int8 and not int8_plane:
-                    log.warning(
-                        "CEDAR_TPU_PALLAS_INT8=1 ignored: CEDAR_TPU_INT8=0 "
-                        "selects the bf16 plane everywhere"
-                    )
-                    pallas_int8 = False
-                self.pallas_args = (
-                    jax.device_put(
-                        packed.W
-                        if pallas_int8
-                        else jax.numpy.asarray(packed.W, jax.numpy.bfloat16),
-                        **kwargs,
-                    ),
-                    jax.device_put(
-                        (thresh_host if pallas_int8 else packed.thresh)[
-                            None, :
-                        ],
-                        **kwargs,
-                    ),
-                    # the pallas kernel indexes groups as int32 [1, R];
-                    # upcast the (narrow int16) packed column here — the
-                    # chunked XLA planes consume it natively
-                    jax.device_put(
-                        packed.rule_group[None, :].astype(np.int32), **kwargs
-                    ),
-                    jax.device_put(packed.rule_policy[None, :], **kwargs),
                 )
 
     def pack_wire(self, codes):
@@ -665,7 +615,6 @@ class TPUPolicyEngine:
         self,
         schema: Optional[SchemaInfo] = None,
         device=None,
-        use_pallas: Optional[bool] = None,
         mesh=None,
         segred: Optional[bool] = None,
         name: str = "engine",
@@ -722,28 +671,6 @@ class TPUPolicyEngine:
         self.mesh = mesh
         self.name = name
         self.warm_max_batch = warm_max_batch
-        # interpret mode lets the pallas path run (and be tested) on CPU;
-        # other non-TPU backends (e.g. GPU) can't lower the Mosaic kernel —
-        # keep the XLA path there
-        backend = jax.default_backend()
-        self._pallas_interpret = backend == "cpu"
-        if use_pallas is None:
-            env = os.environ.get("CEDAR_TPU_PALLAS", "auto")
-            if env == "auto":
-                # hot-path default: the TPU gets the fused slot-match +
-                # clause-reduce + tier-walk kernel (one launch per batch,
-                # word-only HBM output), falling back byte-identically to
-                # the lax plane wherever pallas_supported() rules a shape
-                # out. CPU keeps the XLA plane — interpret mode is a test
-                # vehicle, not a server.
-                use_pallas = backend == "tpu"
-            else:
-                use_pallas = env == "1"
-        if use_pallas and backend not in ("cpu", "tpu"):
-            use_pallas = False
-        if mesh is not None:
-            use_pallas = False  # the sharded pjit plane replaces pallas
-        self.use_pallas = use_pallas
         self.segred = segred
         # bucket-padded staging buffers, reused across batches (returned
         # by each launch's finish()); shared by every caller of this engine
@@ -753,6 +680,7 @@ class TPUPolicyEngine:
         # literal expansion, and with pipeline-depth batches in flight they
         # are the footprint term that scales. Never on CPU — the runtime
         # may alias numpy inputs, and the staging pool reuses those arrays.
+        backend = jax.default_backend()
         donate_env = os.environ.get("CEDAR_TPU_DONATE", "1") != "0"
         self._donate = backend == "tpu" and mesh is None and donate_env
         # mesh twin: the pjit steps take the same donation (their own jit,
@@ -880,7 +808,7 @@ class TPUPolicyEngine:
         t_place = time.monotonic()
         prior = self._compiled
         new = _CompiledSet(
-            packed, self.device, use_pallas=self.use_pallas, mesh=self.mesh,
+            packed, self.device, mesh=self.mesh,
             segred=self.segred, plane_info=info, prior=prior,
             max_rules_per_partition=self.mesh_device_rules,
         )
@@ -1015,7 +943,6 @@ class TPUPolicyEngine:
             or a.code_dtype != b.code_dtype
             or a.active_dtype != b.active_dtype
             or pa.table.rows.shape != pb.table.rows.shape
-            or (a.pallas_args is None) != (b.pallas_args is None)
             or a.segs != b.segs  # jit-static: a layout change retraces
         ):
             return False
@@ -1415,8 +1342,7 @@ class TPUPolicyEngine:
         if cs is None:
             return False
         new = _CompiledSet(
-            cs.packed, self.device, use_pallas=self.use_pallas,
-            mesh=self.mesh, segred=self.segred,
+            cs.packed, self.device, mesh=self.mesh, segred=self.segred,
             # keep the shard-partitioned mesh layout (and its col_map)
             # across a device loss; prior=None — the dead device's
             # buffers are exactly what must NOT be reused
@@ -1502,17 +1428,13 @@ class TPUPolicyEngine:
         out["staging"] = self._staging.stats()
         if aot.enabled():
             out["aot"] = aot.stats()
-        # where and how this set is actually served — observed, not
-        # configured: the device JAX resolved, whether the pallas plane is
-        # selected, and whether the live set carries its layout
-        # (pallas_supported() can drop it for a shape without a word)
+        # where this set is actually served — observed, not configured:
+        # the device JAX resolved
         devices = jax.devices()
         dev = self.device if self.device is not None else devices[0]
         out["platform"] = dev.platform
         out["device_kind"] = dev.device_kind
         out["n_devices"] = len(devices)
-        out["use_pallas"] = bool(self.use_pallas)
-        out["pallas_layout"] = c.pallas_args is not None
         out["warm"] = dict(self._warm_state)
         return out
 
@@ -1667,10 +1589,11 @@ class TPUPolicyEngine:
         (multi bit) or a policy errored alongside a real match (err bit).
         `bitmap` ({row index: bitset row}) is the compacted payload a
         want_bits match call already fetched with the words; rows it covers
-        cost nothing extra, rows it misses (compaction overflow, pallas
-        path) fetch their bitsets in one batched call. Returns {row index:
-        (decision, Diagnostics)} with the full reason/error sets; rows not
-        in the dict are exactly described by their 4-byte word."""
+        cost nothing extra, rows it misses (compaction overflow, batches
+        launched without want_bits) fetch their bitsets in one batched
+        call. Returns {row index: (decision, Diagnostics)} with the full
+        reason/error sets; rows not in the dict are exactly described by
+        their 4-byte word."""
         cs = cs or self._compiled
         packed = cs.packed
         w = words.astype(np.uint32)
@@ -1773,8 +1696,7 @@ class TPUPolicyEngine:
         uint32 bitset} for every flagged row (multi/err verdicts, or any
         multi-distinct group under want_full), compacted on device and
         fetched with the words — the diagnostics payload costs no extra
-        device round trip (ops/match.py BITS_TOPK). The pallas path has no
-        bits plane; there the map is empty and resolve_flagged falls back.
+        device round trip (ops/match.py BITS_TOPK).
 
         `cs` pins the compiled set the codes were encoded against — callers
         that encoded against a snapshot MUST pass it, or a concurrent policy
@@ -1810,42 +1732,57 @@ class TPUPolicyEngine:
 
         held: list = []  # pooled staging buffers, released by finish()
 
-        def one(chunk_c, chunk_e, m):
-            """-> (words_dev, full_dev_or_None, pack_dev_or_None); m is the
-            VALID row count (excludes caller-side staging padding), used
-            only to mask the want_bits compaction. Host staging (pad to
-            the bucket, the u8 wire pack) and the launch (the jitted call
-            and the H2D it implies) are timed apart: obs.trace sub_stage
-            `dispatch.stage` — which is also what a dispatch's time counts
-            as outside every sub-stage — and `dispatch.launch`."""
-            if cs.mesh is not None:
-                with sub_stage("dispatch.stage"):
-                    chunk_c, chunk_e = self._pad_to_bucket(
-                        chunk_c, chunk_e, packed.L,
-                        data_mult=cs.mesh.shape["data"], held=held,
+        # Both launch functions return (words_dev, full_dev_or_None,
+        # pack_dev_or_None); m is the VALID row count (excludes
+        # caller-side staging padding), used only to mask the want_bits
+        # compaction. Host staging (pad to the bucket, the u8 wire pack)
+        # and the launch (the jitted call and the H2D it implies) are
+        # timed apart: obs.trace sub_stage `dispatch.stage` — which is
+        # also what a dispatch's time counts as outside every sub-stage —
+        # and `dispatch.launch`.
+
+        def mesh_launch(chunk_c, chunk_e, m):
+            # multi-chip: the pjit step (parallel/mesh.py) shards the
+            # batch over `data` and the rule matmul over `policy`; the
+            # diagnostics bitsets come from the sharded bits step via
+            # resolve_flagged instead of an in-call payload. The
+            # serving (non-full) variant outputs ONLY the packed
+            # word: the per-shard partial verdicts all-reduce on
+            # device and 4 bytes per request come home.
+            with sub_stage("dispatch.stage"):
+                chunk_c, chunk_e = self._pad_to_bucket(
+                    chunk_c, chunk_e, packed.L,
+                    data_mult=cs.mesh.shape["data"], held=held,
+                )
+            with sub_stage("dispatch.launch"):
+                if self.pod is not None:
+                    # pod regime: broadcast the padded batch so every
+                    # host enters this collective, serialized under the
+                    # pod lock so dispatch order matches fleet-wide
+                    w, full = self.pod.run_match(
+                        self, cs, chunk_c, chunk_e, want_full
                     )
-                with sub_stage("dispatch.launch"):
-                    return mesh_launch(chunk_c, chunk_e)
+                    return w, full, None
+                step_args = (chunk_c, chunk_e, *args)
+                if want_full:
+                    w, f, last = self._mesh_step(packed, True)(*step_args)
+                    return w, (f, last), None
+                w = self._mesh_step(packed, False)(*step_args)
+                return w, None, None
+
+        def device_launch(chunk_c, chunk_e, m):
+            """The one single-device launch: the u8 wire layout where the
+            set has a wire plan and the codes fit it, the flat layout
+            otherwise — the same kernel body behind both."""
             with sub_stage("dispatch.stage"):
                 chunk_c, chunk_e = self._pad_to_bucket(
                     chunk_c, chunk_e, packed.L, held=held
                 )
-                # want_bits launches stay on the XLA planes: the pallas
-                # kernel has no bits plane, and silently dropping the
-                # in-call compaction payload would buy flagged rows in the
-                # latency regime a SECOND serial device round trip — the
-                # exact cost the in-call plane exists to avoid
-                use_pallas = False
-                if cs.pallas_args is not None and not want_bits:
-                    from ..ops.pallas_match import pallas_supported
-
-                    use_pallas = pallas_supported(
-                        chunk_c.shape[0], packed.L, packed.R
-                    )
-                wire_codes = None
-                if not use_pallas and cs.wire is not None:
+                layout, lead = "codes", (chunk_c, chunk_e)
+                if cs.wire is not None:
                     try:
-                        wire_codes = cs.pack_wire(chunk_c)
+                        lead = (*cs.pack_wire(chunk_c), cs.lo8_dev, chunk_e)
+                        layout = "wire"
                     except WireSpanError:
                         # a span violation means these codes don't fit the
                         # u8 plan (advisor r5): serve THIS set via the flat
@@ -1859,105 +1796,38 @@ class TPUPolicyEngine:
                         )
                         cs.wire = None
             with sub_stage("dispatch.launch"):
-                if use_pallas:
-                    return pallas_launch(chunk_c, chunk_e)
-                return xla_launch(chunk_c, chunk_e, m, wire_codes)
-
-        def mesh_launch(chunk_c, chunk_e):
-            # multi-chip: the pjit step (parallel/mesh.py) shards the
-            # batch over `data` and the rule matmul over `policy`; the
-            # diagnostics bitsets come from the sharded bits step via
-            # resolve_flagged instead of an in-call payload. The
-            # serving (non-full) variant outputs ONLY the packed
-            # word: the per-shard partial verdicts all-reduce on
-            # device and 4 bytes per request come home.
-            if self.pod is not None:
-                # pod regime: broadcast the padded batch so every
-                # host enters this collective, serialized under the
-                # pod lock so dispatch order matches fleet-wide
-                w, full = self.pod.run_match(
-                    self, cs, chunk_c, chunk_e, want_full
-                )
-                return w, full, None
-            step_args = (
-                chunk_c,
-                chunk_e,
-                cs.act_rows_dev,
-                cs.W_dev,
-                cs.thresh_dev,
-                cs.rule_group_dev,
-                cs.rule_policy_dev,
-            )
-            if want_full:
-                w, f, last = self._mesh_step(packed, True)(*step_args)
-                return w, (f, last), None
-            w = self._mesh_step(packed, False)(*step_args)
-            return w, None, None
-
-        def pallas_launch(chunk_c, chunk_e):
-            w, f = aot.dispatch(
-                "pallas",
-                match_rules_codes_pallas,
-                (
-                    chunk_c,
-                    chunk_e,
-                    cs.act_rows_dev,
-                    *cs.pallas_args,
-                    packed.n_tiers,
-                    want_full,
-                    self._pallas_interpret,
-                    packed.has_gate,
-                ),
-                aot.STATICS["pallas"],
-            )
-            return w, f, None
-
-        def xla_launch(chunk_c, chunk_e, m, wire_codes):
-            # shape-aware plane selection: the segmented kernel's win is
-            # measured at serving-chunk batch sizes; at super-batch scale
-            # the unrolled per-chunk score intermediates cost more than
-            # the masked scan saves (docs/Limitations.md). Large batches
-            # therefore keep the scan plane even when segs are enabled.
-            segs = cs.segs if chunk_c.shape[0] <= SERVING_CHUNK else None
-            if wire_codes is not None:
-                from ..ops.match import match_rules_codes_wire_donated
-
-                c8, cw = wire_codes
-                wire_fn = (
-                    match_rules_codes_wire_donated
-                    if self._donate
-                    else match_rules_codes_wire
-                )
+                # shape-aware reduction: the segmented kernel's win is
+                # measured at serving-chunk batch sizes; at super-batch
+                # scale the unrolled per-chunk score intermediates cost
+                # more than the masked scan saves (docs/Limitations.md).
+                # Large batches therefore keep the scan even when segs
+                # are enabled.
+                segs = cs.segs if chunk_c.shape[0] <= SERVING_CHUNK else None
+                if layout == "wire":
+                    fn = (
+                        match_rules_codes_wire_donated
+                        if self._donate
+                        else match_rules_codes_wire
+                    )
+                else:
+                    fn = (
+                        match_rules_codes_donated
+                        if self._donate
+                        else match_rules_codes
+                    )
                 out = aot.dispatch(
-                    "wire_donated" if self._donate else "wire",
-                    wire_fn,
+                    layout + "_donated" if self._donate else layout,
+                    fn,
                     (
-                        c8, cw, cs.lo8_dev, chunk_e, *args,
-                        packed.n_tiers, want_full, want_bits,
+                        *lead, *args, packed.n_tiers, want_full, want_bits,
                         np.int32(m) if want_bits else None, packed.has_gate,
                         segs,
                     ),
-                    aot.STATICS["wire"],
-                )
-            else:
-                from ..ops.match import match_rules_codes_donated
-
-                flat_fn = (
-                    match_rules_codes_donated
-                    if self._donate
-                    else match_rules_codes
-                )
-                out = aot.dispatch(
-                    "codes_donated" if self._donate else "codes",
-                    flat_fn,
-                    (
-                        chunk_c, chunk_e, *args, packed.n_tiers, want_full,
-                        want_bits, np.int32(m) if want_bits else None,
-                        packed.has_gate, segs,
-                    ),
-                    aot.STATICS["codes"],
+                    aot.STATICS[layout],
                 )
             return out if want_bits else (*out, None)
+
+        launch = mesh_launch if cs.mesh is not None else device_launch
 
         def trim_full(f, m):
             return (np.asarray(f[0])[:m], np.asarray(f[1])[:m])
@@ -1998,9 +1868,9 @@ class TPUPolicyEngine:
             hi = min(lo + _PIPELINE_SB, n)
             v = hi - lo if valid_rows is None else max(0, min(hi, valid_rows) - lo)
             if lo == 0 and hi == n:
-                w, f, p = one(codes_arr, extras_arr, v)
+                w, f, p = launch(codes_arr, extras_arr, v)
             else:
-                w, f, p = one(codes_arr[lo:hi], extras_arr[lo:hi], v)
+                w, f, p = launch(codes_arr[lo:hi], extras_arr[lo:hi], v)
             part = None
             with sub_stage("dispatch.readback"):
                 if use_pack:
@@ -2078,7 +1948,7 @@ class TPUPolicyEngine:
         host/device work between the two). Diagnostics path only — small
         batches get their bitsets compacted into the main match call
         (match_arrays want_bits); this one runs for large-batch flagged
-        rows, compaction overflow, and the pallas plane. Rows process in
+        rows and compaction overflow. Rows process in
         fixed _BITS_CHUNK-sized pieces, pipelined."""
         cs = cs or self._compiled
         if cs is None:

@@ -2,15 +2,13 @@
 
 One ``ExplainPlane`` wraps a ``TPUPolicyEngine`` and answers explain
 requests with the standalone bits kernel (``match_bits_arrays``, fixed
-``_BITS_CHUNK`` shape, XLA plane only): its per-rule satisfaction bitset
+``_BITS_CHUNK`` shape): its per-rule satisfaction bitset
 is a superset of every other attribution payload — complete per-group
 policy sets AND the winning rule — so one launch carries the whole
 explanation. The ``want_full`` first/last plane (which serves
 fallback-set evaluation) is deliberately NOT launched here: everything
 it reports derives from the bitset, and a second dispatch would only
-double the first-explain compile cost. Engine-level want_full routing
-(never the fused pallas words kernel — it emits only packed words, with
-nothing to attribute from) stays pinned by tests/test_pallas_match.py.
+double the first-explain compile cost.
 
 STRICTLY PAY-FOR-USE: nothing here compiles until the first explain
 request per (engine, compiled set). The serving warm ladder pre-compiles

@@ -22,8 +22,8 @@ DEFAULT_COMPILE_CACHE_DIR = _REPO_ROOT / ".jax_cache"
 def require_tpu() -> dict:
     """Raise unless JAX's default device is a TPU; returns the device as
     JAX reports it ({"platform", "kind", "count"}). The one platform check
-    behind every device path (``--backend tpu`` serving, ``bench.py``,
-    ``tools/hw_validate.py``): with no chip ``jax.devices()`` quietly
+    behind every device path (``--backend tpu`` serving, ``bench.py``):
+    with no chip ``jax.devices()`` quietly
     returns the CPU, and a device path that continued there would answer
     correctly from the wrong place."""
     import jax

@@ -4,10 +4,10 @@ The policy set is a matrix W [L, R] over literals x rules (+1 required-true,
 -1 required-false) with per-rule positive-literal counts `thresh`. A request
 batch arrives as padded active-literal index lists [B, A]; the kernel:
 
-  1. expands them into a {0,1} literal matrix lit [B, L] (bfloat16) via a
+  1. expands them into a {0,1} literal matrix lit [B, L] (int8) via a
      broadcast compare against an iota — a fused VPU op. (A scatter would
      serialize on TPU; the compare keeps everything vectorized.)
-  2. computes scores = lit @ W with float32 accumulation — one MXU matmul
+  2. computes scores = lit @ W with int32 accumulation — one MXU matmul
      that evaluates EVERY rule of EVERY request at once
   3. sat = scores >= thresh  (a rule is satisfied iff all its positive
      literals are active and none of its negated literals are)
@@ -16,14 +16,11 @@ batch arrives as padded active-literal index lists [B, A]; the kernel:
      request — the host round trip is 4 bytes/decision, which is what makes
      the webhook's readback latency budget work.
 
-Scores are exact in both kernel dtypes: lit entries are 0/1, W entries are
-+/-1, and row sums stay far below 2^24. The DEFAULT scoring plane is int8
-inputs with int32 accumulation — on TPU the MXU runs int8 contractions at
-2x bf16 peak (v5e: ~394 TOPS int8 vs ~197 TFLOP/s bf16), and the matmul is
-the entire device cost of a decision. The bf16 plane (bf16 inputs, f32
-accumulation) remains for the pallas kernel and as a fallback
-(CEDAR_TPU_INT8=0); every match function follows the dtype of the W
-tensor it is handed, so the two planes share one code path.
+There is ONE scoring plane: int8 inputs with int32 accumulation. Scores
+are exact (lit entries are 0/1, W entries are +/-1), and on TPU the MXU
+runs int8 contractions at 2x bf16 peak (v5e: ~394 TOPS int8 vs ~197
+TFLOP/s bf16) — the matmul is the entire device cost of a decision. Every
+match function takes an int8 W and int32 thresholds.
 
 This replaces the reference's per-request tree-walking interpreter loop
 (cedar-go PolicySet.IsAuthorized called at /root/reference
@@ -108,27 +105,19 @@ def _note_trace() -> None:
     _TRACE_COUNT += 1
 
 
-def _lit_dtype(w_dtype):
-    """The literal-matrix dtype that pairs with a W tensor: int8 W rides
-    the integer MXU plane, anything else the bf16 plane."""
-    return jnp.int8 if w_dtype == jnp.int8 else jnp.bfloat16
-
-
 @jax.named_scope("cedar.match.score")
 def _scores(lit, Wc):
-    """lit [B, L] @ Wc [L, Rc] with the accumulator that keeps the plane
-    exact: int32 for the int8 plane, float32 for bf16."""
-    acc = jnp.int32 if Wc.dtype == jnp.int8 else jnp.float32
-    return jnp.dot(lit, Wc, preferred_element_type=acc)
+    """lit [B, L] int8 @ Wc [L, Rc] int8, accumulated in int32 (exact)."""
+    return jnp.dot(lit, Wc, preferred_element_type=jnp.int32)
 
 
 @jax.named_scope("cedar.match.activation")
-def _lit_matrix(active, L: int, dtype=jnp.bfloat16):
-    """active [B, A] int -> {0,1} literal matrix [B, L]. Out-of-range
+def _lit_matrix(active, L: int):
+    """active [B, A] int -> {0,1} int8 literal matrix [B, L]. Out-of-range
     ids (the pad value) simply never match the iota."""
     a32 = active.astype(jnp.int32)
     iota = jnp.arange(L, dtype=jnp.int32)
-    return (a32[:, :, None] == iota[None, None, :]).any(axis=1).astype(dtype)
+    return (a32[:, :, None] == iota[None, None, :]).any(axis=1).astype(jnp.int8)
 
 
 @jax.named_scope("cedar.match.scan")
@@ -288,14 +277,14 @@ def match_rules_device(
     active, W_chunks, thresh_c, group_c, policy_c, n_tiers: int, want_full: bool
 ):
     """active: [B, A] int16/int32 literal ids (pad with >= L to drop).
-    W_chunks: [C, L, Rc] bf16; thresh_c/group_c/policy_c: [C, Rc].
+    W_chunks: [C, L, Rc] int8; thresh_c/group_c/policy_c: [C, Rc].
 
     Returns (packed uint32 [B], (first, last) [B, G] int32 pair or None).
     The full matrices are only materialized to the host when the caller
     needs them (interpreter-fallback merge or error attribution)."""
     _note_trace()
     L = W_chunks.shape[1]
-    lit = _lit_matrix(active, L, _lit_dtype(W_chunks.dtype))
+    lit = _lit_matrix(active, L)
     first, last, _ = _first_match(
         lit, W_chunks, thresh_c, group_c, policy_c, n_tiers * _GPT
     )
@@ -304,13 +293,13 @@ def match_rules_device(
 
 
 @jax.named_scope("cedar.match.activation")
-def _lit_matrix_codes(codes, extras, act_rows, dtype=jnp.bfloat16):
+def _lit_matrix_codes(codes, extras, act_rows):
     """codes [B, S] int (row indices into act_rows [V, L] uint8) + extras
-    [B, E] int (raw literal ids, pad >= L) -> {0,1} literal matrix [B, L]
-    in the requested kernel dtype (_lit_dtype). The activation table turns
-    each dictionary-coded request feature into its precomputed
-    literal-activation row; rows are OR-combined (a literal activated by
-    two features must count once, not twice)."""
+    [B, E] int (raw literal ids, pad >= L) -> {0,1} int8 literal matrix
+    [B, L]. The activation table turns each dictionary-coded request
+    feature into its precomputed literal-activation row; rows are
+    OR-combined (a literal activated by two features must count once, not
+    twice)."""
     L = act_rows.shape[1]
     S = codes.shape[1]
     acc = jnp.take(act_rows, codes[:, 0].astype(jnp.int32), axis=0)  # [B, L]
@@ -321,7 +310,7 @@ def _lit_matrix_codes(codes, extras, act_rows, dtype=jnp.bfloat16):
         iota = jnp.arange(L, dtype=jnp.int32)
         lit_e = (e32[:, :, None] == iota[None, None, :]).any(axis=1)
         acc = acc | lit_e.astype(acc.dtype)
-    return acc.astype(dtype)
+    return acc.astype(jnp.int8)
 
 
 # flagged-row compaction width: the kernel returns rule bitsets for up to
@@ -390,7 +379,7 @@ def _match_rules_codes_py(
     n_tiers * 3; rows with a gate hit get WORD_GATE set in their word (and
     an extra trailing column in the want_full matrices)."""
     _note_trace()
-    lit = _lit_matrix_codes(codes, extras, act_rows, _lit_dtype(W_chunks.dtype))
+    lit = _lit_matrix_codes(codes, extras, act_rows)
     return _match_from_lit(
         lit, W_chunks, thresh_c, group_c, policy_c, n_tiers,
         want_full, want_bits, n_valid, has_gate, segs,
@@ -405,12 +394,13 @@ match_rules_codes = functools.partial(
 
 # donated twin: the per-batch codes/extras staging transfers are dead the
 # moment the literal expansion reads them, so donating lets XLA reuse
-# their device buffers for scratch — with several batches in flight
-# (engine/batcher.py pipeline) the input buffers are the part of the
-# footprint that scales with depth. Selected by the engine on TPU-class
-# backends only: the CPU runtime may alias a numpy input buffer, where
-# donation would hand the caller's (pooled, reused) staging array to XLA
-# as writable scratch.
+# their device buffers. On the v5e it uses one of them: an extras buffer
+# of width 1 (a batch with no extras, the common SAR) becomes the [B]
+# word output in place, and the code buffers and wider extras are
+# reported "not usable" (chip check, PERF.md section 6, PR 32). Selected
+# by the engine on TPU-class backends only: the CPU runtime may alias a
+# numpy input buffer, where donation would hand the caller's (pooled,
+# reused) staging array to XLA as writable scratch.
 match_rules_codes_donated = functools.partial(
     jax.jit, static_argnames=_CODES_STATICS, donate_argnums=(0, 1)
 )(_match_rules_codes_py)
@@ -454,9 +444,7 @@ def _match_from_lit(
 
 
 @jax.named_scope("cedar.match.activation")
-def _lit_matrix_codes_wire(
-    codes8, codes_w, lo8, extras, act_rows, dtype=jnp.bfloat16
-):
+def _lit_matrix_codes_wire(codes8, codes_w, lo8, extras, act_rows):
     """u8-wire variant of _lit_matrix_codes: codes8 [B, S8] uint8 carries
     re-based rows for the narrow slots (0 = missing; v>0 = global row
     v + lo8[s] - 1), codes_w [B, Sw] int16/int32 carries the wide slots'
@@ -481,7 +469,7 @@ def _lit_matrix_codes_wire(
         iota = jnp.arange(L, dtype=jnp.int32)
         lit_e = (e32[:, :, None] == iota[None, None, :]).any(axis=1)
         acc = acc | lit_e.astype(acc.dtype)
-    return acc.astype(dtype)
+    return acc.astype(jnp.int8)
 
 
 def _match_rules_codes_wire_py(
@@ -505,9 +493,7 @@ def _match_rules_codes_wire_py(
     _lit_matrix_codes_wire and engine._CompiledSet.wire): identical
     semantics and outputs, roughly half the h2d bytes per request."""
     _note_trace()
-    lit = _lit_matrix_codes_wire(
-        codes8, codes_w, lo8, extras, act_rows, _lit_dtype(W_chunks.dtype)
-    )
+    lit = _lit_matrix_codes_wire(codes8, codes_w, lo8, extras, act_rows)
     return _match_from_lit(
         lit, W_chunks, thresh_c, group_c, policy_c, n_tiers,
         want_full, want_bits, n_valid, has_gate, segs,
@@ -526,55 +512,6 @@ match_rules_codes_wire_donated = functools.partial(
 )(_match_rules_codes_wire_py)
 
 
-@functools.partial(
-    jax.jit, static_argnames=("n_tiers", "want_full", "interpret", "has_gate")
-)
-def match_rules_codes_pallas(
-    codes,
-    extras,
-    act_rows,
-    W2,
-    thresh_r,
-    group_r,
-    policy_r,
-    n_tiers: int,
-    want_full: bool,
-    interpret: bool = False,
-    has_gate: bool = False,
-):
-    """Pallas-kernel variant of match_rules_codes: the scores matmul and the
-    per-group first-match reduction run fused in VMEM (ops/pallas_match.py),
-    so the [B, R] score matrix never reaches HBM. Layouts: W2 [L, R]
-    unchunked in either kernel dtype (bf16 with f32 thresh_r, or int8 with
-    int32 thresh_r — the lit matrix follows W2's dtype),
-    group_r/policy_r [1, R].
-
-    Without want_full the TIER WALK fuses into the kernel too
-    (pallas_match_words): the serving hot path is one pallas launch from
-    feature codes to packed verdict words, and the per-request HBM output
-    shrinks from 2 x [B, G] int32 to one u32 word. want_full keeps the
-    (first, last) kernel for the host tier-walk callers."""
-    from .pallas_match import pallas_first_match, pallas_match_words
-
-    _note_trace()
-    n_groups = n_tiers * _GPT + (1 if has_gate else 0)
-    lit = _lit_matrix_codes(codes, extras, act_rows, _lit_dtype(W2.dtype))
-    if not want_full:
-        packed = pallas_match_words(
-            lit, W2, thresh_r, group_r, policy_r, n_tiers, has_gate,
-            interpret,
-        )
-        return packed, None
-    first, last = pallas_first_match(
-        lit, W2, thresh_r, group_r, policy_r, n_groups, interpret
-    )
-    packed = _tier_walk(first, last, n_tiers)
-    if has_gate:
-        gate = (first[:, n_tiers * _GPT] != INT32_MAX).astype(jnp.uint32)
-        packed = packed | (gate << 27)
-    return packed, (first, last)
-
-
 @functools.partial(jax.jit, static_argnames=("n_groups",))
 def match_rules_compact(active, W_chunks, thresh_c, group_c, policy_c, n_groups: int):
     """Full per-(tier, effect) first-match matrix [B, G] int32; INT32_MAX
@@ -582,7 +519,7 @@ def match_rules_compact(active, W_chunks, thresh_c, group_c, policy_c, n_groups:
     attribution (tests, fallback-heavy sets)."""
     _note_trace()
     L = W_chunks.shape[1]
-    lit = _lit_matrix(active, L, _lit_dtype(W_chunks.dtype))
+    lit = _lit_matrix(active, L)
     first, _, _ = _first_match(lit, W_chunks, thresh_c, group_c, policy_c, n_groups)
     return first
 
@@ -610,7 +547,7 @@ def match_rules_codes_bits(
     word carries the multi or err flag, so the [B, R/32] readback never
     rides the hot path."""
     _note_trace()
-    lit = _lit_matrix_codes(codes, extras, act_rows, _lit_dtype(W_chunks.dtype))
+    lit = _lit_matrix_codes(codes, extras, act_rows)
 
     def body(_, xs):
         Wc, tc, _gc, _pc = xs
@@ -651,11 +588,10 @@ def chunk_rules(W, thresh, rule_group, rule_policy, chunk: int = 4096):
 @functools.partial(jax.jit, static_argnames=("n_groups",))
 def match_rules(active, W, thresh, rule_group, rule_policy, n_groups: int):
     """Unchunked single-matmul variant (small sets / compile checks).
-    Follows W's dtype like every other match function (int8 or bf16 plane).
     Returns (hits [B, G] bool, first_policy [B, G] int32)."""
     _note_trace()
     L = W.shape[0]
-    lit = _lit_matrix(active, L, _lit_dtype(W.dtype))
+    lit = _lit_matrix(active, L)
 
     scores = _scores(lit, W)  # [B, R]
     sat = scores >= thresh[None, :]

@@ -31,7 +31,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops.match import (
     INT32_MAX,
-    _lit_dtype,
     _lit_matrix_codes,
     _scores,
     _tier_walk,
@@ -259,7 +258,6 @@ class PartitionedPlanes:
         mesh: Mesh,
         packed,
         policy_shard: Dict[str, str],
-        int8_plane: bool,
         prior: "Optional[PartitionedPlanes]" = None,
         max_rules_per_partition: Optional[int] = None,
         width_align: int = 64,
@@ -291,16 +289,13 @@ class PartitionedPlanes:
             prior = None  # layout changed: nothing is reusable
 
         L = packed.W.shape[0]
-        w_dtype = np.int8 if int8_plane else jnp.bfloat16
-        thresh_host = (
-            packed.thresh.astype(np.int32) if int8_plane else packed.thresh
-        )
+        thresh_host = packed.thresh.astype(np.int32)
         col_map = np.full(n_parts * r_part, -1, dtype=np.int32)
         w_parts, t_parts, g_parts, p_parts = [], [], [], []
         for p, rows in enumerate(parts):
             k = len(rows)
             col_map[p * r_part : p * r_part + k] = rows
-            W_p = np.zeros((L, r_part), dtype=w_dtype)
+            W_p = np.zeros((L, r_part), dtype=np.int8)
             t_p = np.full((r_part,), 10**9, dtype=thresh_host.dtype)
             g_p = np.zeros((r_part,), dtype=packed.rule_group.dtype)
             pol_p = np.full(
@@ -308,7 +303,7 @@ class PartitionedPlanes:
             )
             if k:
                 idx = np.asarray(rows, dtype=np.intp)
-                W_p[:, :k] = np.asarray(packed.W, dtype=w_dtype)[:, idx]
+                W_p[:, :k] = np.asarray(packed.W, dtype=np.int8)[:, idx]
                 t_p[:k] = thresh_host[idx]
                 g_p[:k] = packed.rule_group[idx]
                 pol_p[:k] = packed.rule_policy[idx]
@@ -501,9 +496,7 @@ def sharded_codes_match_fn(
         donate_argnums=(0, 1) if donate else (),
     )
     def step(codes, extras, act_rows, W, thresh, rule_group, rule_policy):
-        lit = _lit_matrix_codes(
-            codes, extras, act_rows, _lit_dtype(W.dtype)
-        )  # [B, L]
+        lit = _lit_matrix_codes(codes, extras, act_rows)  # [B, L]
         scores = _scores(lit, W)  # [B, R] — R sharded
         sat = scores >= thresh[None, :]
         masked_min = jnp.where(sat, rule_policy[None, :], INT32_MAX)
@@ -560,7 +553,7 @@ def sharded_codes_bits_fn(mesh: Mesh, replicated_out: bool = False):
         jax.jit, in_shardings=in_shardings, out_shardings=out_shardings
     )
     def step(codes, extras, act_rows, W, thresh):
-        lit = _lit_matrix_codes(codes, extras, act_rows, _lit_dtype(W.dtype))
+        lit = _lit_matrix_codes(codes, extras, act_rows)
         scores = _scores(lit, W)
         sat = scores >= thresh[None, :]
         return _pack_sat_bits(sat)

@@ -79,7 +79,6 @@ def _clone_engine(name: str, template):
     return TPUPolicyEngine(
         schema=template.schema,
         device=template.device,
-        use_pallas=template.use_pallas,
         mesh=template.mesh,
         segred=template.segred,
         name=name,

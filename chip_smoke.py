@@ -29,8 +29,7 @@ policies), and fails unless the answers provably came from the device:
               >= 64 AdmissionReviews.
   6. compare  every decision and reason set with the oracle's. Any
               disagreement fails the run.
-  7. evidence /debug/engine and /metrics must show: platform tpu; the
-              pallas plane selected and its layout on the live set;
+  7. evidence /debug/engine and /metrics must show: platform tpu;
               fallback_policies == native_opaque_policies == 0; the row
               routing counters accounting for every SAR as a device-decoded
               row (clean_native + flagged + encoder_gate == sent - cache
@@ -60,7 +59,7 @@ CPU mode, for debugging this script before chip time is spent::
         --max-batch 8 --out /tmp/smoke
 
 runs the same phases and checks in well under a minute, with only the
-platform/pallas checks relaxed. Without ``--allow-cpu`` a CPU platform is a
+platform check relaxed. Without ``--allow-cpu`` a CPU platform is a
 failure, whatever the size.
 """
 
@@ -598,7 +597,7 @@ def parse_args(argv=None):
     )
     p.add_argument(
         "--allow-cpu", action="store_true",
-        help="debug mode: accept a CPU platform (and no pallas plane)",
+        help="debug mode: accept a CPU platform",
     )
     p.add_argument(
         "--webhook-arg", action="append", default=[],
@@ -759,10 +758,6 @@ def run(args, out: pathlib.Path, result: dict, checks: list) -> None:
             if not args.allow_cpu:
                 check(f"{path}: platform is tpu", eng.get("platform") == "tpu",
                       eng.get("platform"))
-                check(f"{path}: pallas plane selected and laid out",
-                      eng.get("use_pallas") is True
-                      and eng.get("pallas_layout") is True,
-                      {k: eng.get(k) for k in ("use_pallas", "pallas_layout")})
             check(f"{path}: no fallback / native-opaque policies",
                   eng.get("fallback_policies") == 0
                   and eng.get("native_opaque_policies") == 0,

@@ -8,7 +8,6 @@ for-decision, including tier descent, error semantics, and default deny.
 
 import random
 
-import os
 
 import pytest
 
@@ -510,10 +509,6 @@ def test_randomized_policies_differential():
     check([src], cases)
 
 
-@pytest.mark.skipif(
-    os.environ.get("CEDAR_TPU_PALLAS") == "1",
-    reason="the pallas kernel ships no in-call compaction payload by design\n    (resolve_flagged falls back to the standalone bits kernel)",
-)
 def test_want_bits_bitmap_matches_bits_kernel():
     """The compacted in-call bits payload (match_arrays want_bits) must be
     row-identical to the standalone bitset kernel, cover exactly the
@@ -553,10 +548,6 @@ forbid (principal, action, resource) when { resource.resource == "nodes" };
         assert (row == ref[i]).all()
 
 
-@pytest.mark.skipif(
-    os.environ.get("CEDAR_TPU_PALLAS") == "1",
-    reason="the pallas kernel ships no in-call compaction payload by design\n    (resolve_flagged falls back to the standalone bits kernel)",
-)
 def test_bits_compaction_overflow_falls_back():
     """More flagged rows than the device compaction carries (BITS_TOPK):
     the overflow rows must still render exact reason sets via the
@@ -600,44 +591,3 @@ permit (principal, action, resource) when { principal.name == "test-user" };
     for decision, diag in results:
         assert decision == "allow"
         assert len(diag.reasons) == 2
-
-
-def test_int8_and_bf16_planes_agree(monkeypatch):
-    """The int8 scoring plane (default since r5 — int8 W, int32
-    accumulation, 2x MXU peak) and the bf16 plane must produce identical
-    decisions and reason/error sets: both are exact (ops/match.py module
-    docstring), so any divergence is a dtype/packing bug."""
-    src = DEMO + """
-permit (principal, action, resource is k8s::Resource)
-  when { principal.name == "test-user" && resource.resource == "jobs" };
-permit (principal in k8s::Group::"devs", action == k8s::Action::"get",
-        resource is k8s::Resource)
-  when { resource.resource == "jobs" };
-"""
-    cases = [
-        sar(verb="get", resource="pods"),
-        sar(verb="list", resource="nodes"),
-        sar(verb="get", resource="secrets"),
-        sar(verb="get", resource="jobs"),  # multi-match: two permits
-        sar(user=SA, verb="get", resource="pods"),
-        sar(verb="create", resource="services", resource_request=False,
-            path="/healthz"),
-    ]
-    items = [record_to_cedar_resource(a) for a in cases]
-
-    def run(env_val):
-        monkeypatch.setenv("CEDAR_TPU_INT8", env_val)
-        engine = TPUPolicyEngine()
-        engine.load([PolicySet.from_source(src, "p")], warm="off")
-        assert engine._compiled.W_dev.dtype == (
-            __import__("jax").numpy.int8 if env_val == "1"
-            else __import__("jax").numpy.bfloat16
-        )
-        return engine.evaluate_batch(items)
-
-    int8_res = run("1")
-    bf16_res = run("0")
-    for (d1, g1), (d2, g2), attrs in zip(int8_res, bf16_res, cases):
-        assert d1 == d2, attrs
-        assert {r.policy for r in g1.reasons} == {r.policy for r in g2.reasons}
-        assert _err_policies(g1.errors) == _err_policies(g2.errors)

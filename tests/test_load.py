@@ -642,8 +642,14 @@ class TestPipelinedBacklog:
             ]
             for t in ts:
                 t.start()
+            # the collector claims a batch only when the dispatch thread
+            # can take it, so all six may be enqueued before the first
+            # claim: wait for both conditions, not for the backlog alone
             deadline = time.monotonic() + 2.0
-            while b.backlog() < 6 and time.monotonic() < deadline:
+            while (
+                not (b.backlog() == 6 and b.queue_fill() < 6)
+                and time.monotonic() < deadline
+            ):
                 time.sleep(0.005)
             assert b.backlog() == 6
             # the collector has claimed at least one batch into the

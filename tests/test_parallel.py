@@ -56,15 +56,15 @@ def test_sharded_codes_step_matches_single_device():
     extras[:, 0] = rng.integers(0, packed.L + 64, size=B)
 
     # single-device reference through the chunked production kernel
+    thresh = packed.thresh.astype(np.int32)
     W3, t3, g3, p3 = chunk_rules(
-        packed.W.astype(np.float32), packed.thresh,
-        packed.rule_group, packed.rule_policy,
+        packed.W, thresh, packed.rule_group, packed.rule_policy,
     )
     ref_words, (ref_first, _ref_count) = match_rules_codes(
         jnp.asarray(codes, jnp.int16),
         jnp.asarray(extras, jnp.int16),
         jnp.asarray(table.rows),
-        jnp.asarray(W3, jnp.bfloat16),
+        jnp.asarray(W3),
         jnp.asarray(t3),
         jnp.asarray(g3),
         jnp.asarray(p3),
@@ -76,8 +76,8 @@ def test_sharded_codes_step_matches_single_device():
     cargs = shard_codes_tensors(
         mesh,
         jnp.asarray(table.rows),
-        jnp.asarray(packed.W.astype(np.float32), jnp.bfloat16),
-        jnp.asarray(packed.thresh),
+        jnp.asarray(packed.W),
+        jnp.asarray(thresh),
         jnp.asarray(packed.rule_group),
         jnp.asarray(packed.rule_policy),
     )

@@ -3,7 +3,7 @@
 pack() lays rules out group-contiguously, so the per-group first/last-
 match can reduce over static column segments (ops/match.py
 _first_match_seg) instead of n_groups masked passes. The plane is opt-in
-until tools/hw_validate.py shows a measured win on hardware; these tests
+until a benchmark cell shows a measured win on the chip; these tests
 pin exact equality against the default scan plane either way.
 """
 
