@@ -85,6 +85,31 @@ _DECISION_LABEL = {
 # (reference server.go:221-226)
 _LABEL_OPS = {"In": "in", "NotIn": "notin", "Exists": "exists", "DoesNotExist": "!"}
 
+# A webhook POST's header block as the handler's one scan takes it
+# (Handler._scan_request, docs/performance.md "One read, one write"):
+# field lines of a printable name, a colon and printable ASCII, each ended
+# by CRLF, the blank line last; 99 of them at most, because http.client
+# reads 100 lines, the blank one among them. A folded line, a bare CR or
+# LF, a byte the email parser would break a line at or a line with no name
+# does not match, and the request goes to http.server's reader untouched.
+_HEADER_BLOCK = re.compile(rb"(?:[!-9;-~]+:[\t -~]*\r\n){1,99}\r\n")
+
+
+class _ScannedHeaders:
+    """The header fields of a scanned request under lower-cased names.
+    ``get`` is what the handler, the tenant front end and
+    ingest_request_id ask of ``headers``; of a repeated name the first
+    value stands, as ``email.message.Message.get`` has it."""
+
+    __slots__ = ("_fields",)
+
+    def __init__(self, fields: dict):
+        self._fields = fields
+
+    def get(self, name: str, default=None):
+        return self._fields.get(name.lower(), default)
+
+
 # per-request observation context (cedar_tpu/obs): the serving layers
 # report cached/fallback facts UPWARD to the request handler's trace
 # tail-keep + audit line without changing any layer's call contract — a
@@ -1545,17 +1570,39 @@ class WebhookServer:
             def log_message(self, fmt, *args):
                 log.debug("%s %s", self.address_string(), fmt % args)
 
+            # the Server and Date lines of a reply, worked out once a second
+            _stamp = (0, "")
+
             def _write_json(
                 self, doc: dict, code: int = 200, headers: dict = None
             ):
+                """The whole reply as one bytes and one write: the status
+                line and the header lines send_response would write, in
+                its order, then ``headers``, then the body. A second write
+                waits out the peer's delayed ACK where the kernel keeps
+                Nagle's algorithm, and gives the interpreter up once
+                more."""
                 data = json.dumps(doc).encode()
-                self.send_response(code)
-                self.send_header("Content-Type", "application/json")
-                self.send_header("Content-Length", str(len(data)))
-                for k, v in (headers or {}).items():
-                    self.send_header(k, v)
-                self.end_headers()
-                self.wfile.write(data)
+                if log.isEnabledFor(logging.DEBUG):
+                    self.log_request(code)
+                now = int(time.time())
+                second, stamp = Handler._stamp
+                if second != now:
+                    stamp = (
+                        f"Server: {self.version_string()}\r\n"
+                        f"Date: {self.date_time_string(now)}\r\n"
+                    )
+                    Handler._stamp = (now, stamp)
+                extra = "".join(
+                    f"{k}: {v}\r\n" for k, v in (headers or {}).items()
+                )
+                head = (
+                    f"{self.protocol_version} {code} "
+                    f"{self.responses.get(code, ('',))[0]}\r\n{stamp}"
+                    "Content-Type: application/json\r\n"
+                    f"Content-Length: {len(data)}\r\n{extra}\r\n"
+                )
+                self.wfile.write(head.encode("latin-1") + data)
 
             # Request phases (docs/observability.md): with a tracer wired,
             # every request on this connection gets a RequestPhases record
@@ -1565,17 +1612,63 @@ class WebhookServer:
             # it has read the line, and flushes right after do_POST.
             _phases = None
             _t_flushed = None
+            # how this request was read (cedar_http_reads_total): "scan" or
+            # "full" once parse_request has run, and the path it was for
+            _read_how = None
+            _read_path = None
 
             def parse_request(self):
                 if server.tracer is not None:
                     self._phases = RequestPhases(self._t_flushed)
-                return super().parse_request()
+                scanned = self._scan_request()
+                self._read_how = "scan" if scanned else "full"
+                return scanned or super().parse_request()
+
+            def _scan_request(self) -> bool:
+                """Read the request every apiserver sends — ``POST <path>
+                HTTP/1.1`` and a plain header block that is whole in the
+                reader's buffer — in one pass, and leave what
+                http.server's parse_request leaves. False for any other
+                request, with nothing taken from ``rfile``: the caller
+                hands it to http.server, which answers it as it always
+                has."""
+                requestline = str(self.raw_requestline, "iso-8859-1")
+                words = requestline.split()
+                if (
+                    len(words) != 3
+                    or words[0] != "POST"
+                    or words[2] != "HTTP/1.1"
+                    or words[1].startswith("//")
+                ):
+                    return False
+                block = _HEADER_BLOCK.match(self.rfile.peek())
+                if block is None:
+                    return False
+                lines = str(block.group(), "iso-8859-1")[:-4].split("\r\n")
+                fields = {}
+                for line in reversed(lines):  # the first of a name stands
+                    name, _, value = line.partition(":")
+                    fields[name.lower()] = value.lstrip(" \t")
+                if "expect" in fields or "transfer-encoding" in fields:
+                    return False
+                self.rfile.read(block.end())
+                self.requestline = requestline.rstrip("\r\n")
+                self.command, self.path, self.request_version = words
+                self.close_connection = (
+                    fields.get("connection", "").lower() == "close"
+                )
+                self.headers = _ScannedHeaders(fields)
+                return True
 
             def handle_one_request(self):
-                self._phases = None
+                self._phases = self._read_how = self._read_path = None
                 try:
                     super().handle_one_request()
                 finally:
+                    if self._read_how is not None:
+                        metrics.record_http_read(
+                            self._read_path or "other", self._read_how
+                        )
                     phases = self._phases
                     if phases is not None:
                         phases.t_flush = self._t_flushed = time.monotonic()
@@ -1656,6 +1749,7 @@ class WebhookServer:
                         else "admission" if path == "/v1/admit"
                         else None
                     )
+                    self._read_path = path_label
                     if phases is not None and path_label is not None:
                         metrics.record_request_body_bytes(path_label, length)
                     priority = ""

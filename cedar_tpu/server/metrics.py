@@ -899,6 +899,22 @@ request_phase_seconds = REGISTRY.register(
     )
 )
 
+http_reads_total = REGISTRY.register(
+    Counter(
+        "cedar_http_reads_total",
+        "Requests the webhook's handler read, partitioned by path "
+        "(authorization / admission / other: a request answered before "
+        "its endpoint was known) and how: scan = the handler's one pass "
+        "over the buffered header block (a POST, HTTP/1.1, plain header "
+        "lines); full = http.server's own reader (any other method or "
+        "version, Expect, Transfer-Encoding, a folded or malformed line, "
+        "a header block that came in pieces). A rising full share under "
+        "apiserver traffic means something in front re-shapes requests "
+        "(docs/performance.md \"One read, one write\").",
+        ["path", "how"],
+    )
+)
+
 # The admission path's request timer and the sizes of what a request
 # carries (docs/observability.md "Request phases"): the phase ledger says
 # where an admission request's time went; these say how long the handler
@@ -1616,6 +1632,10 @@ def record_request_phases(path: str, names, stamps) -> None:
     names, and one boundary stamp more) into the phase ledger, under one
     lock."""
     request_phase_seconds.observe_steps(path, names, stamps)
+
+
+def record_http_read(path: str, how: str) -> None:
+    http_reads_total.inc(path=path, how=how)
 
 
 def record_admission_latency(decision: str, latency_s: float) -> None:

@@ -87,7 +87,11 @@ def test_a_claim_metric_is_the_entry_the_issue_asked_for(metric):
     for cell in cells:
         assert moves in {x["name"] for x in m.metrics_for(cell, "end_to_end")}
         assert metric in {x["name"] for x in m.metrics_for(cell, "per_layer")}
-    # the three stand at the end of the list: nothing that was there moved
-    assert [x["name"] for x in m.doc["per_layer"][-3:]] == [
+    # the three stand together where PR 31 appended them, after everything
+    # that was there then: later entries come after them
+    names = [x["name"] for x in m.doc["per_layer"]]
+    at = names.index("claim_held_share.saturate")
+    assert names[at:at + 3] == [
         "claim_held_share.saturate", "claim_held_share.lone",
         "claim_held_share.admission"]
+    assert names[at - 1] == "flagged_row_share.admit"
