@@ -185,8 +185,21 @@ class FingerprintMemo:
         self.capacity = max(1, int(capacity))
         self._lock = threading.Lock()
         self._memo: "OrderedDict[bytes, Optional[str]]" = OrderedDict()
+        # counted under the lock the lookup takes anyway; /metrics reads
+        # them at scrape time (cedar_fingerprint_memo_total)
+        self._hits = 0
+        self._misses = 0
+
+    def counts(self) -> Tuple[int, int]:
+        """(hits, misses) since start: a miss paid the JSON parse."""
+        with self._lock:
+            return self._hits, self._misses
 
     def fingerprint(self, endpoint: str, body: bytes) -> Optional[str]:
+        return self.lookup(endpoint, body)[0]
+
+    def lookup(self, endpoint: str, body: bytes) -> Tuple[Optional[str], bool]:
+        """(fingerprint, whether the memo held it)."""
         # tenant-scoped memo rows: two tenants' byte-identical bodies map
         # to DIFFERENT canonical fingerprints, so the raw-digest key must
         # split on the tenant too or the second tenant would hit the
@@ -203,14 +216,16 @@ class FingerprintMemo:
         with self._lock:
             if digest in self._memo:
                 self._memo.move_to_end(digest)
-                return self._memo[digest]
+                self._hits += 1
+                return self._memo[digest], True
+            self._misses += 1
         fp = fingerprint_body(endpoint, body)
         with self._lock:
             self._memo[digest] = fp
             self._memo.move_to_end(digest)
             while len(self._memo) > self.capacity:
                 self._memo.popitem(last=False)
-        return fp
+        return fp, False
 
 
 def recorded_name_parts(url_path: str, body: bytes) -> Tuple[str, str]:

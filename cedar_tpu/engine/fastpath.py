@@ -73,19 +73,39 @@ from .evaluator import (
 
 log = logging.getLogger(__name__)
 
+
+class RuleResult(tuple):
+    """A (decision, reason, error) that the webhook's own rules gave
+    before Cedar was asked (self-allow, system:* skip). A tuple to every
+    consumer; the request handler reads ``answered_by`` off it for the
+    ``by`` label of its timer (server/http.py)."""
+
+    __slots__ = ()
+    answered_by = "rule"
+
+
+class InterpreterResult(tuple):
+    """A (decision, reason, error) that the interpreter gave for a row the
+    device plane could not answer (a gated row, an encoder fallback, a
+    degraded batch)."""
+
+    __slots__ = ()
+    answered_by = "interpreter"
+
+
 # (decision, reason, error) results for gate flags (authorizer.go:38-57)
 _GATE_RESULTS = {
-    F_SELF_ALLOW_POLICIES: (
+    F_SELF_ALLOW_POLICIES: RuleResult((
         DECISION_ALLOW,
         "cedar authorizer is always allowed to access policies",
         None,
-    ),
-    F_SELF_ALLOW_RBAC: (
+    )),
+    F_SELF_ALLOW_RBAC: RuleResult((
         DECISION_ALLOW,
         "cedar authorizer is always allowed to read RBAC policies",
         None,
-    ),
-    F_SYSTEM_SKIP: (DECISION_NO_OPINION, "", None),
+    )),
+    F_SYSTEM_SKIP: RuleResult((DECISION_NO_OPINION, "", None)),
 }
 
 # (decision, reason, error): error non-None mirrors the webhook handler's
@@ -843,12 +863,12 @@ class SARFastPath(_RawFastPath):
         """Evaluate a batch of raw SAR JSON bodies -> (decision, reason)."""
         snap = self._current_snapshot()
         if snap is None:
-            return [self._fallback(b) for b in bodies]
+            return [self._fallback_row(b) for b in bodies]
         if not self.authorizer.ready():
             # NoOpinion until every store's initial load completes
             # (authorizer.go:58-66); gates still apply, so run the exact path
-            return [self._fallback(b) for b in bodies]
-        return self._guarded_process(bodies, snap, self._fallback)
+            return [self._fallback_row(b) for b in bodies]
+        return self._guarded_process(bodies, snap, self._fallback_row)
 
     def _pipeline_ready(self) -> bool:
         return self.authorizer.ready()
@@ -868,13 +888,13 @@ class SARFastPath(_RawFastPath):
         )[0]
 
     def _fallback_row(self, body: bytes) -> Result:
-        return self._fallback(body)
+        return InterpreterResult(self._fallback(body))
 
     def _run_gated(self, bodies: List[bytes]) -> List[Result]:
         if self._fallback == self._python_fallback:
-            return self._gated_batch(bodies)
+            return [InterpreterResult(r) for r in self._gated_batch(bodies)]
         # honor an injected custom fallback per row
-        return [self._fallback(b) for b in bodies]
+        return [self._fallback_row(b) for b in bodies]
 
     def _decode_word_payload(self, snap: _Snapshot, word: int) -> Result:
         """Decode + cache one clean verdict word (no multi/err/gate flags —

@@ -171,6 +171,22 @@ def _octx_mark(key: str) -> None:
         ctx[key] = True
 
 
+def _answered_by(octx: dict, answer) -> str:
+    """Who answered an authorization request, for the ``by`` label of the
+    handler's timer and the root span's ``answered_by``: the decision
+    cache, the webhook's own rules before Cedar or the interpreter for
+    one row (both marked on the result by engine/fastpath.py), the
+    interpreter in the request thread, else the engine."""
+    if "cached" in octx:
+        return "cache"
+    by = getattr(answer, "answered_by", None)
+    if by is not None:
+        return by
+    if "interpreter" in octx:
+        return "interpreter"
+    return "engine"
+
+
 def convert_extra(extra: Optional[dict]) -> dict:
     """Extra keys are lower-cased (reference convertExtraForAuthorizerAttributes,
     server.go:205-214)."""
@@ -810,10 +826,10 @@ class WebhookServer:
             set_current(trace)
         # per-request facts the layers below report upward for the audit
         # line and the trace tail-keep policy (cached answer? served by a
-        # degraded/fallback path?) without changing their return contracts
+        # degraded/fallback path?) without changing their return contracts;
+        # the timer's `by` label reads them too, so they are always kept
         octx: dict = {}
-        if trace is not None or self.audit_log is not None:
-            _octx_set(octx)
+        _octx_set(octx)
         tenant = getattr(body, "tenant", "")
         if tenant and trace is not None:
             trace.root.set_attr("tenant", tenant)
@@ -825,11 +841,13 @@ class WebhookServer:
         if protocol and trace is not None:
             trace.root.set_attr("protocol", protocol)
         decision, reason, error = DECISION_NO_OPINION, "", None
+        answer = None
         try:
             try:
-                decision, reason, error = self._authorize_cached(
+                answer = self._authorize_cached(
                     body, request_id, priority=priority
                 )
+                decision, reason, error = answer
             except RequestShed as e:
                 # the evaluation-stage gate refused an already-admitted
                 # request (server saturated by the time its cache-missed
@@ -870,8 +888,11 @@ class WebhookServer:
             latency = time.monotonic() - start
             if phases is not None:
                 phases.t_stop = start + latency
+            by = _answered_by(octx, answer)
             metrics.record_request_total(label, protocol=protocol)
-            metrics.record_request_latency(label, latency, protocol=protocol)
+            metrics.record_request_latency(
+                label, latency, protocol=protocol, by=by
+            )
             if tenant:
                 metrics.record_tenant_request(
                     "authorization", tenant, label, latency
@@ -887,6 +908,7 @@ class WebhookServer:
                 except Exception:  # noqa: BLE001 — never break serving
                     log.exception("slo record failed")
             if trace is not None:
+                trace.root.set_attr("answered_by", by)
                 self._finish_trace(
                     trace, phases, octx, label, error is not None
                 )
@@ -924,7 +946,14 @@ class WebhookServer:
             return self._authorize_uncached(
                 body, request_id, priority=priority, deadline=deadline
             )
-        key = self._sar_memo.fingerprint("authorize", body)
+        t_fp = time.monotonic()
+        key, memo_hit = self._sar_memo.lookup("authorize", body)
+        tr = current_trace()
+        if tr is not None:
+            # every request pays this one: built only for a kept trace
+            tr.defer_span(
+                "cache.fingerprint", t_fp, time.monotonic(), memo_hit=memo_hit
+            )
         if key is None:
             # unparseable body: the uncached path produces the exact
             # decode-error answer (never cached — the fingerprint requires
@@ -1141,6 +1170,7 @@ class WebhookServer:
             # a wired device plane was bypassed: fallback-served, which
             # tail-keeps the trace and stamps the audit line
             _octx_mark("fallback")
+        _octx_mark("interpreter")
         with trace_span("interpreter") as sp:
             if sp is not None:
                 sp.set_attr("reason", py_reason)
@@ -2009,6 +2039,12 @@ class WebhookServer:
                             server.slo.publish()
                         except Exception:  # noqa: BLE001 — scrape must serve
                             log.exception("slo publish failed")
+                    if server._sar_memo is not None:
+                        # the memo counts under its own lock; the scrape
+                        # mirrors the two totals
+                        metrics.set_fingerprint_memo(
+                            "authorization", *server._sar_memo.counts()
+                        )
                     data = metrics.REGISTRY.expose().encode()
                     self.send_response(200)
                     self.send_header(

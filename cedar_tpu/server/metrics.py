@@ -3,7 +3,7 @@
 Metric names/labels/buckets parity with reference
 internal/server/metrics/metrics.go:
   * ``cedar_authorizer_request_total{decision}`` counter (:28-36)
-  * ``cedar_authorizer_request_duration_seconds{decision}`` histogram,
+  * ``cedar_authorizer_request_duration_seconds{decision,by}`` histogram,
     buckets 0.25/0.5/0.7/1/1.5/3/5/10 (:38-47)
   * ``cedar_authorizer_e2e_latency_seconds{filename}`` histogram,
     exponential buckets 2*2^i, 8 buckets (:49-58)
@@ -70,6 +70,13 @@ class Counter:
         key = tuple((k, labels.get(k, "")) for k in self.label_names) + tuple(extra)
         with self._lock:
             self._values[key] = self._values.get(key, 0.0) + amount
+
+    def set_total(self, value: float, **labels) -> None:
+        """Mirror a total that its owner counts under a lock of its own
+        (refreshed at scrape time): never mixed with ``inc`` on a series."""
+        key = tuple((k, labels.get(k, "")) for k in self.label_names)
+        with self._lock:
+            self._values[key] = float(value)
 
     def collect(self) -> List[str]:
         out = [f"# HELP {self.name} {self.help}", f"# TYPE {self.name} counter"]
@@ -286,8 +293,14 @@ request_total = REGISTRY.register(
 request_latency = REGISTRY.register(
     Histogram(
         f"{SUBSYSTEM}_request_duration_seconds",
-        "Request latency in seconds partitioned by authorization decision.",
-        ["decision"],
+        "Request latency in seconds partitioned by authorization decision "
+        "and by who answered (by: cache = the decision cache; engine = the "
+        "device plane; rule = the webhook's own rules before Cedar, "
+        "self-allow and the system:* skip; interpreter = the interpreter, "
+        "for a gated or fallen-back row, a bypassed plane or a deployment "
+        "without one). The one observation a request makes on this family "
+        "carries both labels; sum over `by` for the family as it was.",
+        ["decision", "by"],
         [0.25, 0.5, 0.7, 1, 1.5, 3, 5, 10],
     )
 )
@@ -812,6 +825,21 @@ decision_cache_hit_ratio = REGISTRY.register(
         "ratio, and a collapse usually means TTLs are too short or policy "
         "reloads are churning generations.",
         ["path"],
+    )
+)
+
+fingerprint_memo_total = REGISTRY.register(
+    Counter(
+        "cedar_fingerprint_memo_total",
+        "Body-digest -> canonical-fingerprint memo lookups in front of the "
+        "decision cache (cache/fingerprint.py FingerprintMemo), by path "
+        "and outcome: a hit is one sha256 and a dict read, a miss pays "
+        "the JSON parse and the canonical hash the memo exists to avoid. "
+        "Counted under the memo's own lock and mirrored here at scrape "
+        "time, so the request path pays no observation for it. A hit "
+        "share well under the decision cache's means the working set of "
+        "bodies outgrew the memo.",
+        ["path", "outcome"],
     )
 )
 
@@ -1542,10 +1570,10 @@ def record_row_routing(path: str, row_class: str, n: int) -> None:
 
 
 def record_request_latency(
-    decision: str, latency_s: float, protocol: str = ""
+    decision: str, latency_s: float, protocol: str = "", by: str = "engine"
 ) -> None:
     request_latency.observe(
-        latency_s, decision=decision, extra=_protocol_extra(protocol)
+        latency_s, decision=decision, by=by, extra=_protocol_extra(protocol)
     )
 
 
@@ -1603,6 +1631,11 @@ def record_cache_coalesced(path: str) -> None:
 
 def set_cache_size(path: str, size: int) -> None:
     decision_cache_size.set(size, path=path)
+
+
+def set_fingerprint_memo(path: str, hits: int, misses: int) -> None:
+    fingerprint_memo_total.set_total(hits, path=path, outcome="hit")
+    fingerprint_memo_total.set_total(misses, path=path, outcome="miss")
 
 
 def set_cache_hit_ratio(path: str, ratio: float) -> None:

@@ -261,6 +261,25 @@ class TestFingerprint:
             )
         assert len(memo._memo) <= 4
 
+    def test_memo_counts_a_hit_and_a_miss_for_what_they_were(self):
+        memo = FingerprintMemo(capacity=2)
+        bodies = [json.dumps(make_sar(name=f"n{i}")).encode() for i in range(3)]
+        assert memo.counts() == (0, 0)
+        fp, hit = memo.lookup("authorize", bodies[0])
+        assert fp is not None and hit is False
+        assert memo.lookup("authorize", bodies[0]) == (fp, True)
+        assert memo.fingerprint("authorize", bodies[0]) == fp
+        assert memo.counts() == (2, 1)
+        # a body pushed out by the capacity is a miss again
+        memo.lookup("authorize", bodies[1])
+        memo.lookup("authorize", bodies[2])
+        assert memo.lookup("authorize", bodies[0])[1] is False
+        assert memo.counts() == (2, 4)
+        # a body that does not parse is remembered as such, and counted
+        assert memo.lookup("authorize", b"{") == (None, False)
+        assert memo.lookup("authorize", b"{") == (None, True)
+        assert memo.counts() == (3, 5)
+
 
 # ------------------------------------------------------------ decision cache
 

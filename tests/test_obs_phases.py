@@ -256,6 +256,78 @@ def test_a_cache_hit_has_no_pipeline_phases():
         s.stop()
 
 
+def timer_counts_by():
+    """{by: observations} of the handler's timer, over every decision."""
+    out = {}
+    with metrics.request_latency._lock:
+        for key, n in metrics.request_latency._totals.items():
+            by = dict(key)["by"]
+            out[by] = out.get(by, 0) + n
+    return out
+
+
+def system_sar(i):
+    doc = sar(i)
+    doc["spec"]["user"] = "system:kube-scheduler"
+    return doc
+
+
+def test_the_timer_says_who_answered_and_the_memo_counts():
+    """One observation a request, labelled by who answered: a repeated body
+    by the cache, a system:* user by the webhook's own rule, the rest by
+    the engine; the root span says the same, the cache.fingerprint span
+    says whether the memo held the body, and /metrics (the memo's two
+    counters among it) still reads under the benchmark's parser."""
+    import urllib.request
+
+    from benchmark import prom
+
+    s = Served(decision_cache=DecisionCache())
+    try:
+        before = timer_counts_by()
+        sent = [sar(0), sar(0), sar(1), system_sar(2), sar(0), system_sar(3)]
+        conn = s.connection()
+        for doc in sent:
+            resp, _ = post(conn, "/v1/authorize", doc)
+            assert resp.status == 200
+        conn.close()
+        deadline = time.monotonic() + 5
+        while len(s.records) < len(sent) and time.monotonic() < deadline:
+            time.sleep(0.01)
+        after = timer_counts_by()
+        moved = {by: after[by] - before.get(by, 0) for by in after
+                 if after[by] != before.get(by, 0)}
+        assert moved == {"engine": 2, "cache": 2, "rule": 2}
+        assert sum(moved.values()) == len(sent)
+
+        docs = [s.tracer.get(r.trace.trace_id) for r in s.records]
+        assert [d["spans"][0]["attrs"]["answered_by"] for d in docs] == [
+            "engine", "cache", "engine", "rule", "cache", "rule"]
+        memo_hits = []
+        for d in docs:
+            (fp,) = [sp for sp in d["spans"] if sp["name"] == "cache.fingerprint"]
+            (look,) = [sp for sp in d["spans"] if sp["name"] == "cache.lookup"]
+            assert fp["start_us"] <= look["start_us"]
+            memo_hits.append(fp["attrs"]["memo_hit"])
+        assert memo_hits == [False, True, False, False, True, False]
+        assert s.server._sar_memo.counts() == (2, 4)
+
+        with urllib.request.urlopen(
+            f"http://127.0.0.1:{s.server.bound_metrics_port}/metrics", timeout=10
+        ) as r:
+            samples = prom.parse(r.read().decode())
+        memo = {labels["outcome"]: v for name, labels, v in samples
+                if name == "cedar_fingerprint_memo_total"
+                and labels["path"] == "authorization"}
+        assert memo == {"hit": 2.0, "miss": 4.0}
+        timer = "cedar_authorizer_request_duration_seconds_count"
+        for by, n in after.items():
+            assert prom.total(samples, timer, {"by": by}) == n
+        assert prom.total(samples, timer) == sum(after.values())
+    finally:
+        s.stop()
+
+
 def authorize_from_threads(served, callers, per_caller, path="authorization"):
     """Distinct requests from ``callers`` keep-alive connections at once;
     the X-Cedar-Trace-Id of every reply, once every request's phases are
