@@ -680,7 +680,7 @@ def run_pipeline_scenario() -> int:
     B = _n(4096, 1024)
     K = _n(30, 8)  # timed batches per round
     ROUNDS = _n(3, 2)
-    DEPTH, WORKERS = 3, 2
+    DEPTH = 3
 
     ps, users, nss, resources, verbs, groups = build_policy_set(n_policies)
     # segred mirrors the webhook CLI's cpu-backend serving default
@@ -755,7 +755,7 @@ def run_pipeline_scenario() -> int:
         stamps: list = []
         b = PipelinedBatcher(
             _BatchStages(stamps), max_batch=1, window_s=0.0,
-            depth=DEPTH, encode_workers=WORKERS,
+            depth=DEPTH,
         )
         results = [None] * n
 
@@ -799,7 +799,7 @@ def run_pipeline_scenario() -> int:
 
     serial_b = MicroBatcher(fast.authorize_raw, window_s=0.0002)
     piped_b = PipelinedBatcher(
-        fast, window_s=0.0002, depth=DEPTH, encode_workers=WORKERS
+        fast, window_s=0.0002, depth=DEPTH
     )
     try:
         s_lat: list = []
@@ -837,7 +837,6 @@ def run_pipeline_scenario() -> int:
         "single_request_no_regression": bool(lone_ok),
         "speedup_ok": bool(speedup >= 1.3),
         "pipeline_depth": DEPTH,
-        "encode_workers": WORKERS,
         "elapsed_s": round(time.time() - t0, 1),
     }
     print(json.dumps(result))
@@ -915,7 +914,7 @@ def run_steady_scenario() -> int:
     B = _n(4000, 1000)
     K = _n(24, 10)  # timed batches for the steady-state interval
     ND = 1152  # differential bodies (>= 1.1k even in smoke: it is a gate)
-    DEPTH, WORKERS = 3, 2
+    DEPTH = 3
 
     cache_dir = tempfile.mkdtemp(prefix="cedar-aot-steady-")
 
@@ -1104,7 +1103,7 @@ def run_steady_scenario() -> int:
         stamps: list = []
         pb = PipelinedBatcher(
             _Stages(stamps), max_batch=1, window_s=0.0,
-            depth=DEPTH, encode_workers=WORKERS,
+            depth=DEPTH,
         )
         results = [None] * n
 
@@ -1172,7 +1171,7 @@ def run_steady_scenario() -> int:
         return out
 
     pb_on = PipelinedBatcher(
-        fast, window_s=0.0002, depth=DEPTH, encode_workers=WORKERS
+        fast, window_s=0.0002, depth=DEPTH
     )
     try:
         on_res = run_submits(pb_on, bodies_d)
@@ -1193,7 +1192,7 @@ def run_steady_scenario() -> int:
         )
         fast_off = SARFastPath(engine_off, auth_off)
         pb_off = PipelinedBatcher(
-            fast_off, window_s=0.0002, depth=DEPTH, encode_workers=WORKERS
+            fast_off, window_s=0.0002, depth=DEPTH
         )
         off_depth = pb_off.debug_stats()["depth"]  # env hatch: must be 1
         try:
@@ -1239,7 +1238,6 @@ def run_steady_scenario() -> int:
         "decision_flips": flips,
         "single_buffer_depth": off_depth,
         "pipeline_depth": DEPTH,
-        "encode_workers": WORKERS,
         # the REAL resolved backend + process world size;
         # device_fallback preserves the never-read-as-device signal
         "backend": backend,
@@ -1773,7 +1771,7 @@ def run_chaos_scenario() -> int:
         fleet_replicas.append(
             EngineReplica(
                 i, r_engine, r_fast, breaker=r_breaker,
-                max_batch=256, pipeline_depth=2, encode_workers=1,
+                max_batch=256, pipeline_depth=2,
             )
         )
     fleet = EngineFleet(fleet_replicas)
@@ -1950,7 +1948,7 @@ def run_fleet_scenario() -> int:
             replicas.append(
                 EngineReplica(
                     i, eng, fp, max_batch=512, pipeline_depth=2,
-                    encode_workers=1, fleet_name=f"bench-fleet{n_rep}",
+                    fleet_name=f"bench-fleet{n_rep}",
                 )
             )
         fleet = EngineFleet(replicas, name=f"bench-fleet{n_rep}")
@@ -1970,7 +1968,7 @@ def run_fleet_scenario() -> int:
         return 1
     expected = ref_fast.authorize_raw(bodies)
     direct = PipelinedBatcher(
-        ref_fast, max_batch=512, window_s=0.0002, depth=2, encode_workers=1
+        ref_fast, max_batch=512, window_s=0.0002, depth=2
     )
     direct_lat = []
     for b in bodies[:LONE]:

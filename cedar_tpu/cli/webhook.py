@@ -509,7 +509,6 @@ def build_server(args) -> WebhookServer:
                 max_batch=args.max_batch,
                 window_s=args.batch_window_us / 1e6,
                 pipeline_depth=args.pipeline_depth,
-                encode_workers=args.encode_workers,
             )
         ]
         for i in range(1, args.fleet_replicas):
@@ -544,7 +543,6 @@ def build_server(args) -> WebhookServer:
                     max_batch=args.max_batch,
                     window_s=args.batch_window_us / 1e6,
                     pipeline_depth=args.pipeline_depth,
-                    encode_workers=args.encode_workers,
                 )
             )
         fleet = EngineFleet(
@@ -638,7 +636,6 @@ def build_server(args) -> WebhookServer:
                 fastpath=w_fast,
                 decision_cache=w_cache,
                 pipeline_depth=args.pipeline_depth,
-                encode_workers=args.encode_workers,
                 max_batch=args.max_batch,
                 batch_window_s=args.batch_window_us / 1e6,
                 request_timeout_s=(
@@ -1153,7 +1150,6 @@ def build_server(args) -> WebhookServer:
         batch_window_s=args.batch_window_us / 1e6,
         max_batch=args.max_batch,
         pipeline_depth=args.pipeline_depth,
-        encode_workers=args.encode_workers,
         request_timeout_s=(
             args.request_timeout_ms / 1e3 if args.request_timeout_ms > 0 else None
         ),
@@ -1335,7 +1331,10 @@ def make_parser() -> argparse.ArgumentParser:
         "--batch-window-us",
         type=float,
         default=200.0,
-        help="micro-batch forming window for the TPU fast path",
+        help="micro-batch forming window for the TPU fast path: with "
+        "--pipeline-depth > 0 slept only by a burst's first claim at an "
+        "idle pipeline (a request that comes alone is claimed at once), "
+        "with the serial loop by every claim (docs/performance.md)",
     )
     cedar.add_argument(
         "--max-batch",
@@ -1355,15 +1354,6 @@ def make_parser() -> argparse.ArgumentParser:
         "yet decoded at N. It does not let N encoded batches queue "
         "before the launch: one claimed batch stands there, and the rest "
         "of a backlog waits in the submit queue (the late claim)",
-    )
-    cedar.add_argument(
-        "--encode-workers",
-        type=int,
-        default=0,
-        help="host encode threads feeding the pipelined batcher (only "
-        "used with --pipeline-depth > 0); 0 auto-sizes from the native "
-        "encoder pool width — each worker's chunk encode already fans "
-        "across the persistent C++ worker pool (docs/performance.md)",
     )
     cedar.add_argument(
         "--native-encode-threads",

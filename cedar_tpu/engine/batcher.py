@@ -8,21 +8,23 @@ batch function in one call; each submitter blocks until its own result is
 ready. This is the micro-batching gRPC-link design of SURVEY.md §5.8,
 in-process.
 
-Latency shape: a lone request waits at most ``window_s`` (default 200µs)
-before the batch fires — well inside the p99 < 2ms budget — while a
-saturated server naturally forms large batches (up to ``max_batch``) and
-rides the device's throughput curve.
+Latency shape: under the serial batcher a lone request waits at most
+``window_s`` (default 200µs) before the batch fires, while a saturated
+server naturally forms large batches (up to ``max_batch``) and rides the
+device's throughput curve. The pipelined batcher claims a request that
+came alone to an idle pipeline at once, on no timer.
 
 ``PipelinedBatcher`` replaces the strictly serial worker loop with a
-three-stage pipeline (docs/performance.md): batch N+1's host ENCODE runs on
-a small worker pool while batch N's device work is in flight, the DISPATCH
-thread launches each encoded batch asynchronously and immediately moves to
-the next, and a DECODE thread materializes results and completes each
-submitter's slot. At most ONE claimed batch stands before the dispatch
-thread (the late claim, PipelinedBatcher): until that place is free the
-collector leaves requests in the submit queue, where they can still be
-withdrawn and join what arrives next; a bounded depth-``depth`` queue
-before the decode stage provides the backpressure behind the launch.
+three-stage pipeline (docs/performance.md): the COLLECT thread claims a
+batch and runs its host ENCODE itself while batch N's device work is in
+flight, the DISPATCH thread launches each encoded batch asynchronously and
+immediately moves to the next, and a DECODE thread materializes results
+and completes each submitter's slot. At most ONE claimed batch stands
+before the dispatch thread (the late claim, PipelinedBatcher): until that
+place is free the collector leaves requests in the submit queue, where
+they can still be withdrawn and join what arrives next; a bounded
+depth-``depth`` queue before the decode stage provides the backpressure
+behind the launch.
 Submission semantics (deadline withdrawal, coalescing, drain-on-stop) are
 IDENTICAL to the serial batcher: both share one queue/slot front end, and
 the stages are required to produce the same results the serial batch fn
@@ -96,13 +98,15 @@ def _record_occupancy(path: Optional[str], n: int) -> None:
         pass
 
 
-def _record_claim(path: Optional[str], held: bool) -> None:
+def _record_claim(path: Optional[str], held: bool, lingered: bool) -> None:
     if path is None:
         return
     try:
-        from ..server.metrics import record_batch_claim
+        from ..server.metrics import record_batch_claim, record_batch_linger
 
         record_batch_claim(path, held)
+        if lingered:
+            record_batch_linger(path)
     except Exception:  # noqa: BLE001 — metrics must never break serving
         pass
 
@@ -144,17 +148,20 @@ class _StageTimes:
     ``decode.device_wait`` / ``.host``), split by the obs.trace.sub_stage
     sites in engine/evaluator.py and engine/fastpath.py while this record
     is bound to the worker's thread (``part`` / ``part_t0``: the running
-    segment); a stage's parts sum to its window."""
+    segment); a stage's parts sum to its window. ``lingered``: the claim
+    slept out a forming window first (_form_batch)."""
 
     __slots__ = (
-        "claimed", "first_enq", "rows", "extras_max", "sub", "part", "part_t0",
+        "claimed", "first_enq", "lingered", "rows", "extras_max", "sub",
+        "part", "part_t0",
         "encode0", "encode1", "dispatch0", "dispatch1",
         "decode0", "decode1", "eval0", "eval1",
     )
 
-    def __init__(self, claimed: float):
+    def __init__(self, claimed: float, lingered: bool):
         self.claimed = claimed
         self.first_enq: Optional[float] = None
+        self.lingered = lingered
         self.rows = 0
         # the widest row's set-membership extras, where a native encode ran
         # (obs.trace.note_encode_extras): `extras_max` on batch.encode
@@ -589,8 +596,9 @@ class MicroBatcher:
             # dispatch thread is free (the late claim), so whatever
             # arrived while the launch of batch N held that thread is
             # already in the queue and rides one claim with NO host
-            # linger added to its latency. The pacing clock on the chip
-            # is that launch (5.68 of a dispatch's 6.11 ms at 32
+            # linger added to its latency — and 0 for a request that
+            # came alone to an idle pipeline. The pacing clock on the
+            # chip is that launch (5.68 of a dispatch's 6.11 ms at 32
             # callers, the device 0.130 of them: ledger, PR 30), not
             # the device (docs/performance.md).
             window = self._linger_window_s()
@@ -609,7 +617,9 @@ class MicroBatcher:
             # snapshot. The same pass stamps the batch's shared stage
             # record (queue-wait measured from the OLDEST member — the
             # worst wait in the batch is what the claim latency cost).
-            times = _StageTimes(time.monotonic()) if batch else None
+            times = (
+                _StageTimes(time.monotonic(), window > 0) if batch else None
+            )
             for _, slot in batch:
                 slot.times = times
                 if times.first_enq is None or slot.t_enq < times.first_enq:
@@ -710,7 +720,9 @@ class PipelinedBatcher(MicroBatcher):
     expose (engine/fastpath.py):
 
       * ``pipeline_encode(items) -> ctx`` — host-only parse/encode; runs on
-        a pool of ``encode_workers`` threads, one batch per worker
+        the collector's thread, which claimed the batch: one batch stands
+        before the dispatch thread, so one encode runs at a time and the
+        collector has nothing else it may do until that place is freed
       * ``pipeline_dispatch(ctx) -> ctx`` — launch the device work
         asynchronously (no blocking readback); runs on the dispatch thread,
         which immediately moves to the next encoded batch
@@ -745,6 +757,16 @@ class PipelinedBatcher(MicroBatcher):
     cedar_pipeline_stall_seconds_total{stage="collect"} and how often it
     engages as cedar_batch_claims_total{path, held}.
 
+    The forming window: a claim sleeps ``window_s`` only where nothing is
+    in flight and two or more entries already wait (a burst that has
+    begun, _linger_window_s). A request that came alone to an idle
+    pipeline is claimed at once: in the lone cells not one claim in
+    ~12,000 a run ever gained a second row from the window (ledger,
+    PR 34: batch_rows 1.0), and the timed wait cost a lone request 0.8 ms
+    on the chip's host for its 0.2 (queue phase 0.83-0.91 -> 0.05-0.07 ms:
+    my chip runs, PR 35). How often the window engages is
+    cedar_batch_lingers_total{path}.
+
     ``depth`` bounds the batches launched and not yet decoded (the queue
     before the decode stage): when the device or the decode falls behind,
     the dispatch thread blocks there with the place still taken, and the
@@ -763,13 +785,10 @@ class PipelinedBatcher(MicroBatcher):
         max_batch: int = 8192,
         window_s: float = 0.0002,
         depth: int = 2,
-        encode_workers: int = 2,
         metrics_path: Optional[str] = None,
         replica: str = "",
         dispatch_seam: Optional[str] = None,
     ):
-        from concurrent.futures import ThreadPoolExecutor
-
         self.stages = stages
         # CEDAR_TPU_INFLIGHT caps the in-flight batch depth from the
         # environment: "1" is the single-buffer escape hatch for the
@@ -783,20 +802,6 @@ class PipelinedBatcher(MicroBatcher):
             except ValueError:
                 pass
         self.depth = max(1, int(depth))
-        if encode_workers <= 0:
-            # auto-size (--encode-workers 0): each encode worker drives a
-            # whole chunk's C++ encode, which itself fans across the
-            # persistent native worker pool (native/encoder.cpp
-            # EncodePool) sized by CEDAR_NATIVE_THREADS / cores — a few
-            # python-level workers keep the dispatch stage fed without
-            # oversubscribing that pool
-            from ..native import _default_encode_threads
-
-            encode_workers = max(2, min(4, _default_encode_threads() // 4))
-        self.encode_workers = max(1, int(encode_workers))
-        self._pool = ThreadPoolExecutor(
-            self.encode_workers, thread_name_prefix="pipe-encode"
-        )
         self._batches_total = 0
         # batches accepted into the pipeline but not yet decoded; lets the
         # decode stage distinguish starvation (work exists upstream, the
@@ -954,7 +959,6 @@ class PipelinedBatcher(MicroBatcher):
             "max_batch": self.max_batch,
             "window_us": round(self.window_s * 1e6, 1),
             "depth": self.depth,
-            "encode_workers": self.encode_workers,
             "dispatch_queue": self._dispatch_q.qsize(),
             "decode_queue": self._decode_q.qsize(),
             "batches_total": self._batches_total,
@@ -971,10 +975,12 @@ class PipelinedBatcher(MicroBatcher):
         """Device-side accumulation: while batches are already in flight
         the collector claims immediately — requests that arrived during
         the device's evaluation of batch N ARE the accumulated batch, so
-        an extra host linger only adds latency without adding rows. An
-        idle pipeline (nothing in flight) keeps the normal forming
-        window so a burst's first tick still coalesces."""
-        if self._inflight > 0:
+        an extra host linger only adds latency without adding rows. So
+        does a single entry at an idle pipeline: a request that came
+        alone has nobody to wait for. Two or more entries waiting with
+        nothing in flight are a burst that has begun, and its first
+        claim keeps the forming window. Called under ``_cv``."""
+        if self._inflight > 0 or len(self._queue) < 2:
             return 0.0
         return self.window_s
 
@@ -1068,31 +1074,35 @@ class PipelinedBatcher(MicroBatcher):
             # in the queue before it came free (the batch's oldest member
             # waited for the place, not for the window); the time so held
             # is this stage's stall
+            times = batch[0][1].times
             held = 0.0
             if since:
-                held = place.freed_at - max(
-                    since, batch[0][1].times.first_enq
-                )
-            _record_claim(self.metrics_path, held > 0)
+                held = place.freed_at - max(since, times.first_enq)
+            _record_claim(self.metrics_path, held > 0, times.lingered)
             self._stall("collect", held)
             # chaos kill seams OUTSIDE the per-batch containment: unwind
             # this stage like a real crash would
             chaos_fire("pipeline.collect")
             if self._dispatch_seam is not None:
                 chaos_fire(self._dispatch_seam, self.replica)
+            # busy across the encode: a --max-batch batch encodes in well
+            # under a second (tens of µs a row), the wedge budget is 10 s
             hb.busy()
             self._batches_total += 1
-            items = [it for it, _ in batch]
+            self._inflight_add(1, len(batch))
             try:
-                fut = self._pool.submit(
-                    self._encode_timed, items, batch[0][1].times
-                )
-            except RuntimeError as e:  # pool shut down under us
+                ctx = self._encode_timed([it for it, _ in batch], times)
+            except BaseException as e:  # noqa: BLE001 — per-batch isolation
                 place.free()
+                self._inflight_add(-1, -len(batch))
                 self._fail_batch(batch, e)
                 continue
-            self._inflight_add(1, len(batch))
-            if not self._put(dispatch_q, (batch, fut), dispatcher):
+            if self._epoch != epoch:
+                # superseded during the encode (a forced revive): the old
+                # hand-off queue has no reader left, so fail fast here
+                self._shed_superseded((batch, ctx))
+                return
+            if not self._put(dispatch_q, (batch, ctx), dispatcher):
                 self._inflight_add(-1, -len(batch))
                 self._fail_batch(
                     batch, RuntimeError("pipeline dispatch stage died")
@@ -1115,6 +1125,7 @@ class PipelinedBatcher(MicroBatcher):
         hb = self.heartbeats["dispatch"]
         while True:
             hb.idle()
+            t0 = time.monotonic()
             item = dispatch_q.get()
             if self._epoch != epoch:
                 # superseded by revive(): a fresh stage owns the work — but
@@ -1131,14 +1142,14 @@ class PipelinedBatcher(MicroBatcher):
                 place.free()
                 self._put(decode_q, _SENTINEL, decoder)
                 return
-            batch, fut = item
+            batch, ctx = item
+            times = batch[0][1].times
+            # time this thread waited for the standing batch's encode (from
+            # its claim, or from this thread's coming back for work if that
+            # was later): since the late claim one encode a batch by design
+            self._stall("dispatch", time.monotonic() - max(t0, times.claimed))
             try:
-                t0 = time.monotonic()
-                ctx = fut.result()  # wait for the encode worker
-                # time waiting on the encode future: since the late claim
-                # this thread idles for one encode a batch by design
-                self._stall("dispatch", time.monotonic() - t0)
-                with batch_stage(batch[0][1].times, "dispatch", len(batch)):
+                with batch_stage(times, "dispatch", len(batch)):
                     ctx = self.stages.pipeline_dispatch(ctx)
             except BaseException as e:  # noqa: BLE001 — per-batch isolation
                 place.free()
@@ -1206,4 +1217,3 @@ class PipelinedBatcher(MicroBatcher):
         each stage forwards), so every accepted submitter gets an answer
         before the workers exit."""
         super().stop(drain_timeout_s=drain_timeout_s)
-        self._pool.shutdown(wait=False)

@@ -165,7 +165,6 @@ def build_worker_stack(
         fastpath=fastpath,
         decision_cache=cache,
         pipeline_depth=batch_depth,
-        encode_workers=1,
         request_timeout_s=spec.get("timeout_s"),
     )
     return InProcessWorker(

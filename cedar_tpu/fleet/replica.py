@@ -61,7 +61,6 @@ class EngineReplica:
         max_batch: int = 8192,
         window_s: float = 0.0002,
         pipeline_depth: int = 2,
-        encode_workers: int = 2,
         fleet_name: str = "authorization",
         batcher=None,
     ):
@@ -79,7 +78,6 @@ class EngineReplica:
                     max_batch=max_batch,
                     window_s=window_s,
                     depth=pipeline_depth,
-                    encode_workers=encode_workers,
                     metrics_path=fleet_name,
                     replica=self.name,
                     dispatch_seam=REPLICA_DISPATCH_SEAM,

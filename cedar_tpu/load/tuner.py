@@ -18,6 +18,13 @@ decision plane:
   * when healthy and demand is gone, decay both knobs back toward their
     configured home values.
 
+What ``window_s`` governs on the pipelined batcher (engine/batcher.py
+``_linger_window_s``): the first claim of a burst at an idle pipeline —
+two or more requests waiting, nothing in flight. A request that came
+alone is claimed at once and a claim behind a batch in flight never
+lingered, so ``linger_us`` moves nothing for either; on the serial
+batcher it is every claim's wait.
+
 Every move is clamped to operator-set ``TuningBounds``, logged with the
 measurement that justified it (served at ``/debug/load``), and published
 to the ``cedar_batch_tuning{path,param}`` gauges so a dashboard can watch

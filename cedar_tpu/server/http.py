@@ -370,7 +370,6 @@ class WebhookServer:
         analysis_provider=None,
         decision_cache=None,
         pipeline_depth: int = 0,
-        encode_workers: int = 2,
         rollout=None,
         rollout_control_enabled: bool = True,
         rollout_control_token: Optional[str] = None,
@@ -389,14 +388,10 @@ class WebhookServer:
         # pipeline_depth > 0 runs each raw fast path through the
         # three-stage PipelinedBatcher (engine/batcher.py): host encode of
         # batch N+1 overlaps device execution of batch N, with
-        # `pipeline_depth` batches in flight and `encode_workers` encode
-        # threads. 0 keeps the serial MicroBatcher (identical results —
+        # `pipeline_depth` batches in flight. 0 keeps the serial MicroBatcher (identical results —
         # tests/test_pipeline.py pins the differential; the CLI defaults
         # to depth 2, embedders opt in).
         self.pipeline_depth = max(0, int(pipeline_depth))
-        # 0 = auto: passed through so PipelinedBatcher sizes the pool from
-        # the native encoder's resolved thread width (engine/batcher.py)
-        self.encode_workers = max(0, int(encode_workers))
 
         def _eval_batcher(fastpath_obj, serial_fn, path):
             from ..engine.batcher import MicroBatcher, PipelinedBatcher
@@ -407,7 +402,6 @@ class WebhookServer:
                     max_batch=max_batch,
                     window_s=batch_window_s,
                     depth=self.pipeline_depth,
-                    encode_workers=self.encode_workers,
                     metrics_path=path,
                 )
             return MicroBatcher(

@@ -874,6 +874,20 @@ batch_claims_total = REGISTRY.register(
     )
 )
 
+batch_lingers_total = REGISTRY.register(
+    Counter(
+        "cedar_batch_lingers_total",
+        "Claims of the pipelined batcher's collector that slept out the "
+        "forming window (--batch-window-us) first, by path: nothing was in "
+        "flight and two or more requests already waited (a burst's first "
+        "claim). A request that came alone to an idle pipeline, and any "
+        "claim while a batch is in flight, is claimed at once and counts "
+        "nothing here; over cedar_batch_claims_total it is the share of "
+        "claims the window engaged for.",
+        ["path"],
+    )
+)
+
 pipeline_stall_seconds_total = REGISTRY.register(
     Counter(
         "cedar_pipeline_stall_seconds_total",
@@ -882,7 +896,8 @@ pipeline_stall_seconds_total = REGISTRY.register(
         "standing place before the dispatch thread was taken (the "
         "dispatch stage, the device or the decode behind it sets the "
         "pace); dispatch = the dispatch thread "
-        "waited on an encode worker (encode-bound); decode = the decode "
+        "waited for the standing batch's encode, which the collector's "
+        "thread runs (encode-bound); decode = the decode "
         "thread sat idle while batches were in flight (pipeline "
         "starvation). Rate > ~0.5 s/s on one stage names the bottleneck "
         "(docs/performance.md has the tuning table).",
@@ -1648,6 +1663,10 @@ def record_batch_occupancy(path: str, n: int) -> None:
 
 def record_batch_claim(path: str, held: bool) -> None:
     batch_claims_total.inc(path=path, held="yes" if held else "no")
+
+
+def record_batch_linger(path: str) -> None:
+    batch_lingers_total.inc(path=path)
 
 
 def record_pipeline_stall(path: str, stage: str, seconds: float) -> None:

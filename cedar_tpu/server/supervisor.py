@@ -2,7 +2,7 @@
 device plane.
 
 The serving process is a small organism of worker threads — micro-batcher
-stages (encode pool / dispatch / decode), the shadow-rollout worker, the
+stages (collect / dispatch / decode), the shadow-rollout worker, the
 CRD watch, store reload tickers — any of which can die from an uncaught
 exception or wedge inside a hung device call. Before this module the only
 recovery story was the circuit breaker (requests route around a sick

@@ -154,7 +154,7 @@ def _sar_stack(src=SAR_POLICIES, n_replicas=2, hedge_delay_s=0.0,
         replicas.append(
             EngineReplica(
                 i, engine, fastpath, breaker=breaker, recovery=recovery,
-                max_batch=64, pipeline_depth=2, encode_workers=1,
+                max_batch=64, pipeline_depth=2,
                 fleet_name="fleet-test",
             )
         )

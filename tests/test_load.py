@@ -627,7 +627,7 @@ class TestPipelinedBacklog:
                 return [(DECISION_ALLOW, "", None)] * len(ctx)
 
         b = PipelinedBatcher(
-            _Stages(), max_batch=2, window_s=0.0, depth=1, encode_workers=1
+            _Stages(), max_batch=2, window_s=0.0, depth=1
         )
         results = []
         try:

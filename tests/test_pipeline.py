@@ -251,7 +251,7 @@ class TestPipelinedDifferential:
         # small max_batch forces many batches through the pipeline so the
         # differential crosses batch boundaries, not one giant batch
         batcher = PipelinedBatcher(
-            fast, max_batch=128, window_s=0.0002, depth=2, encode_workers=2
+            fast, max_batch=128, window_s=0.0002, depth=2
         )
         try:
             piped = _sar_bytes(_submit_all(batcher, bodies))
@@ -273,7 +273,7 @@ class TestPipelinedDifferential:
         bodies = [_adm_body(i) for i in range(400)]
         serial = _adm_bytes(fast.handle_raw(bodies))
         batcher = PipelinedBatcher(
-            fast, max_batch=64, window_s=0.0002, depth=2, encode_workers=2
+            fast, max_batch=64, window_s=0.0002, depth=2
         )
         try:
             piped = _adm_bytes(_submit_all(batcher, bodies))
@@ -473,13 +473,18 @@ class TestPipelinedBatcherSemantics:
 class _WatchedBatcher(PipelinedBatcher):
     """Records every claim on the collector's thread: its items, its
     shared stage record, and how many claimed batches already stood
-    before the dispatch thread at that moment."""
+    before the dispatch thread at that moment. A test that clears
+    ``may_claim`` keeps the collector from coming to the queue again once
+    it has delivered what it is waiting for now."""
 
     def __init__(self, *args, **kwargs):
         self.claims = []
+        self.may_claim = threading.Event()
+        self.may_claim.set()
         super().__init__(*args, **kwargs)
 
     def _form_batch(self, epoch=None):
+        self.may_claim.wait(timeout=30)
         batch = super()._form_batch(epoch)
         if batch:
             self.claims.append(
@@ -504,10 +509,14 @@ class _GatedStages(_StubStages):
         self.gated = gated
         self.raise_in = raise_in
         self.dispatched = []
+        self.encode_threads = []
 
     def pipeline_encode(self, items):
+        self.encode_threads.append(threading.current_thread().name)
         if self.raise_in == "encode" and "boom" in items:
             raise ValueError("encode bug")
+        if self.gated == "encode":
+            self.gate.wait(timeout=30)
         return super().pipeline_encode(items)
 
     def pipeline_dispatch(self, ctx):
@@ -529,6 +538,14 @@ def _claims(path):
         values.get((("path", path), ("held", held)), 0.0)
         for held in ("yes", "no")
     )
+
+
+def _lingers(path):
+    """cedar_batch_lingers_total{path}."""
+    from cedar_tpu.server.metrics import batch_lingers_total
+
+    with batch_lingers_total._lock:
+        return dict(batch_lingers_total._values).get((("path", path),), 0.0)
 
 
 def _wait_for(cond, timeout=5.0):
@@ -673,8 +690,10 @@ class TestLateClaim:
             stages.gate.set()
             b.stop()
 
-    def test_a_lone_submitter_is_claimed_after_the_window_and_never_held(self):
-        window_s = 0.004
+    def test_a_lone_submitter_is_claimed_at_once_and_never_held(self):
+        # a window a timed wait cannot be mistaken for: every claim below
+        # would take 50 ms if the collector slept on it
+        window_s = 0.05
         b = _WatchedBatcher(
             _StubStages(), max_batch=8, window_s=window_s, depth=2,
             metrics_path="late-claim-lone",
@@ -687,9 +706,124 @@ class TestLateClaim:
         assert [items for items, _t, _s in b.claims] == [[i] for i in range(20)]
         for _items, times, standing in b.claims:
             assert standing == 0
-            assert times.claimed - times.first_enq >= 0.9 * window_s
+            assert not times.lingered
+            assert times.claimed - times.first_enq < 0.5 * window_s
         assert _claims("late-claim-lone") == (0.0, 20.0)
+        assert _lingers("late-claim-lone") == 0.0
         assert b.debug_stats()["stall_seconds"]["collect"] == 0
+
+    def test_a_burst_that_has_begun_lingers_and_rides_one_claim(self):
+        """Two or more entries waiting at an idle pipeline keep the forming
+        window: what arrives inside it rides the same claim."""
+        window_s = 0.2
+        b = _WatchedBatcher(
+            _StubStages(), max_batch=8, window_s=window_s, depth=2,
+            metrics_path="late-claim-burst",
+        )
+        try:
+            # the collector is kept from the queue until both entries are
+            # in it, and the pipeline is idle again by then
+            b.may_claim.clear()
+            assert b.submit("first", timeout=5.0) == "firstfirst"
+            assert _wait_for(lambda: b.debug_stats()["inflight"] == 0)
+            entries = [b.enqueue(i) for i in (0, 1)]
+            b.may_claim.set()
+            time.sleep(window_s / 4)
+            entries.append(b.enqueue(2))  # inside the window
+            assert [b.wait_entry(e, timeout=5.0) for e in entries] == [0, 2, 4]
+        finally:
+            b.may_claim.set()
+            b.stop()
+        assert [items for items, _t, _s in b.claims] == [["first"], [0, 1, 2]]
+        assert not b.claims[0][1].lingered
+        times = b.claims[1][1]
+        assert times.lingered
+        assert times.claimed - times.first_enq >= 0.9 * window_s
+        assert _lingers("late-claim-burst") == 1.0
+        assert _claims("late-claim-burst") == (0.0, 2.0)
+
+    def test_a_batch_in_flight_means_no_window(self):
+        """While a launch is out, the backlog behind it is claimed the
+        moment the place is free, however many entries wait."""
+        window_s = 0.5
+        stages = _GatedStages(gated="a0")
+        stages.gate.clear()
+        b = _WatchedBatcher(
+            stages, max_batch=8, window_s=window_s, depth=2,
+            metrics_path="late-claim-inflight",
+        )
+        try:
+            first = b.enqueue("a0")
+            assert _wait_for(lambda: len(b.claims) == 1)
+            behind = [b.enqueue(x) for x in ("b0", "b1", "b2")]
+            # hold the decode of a0 so it is still in flight at the claim
+            stages.decode_sleep_s = 0.05
+            stages.gate.set()
+            t0 = time.monotonic()
+            assert [b.wait_entry(e, timeout=5.0) for e in behind] == [
+                "b0b0", "b1b1", "b2b2"]
+            assert time.monotonic() - t0 < window_s
+            assert b.wait_entry(first, timeout=5.0) == "a0a0"
+        finally:
+            stages.gate.set()
+            b.stop()
+        assert [items for items, _t, _s in b.claims] == [
+            ["a0"], ["b0", "b1", "b2"]]
+        assert not any(times.lingered for _i, times, _s in b.claims)
+        assert _lingers("late-claim-inflight") == 0.0
+        assert _claims("late-claim-inflight") == (1.0, 1.0)
+
+    def test_the_encode_runs_on_the_thread_that_claimed(self):
+        stages = _GatedStages()
+        b = _WatchedBatcher(stages, max_batch=4, window_s=0.0002, depth=2)
+        try:
+            assert _submit_all(b, list(range(24)), workers=6) == [
+                2 * i for i in range(24)]
+            stats = b.debug_stats()
+        finally:
+            b.stop()
+        assert set(stages.encode_threads) == {"pipe-collect"}
+        assert len(stages.encode_threads) == len(b.claims)
+        assert "encode_workers" not in stats
+        # the dispatch thread's wait for the standing batch's encode
+        assert stats["stall_seconds"]["dispatch"] > 0
+
+    def test_an_encode_that_raises_fails_its_batch_alone(self):
+        stages = _GatedStages(raise_in="encode")
+        b = _WatchedBatcher(stages, max_batch=4, window_s=0.0002, depth=2)
+        try:
+            collector = b._thread
+            with pytest.raises(RuntimeError, match="evaluation failed") as e:
+                b.submit("boom", timeout=5.0)
+            assert isinstance(e.value.__cause__, ValueError)
+            # nothing counts as in flight, and the thread that ran the
+            # encode serves the next submit (so the place was freed)
+            assert b.debug_stats()["inflight"] == 0
+            assert b.backlog() == 0
+            assert b.submit("fine", timeout=5.0) == "finefine"
+            assert b._thread is collector and collector.is_alive()
+        finally:
+            b.stop()
+
+    def test_a_revive_during_the_encode_fails_that_batch_fast(self):
+        """A collector superseded while it encodes (the supervisor's forced
+        revive of a wedged stage) must not put its batch on a hand-off
+        queue that no thread reads any more."""
+        stages = _GatedStages(gated="encode")
+        stages.gate.clear()
+        b = _WatchedBatcher(stages, max_batch=4, window_s=0.0002, depth=2)
+        try:
+            wedged = b.enqueue("w")
+            assert _wait_for(lambda: stages.encode_threads)
+            assert b.revive(force=True)
+            stages.gated = None
+            assert b.submit("fresh", timeout=5.0) == "freshfresh"
+            stages.gate.set()
+            with pytest.raises(RuntimeError, match="restarted; batch shed"):
+                b.wait_entry(wedged, timeout=5.0)
+        finally:
+            stages.gate.set()
+            b.stop()
 
     def test_rows_leave_in_the_order_they_came_across_held_claims(self):
         stages = _GatedStages(dispatch_sleep_s=0.002)
@@ -777,7 +911,6 @@ class TestDebugEngineEndpoint:
             fastpath=fast,
             admission_fastpath=adm_fast,
             pipeline_depth=2,
-            encode_workers=2,
         )
         server.start()
         try:
@@ -798,7 +931,7 @@ class TestDebugEngineEndpoint:
                 pipe = doc[path]["pipeline"]
                 assert pipe["mode"] == "pipelined"
                 assert pipe["depth"] == 2
-                assert pipe["encode_workers"] == 2
+                assert "encode_workers" not in pipe
                 assert "dispatch_queue" in pipe and "decode_queue" in pipe
                 assert "stall_seconds" in pipe
                 eng = doc[path]["engine"]
@@ -809,3 +942,16 @@ class TestDebugEngineEndpoint:
             assert "cedar_pipeline_stall_seconds_total" in exposition
         finally:
             server.stop()
+
+
+def test_webhook_help_has_no_encode_workers_flag():
+    """The encode pool went with its flag (the collector's thread encodes
+    what it claimed): refused like any unknown flag, not deprecated."""
+    from cedar_tpu.cli.webhook import make_parser
+
+    text = make_parser().format_help()
+    assert "--pipeline-depth" in text and "--batch-window-us" in text
+    assert "encode-workers" not in text
+    with pytest.raises(SystemExit):
+        make_parser().parse_args(["--encode-workers", "2"])
+    assert not hasattr(make_parser().parse_args([]), "encode_workers")
