@@ -1022,11 +1022,18 @@ def run_steady_scenario() -> int:
     cs = engine._compiled
     packed = cs.packed
     snap = fast._current_snapshot()
-    codes_i32, extras_i32, _counts, _flags = snap.encoder.encode_batch(
+    codes_i32, extras_i32, counts, _flags = snap.encoder.encode_batch(
         pool[0]
     )
     codes_base = np.ascontiguousarray(codes_i32.astype(cs.code_dtype))
-    extras_base = np.ascontiguousarray(extras_i32.astype(cs.active_dtype))
+    # the width the serving path would pad this batch to, not the
+    # encoder's cap (256 columns of padding are not what a batch carries)
+    from cedar_tpu.engine.evaluator import EXTRAS_WIDTHS, _round_bucket
+
+    live = _round_bucket(int(counts.max(initial=0)), EXTRAS_WIDTHS)
+    extras_base = np.ascontiguousarray(
+        extras_i32[:, :live].astype(cs.active_dtype)
+    )
     wire = getattr(cs, "wire", None)
     segs = getattr(cs, "segs", None)
     kargs = (

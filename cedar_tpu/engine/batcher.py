@@ -152,8 +152,8 @@ class _StageTimes:
     slept out a forming window first (_form_batch)."""
 
     __slots__ = (
-        "claimed", "first_enq", "lingered", "rows", "extras_max", "sub",
-        "part", "part_t0",
+        "claimed", "first_enq", "lingered", "rows", "extras_max", "groups",
+        "known_groups", "sub", "part", "part_t0",
         "encode0", "encode1", "dispatch0", "dispatch1",
         "decode0", "decode1", "eval0", "eval1",
     )
@@ -164,8 +164,12 @@ class _StageTimes:
         self.lingered = lingered
         self.rows = 0
         # the widest row's set-membership extras, where a native encode ran
-        # (obs.trace.note_encode_extras): `extras_max` on batch.encode
+        # (obs.trace.note_encode_extras): `extras_max` on batch.encode,
+        # beside the most groups a row's principal carries and the most of
+        # them that some policy names (`groups`, `known_groups`)
         self.extras_max: Optional[int] = None
+        self.groups = 0
+        self.known_groups = 0
         self.sub: dict = {}
         self.part: Optional[str] = None
         self.part_t0 = 0.0

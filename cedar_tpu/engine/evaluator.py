@@ -85,6 +85,20 @@ from . import aot
 log = logging.getLogger(__name__)
 
 _BATCH_BUCKETS = (1, 8, 32, 128, 512, 1024, 2048, 4096, 8192, 16384, 32768)
+# the extras widths a batch is padded to (the live width of its widest
+# row, rounded up; fastpath._encode_chunk): every one is a shape of the
+# warm ladder (_warm_shape_plan), and the widest is the native encoder's
+# cap (native.DEFAULT_EXTRAS_CAP), so no natively encoded batch meets a
+# shape the ladder did not compile — a wide row (selector- or group-heavy
+# traffic) paying a first-hit trace is the same deadline blowout as a
+# cold bucket. Width 1 carries the batch without extras, 8 and 32 the
+# set-membership tests of selectors and admission objects, 256 a
+# principal whose token carries an identity provider's groups (up to 200;
+# the first eight policy-known ones take the ancestor slots, the rest
+# ride here). Four widths and not six: each width is 13 shapes of the
+# ladder an engine, and a padded column costs the device one [B, L]
+# compare of a launch that reads the whole rule plane.
+EXTRAS_WIDTHS = (1, 8, 32, 256)
 
 # chunk size of the raw fast paths' encode/device overlap pipeline
 # (engine/fastpath.py uses this as _RawFastPath._CHUNK); defined here so the
@@ -1082,14 +1096,6 @@ class TPUPolicyEngine:
             except Exception:  # noqa: BLE001 — metrics never break warm-up
                 pass
 
-    # every extras width the native fast path can produce: _encode_chunk
-    # buckets the live width via _round_bucket(max_e, (8, 16, 32, ...))
-    # capped at the encoder's DEFAULT_EXTRAS_CAP (32), so production
-    # batches land on exactly these four shapes. The warm ladder must
-    # cover them ALL — width 16/32 (selector/group-heavy traffic) paying
-    # a first-hit trace is the same deadline blowout as a cold bucket.
-    _WARM_EXTRAS_WIDTHS = (1, 8, 16, 32)
-
     def _warm_shape_plan(
         self,
         packed: PackedPolicySet,
@@ -1101,7 +1107,7 @@ class TPUPolicyEngine:
         on it via _warm_first), then every batch bucket up to max_batch
         (default self.warm_max_batch) at each extras width — no-extras
         requests ride width 1, selector/set-heavy requests land on the
-        8/16/32 buckets (_WARM_EXTRAS_WIDTHS).
+        8/32/256 buckets (EXTRAS_WIDTHS).
         Three planes per bucket: the latency-regime fast path (want_bits
         in-call, only at buckets <= BITS_INCALL_MAX where the fast paths
         request it), the throughput/python path (plain words), and — for
@@ -1118,7 +1124,7 @@ class TPUPolicyEngine:
         object per attribute access), which silently warmed the wrong
         want_bits variant for two rounds."""
         if extras_widths is None:
-            extras_widths = self._WARM_EXTRAS_WIDTHS
+            extras_widths = EXTRAS_WIDTHS
         cap = max_batch if max_batch is not None else self.warm_max_batch
         buckets = [b for b in _BATCH_BUCKETS if b <= max(cap, 1)]
         shapes: list = [("match", 1, 1)]
@@ -2029,13 +2035,11 @@ class TPUPolicyEngine:
         S = packed.table.n_slots
         codes_arr = np.zeros((B, S), dtype=cs.code_dtype)
         max_e = max((len(e) for _, e in encoded), default=0)
-        if max_e == 0:
-            E = 0
-        elif max_e <= 256:
-            E = _round_bucket(max_e, (8, 16, 32, 64, 128, 256))
+        if max_e <= EXTRAS_WIDTHS[-1]:
+            E = _round_bucket(max_e, EXTRAS_WIDTHS)  # the ladder's shapes
         else:  # never truncate: dropping an extra would drop an activation
             E = -(-max_e // 128) * 128
-        extras_arr = np.full((B, max(E, 1)), packed.L, dtype=cs.active_dtype)
+        extras_arr = np.full((B, E), packed.L, dtype=cs.active_dtype)
         for i, (c, e) in enumerate(encoded):
             codes_arr[i] = c
             if e:

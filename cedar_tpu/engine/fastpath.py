@@ -43,6 +43,7 @@ import numpy as np
 from ..chaos.registry import chaos_fire
 from ..obs.trace import note_encode_extras, sub_stage
 from ..native import (
+    ANC_WHERE,
     F_ADM_ERROR,
     F_ADM_NS_SKIP,
     F_EXTRAS_OVERFLOW,
@@ -65,6 +66,7 @@ from ..ops.match import WORD_ERR, WORD_GATE, WORD_MULTI
 from .evaluator import (
     _BATCH_BUCKETS,
     BITS_INCALL_MAX,
+    EXTRAS_WIDTHS,
     SERVING_CHUNK,
     TPUPolicyEngine,
     _round_bucket,
@@ -262,11 +264,12 @@ class _RawFastPath:
     # ----------------------------------------------------- subclass surface
 
     def _encode_into(
-        self, snap: _Snapshot, bodies, codes, extras, counts, flags
+        self, snap: _Snapshot, bodies, codes, extras, counts, flags, anc
     ):
         """C++ encode of one chunk DIRECTLY into the caller's buffers
-        (the engine's pooled staging); returns the path's aux payload
-        (None for SAR, uids for admission)."""
+        (the engine's pooled staging; `anc` takes each row's group tallies,
+        native.ANC_WHERE); returns the path's aux payload (None for SAR,
+        uids for admission)."""
         raise NotImplementedError
 
     def _route_flags(self, flags, results, bodies, aux) -> np.ndarray:
@@ -484,17 +487,29 @@ class _RawFastPath:
         record_row_routing(p, "encoder_fallback", n_fallback)
         record_row_routing(p, "encoder_gate", n - n_fallback - n_ok)
 
-    def _record_extras(self, ok_counts, max_e: int) -> None:
+    def _record_extras(self, ok_counts, max_e: int, ok_anc) -> None:
         """One chunk's set-membership extras (the natively encoded rows'
         counts) -> cedar_encode_extras and `extras_max` on the
         batch.encode span: how near the rows come to the encoder's cap. A
-        row past it is an encoder_fallback row of _record_routing."""
-        from ..server.metrics import record_encode_extras
-
-        record_encode_extras(
-            self._METRIC_PATH, int(ok_counts.sum()), len(ok_counts)
+        row past it is an encoder_fallback row of _record_routing. And the
+        same rows' principal groups by where the encoder put them
+        (`ok_anc` [rows, 3], native.ANC_WHERE) ->
+        cedar_encode_ancestors_total and `groups` / `known_groups` on the
+        span: how much of the extras list is group membership."""
+        from ..server.metrics import (
+            record_encode_ancestors,
+            record_encode_extras,
         )
-        note_encode_extras(max_e)
+
+        p = self._METRIC_PATH
+        record_encode_extras(p, int(ok_counts.sum()), len(ok_counts))
+        for where, n in zip(ANC_WHERE, ok_anc.sum(axis=0).tolist()):
+            record_encode_ancestors(p, where, n)
+        note_encode_extras(
+            max_e,
+            int(ok_anc.sum(axis=1).max(initial=0)),
+            int(ok_anc[:, :2].sum(axis=1).max(initial=0)),
+        )
 
     def _encode_chunk(self, snap: _Snapshot, bodies: Sequence[bytes]):
         """Host-only half of chunk preparation: C++ encode STRAIGHT INTO
@@ -525,9 +540,10 @@ class _RawFastPath:
         held = [codes, extras]
         counts = np.empty((n,), np.int32)
         flags = np.empty((n,), np.uint8)
+        anc = np.empty((n, len(ANC_WHERE)), np.int32)
         try:
             aux = self._encode_into(
-                snap, bodies, codes, extras, counts, flags
+                snap, bodies, codes, extras, counts, flags, anc
             )
             # fused multi-tenant plane (cedar_tpu/tenancy): the body bytes
             # carry no tenant — stamp each request's tenant feature code
@@ -569,11 +585,13 @@ class _RawFastPath:
             # column costs a [B, E, L] broadcast-compare on device
             ok_counts = counts if all_ok else counts[idx]
             max_e = int(ok_counts.max(initial=0))
-            self._record_extras(ok_counts, max_e)
-            if max_e == 0:
-                E = 1
-            else:
-                E = min(_round_bucket(max_e, (8, 16, 32, 64, 128, 256)), cap)
+            self._record_extras(
+                ok_counts, max_e, anc if all_ok else anc[idx]
+            )
+            # the ladder's own widths (a row past the widest never got
+            # here: the encoder's cap is that width), so every served
+            # shape is one the warm ladder compiled
+            E = _round_bucket(max_e, EXTRAS_WIDTHS)
             if all_ok:
                 ok_codes = codes
                 ok_extras = extras[:, :E]
@@ -875,8 +893,10 @@ class SARFastPath(_RawFastPath):
 
     # --------------------------------------------------------------- hooks
 
-    def _encode_into(self, snap, bodies, codes, extras, counts, flags):
-        snap.encoder.encode_batch_into(bodies, codes, extras, counts, flags)
+    def _encode_into(self, snap, bodies, codes, extras, counts, flags, anc):
+        snap.encoder.encode_batch_into(
+            bodies, codes, extras, counts, flags, anc=anc
+        )
         return None
 
     def _route_flags(self, flags, results, bodies, aux):
@@ -1066,9 +1086,9 @@ class AdmissionFastPath(_RawFastPath):
 
     # --------------------------------------------------------------- hooks
 
-    def _encode_into(self, snap, bodies, codes, extras, counts, flags):
+    def _encode_into(self, snap, bodies, codes, extras, counts, flags, anc):
         return snap.encoder.encode_adm_batch_into(
-            bodies, codes, extras, counts, flags
+            bodies, codes, extras, counts, flags, anc=anc
         )
 
     def _route_flags(self, flags, results, bodies, uids):

@@ -66,6 +66,11 @@ F_EXTRAS_OVERFLOW = 5
 F_ADM_NS_SKIP = 6  # admission: kube-system/cedar-k8s-authz-system -> allow
 F_ADM_ERROR = 7  # admission: conversion error/unsupported shape -> py path
 
+# columns of the encoders' optional `anc` output (encoder.cpp ANC_*): where
+# each of a row's principal groups went — an ancestor code slot, the extras
+# list (a policy-known group past the slots), or nowhere (no policy names it)
+ANC_WHERE = ("slot", "extras", "unknown")
+
 _VAR_IDX = {"principal": 0, "action": 1, "resource": 2, "context": 3}
 _CMP_OPS = {"<": 0, "<=": 1, ">": 2, ">=": 3}
 
@@ -324,6 +329,7 @@ def _load_library():
             ctypes.c_int32,
             ctypes.POINTER(ctypes.c_int32),
             ctypes.POINTER(ctypes.c_uint8),
+            ctypes.POINTER(ctypes.c_int32),
             ctypes.c_int32,
         ]
         lib.ce_encode_adm_batch.restype = None
@@ -339,6 +345,7 @@ def _load_library():
             ctypes.POINTER(ctypes.c_int32),
             ctypes.POINTER(ctypes.c_uint8),
             ctypes.c_char_p,
+            ctypes.POINTER(ctypes.c_int32),
             ctypes.POINTER(ctypes.c_int32),
             ctypes.c_int32,
         ]
@@ -360,6 +367,7 @@ def _load_library():
                 ctypes.c_int32,
                 ctypes.POINTER(ctypes.c_int32),
                 ctypes.POINTER(ctypes.c_uint8),
+                ctypes.POINTER(ctypes.c_int32),
                 ctypes.c_int32,
             ]
             pylib.ce_encode_adm_pylist.restype = None
@@ -374,6 +382,7 @@ def _load_library():
                 ctypes.POINTER(ctypes.c_int32),
                 ctypes.POINTER(ctypes.c_uint8),
                 ctypes.c_char_p,
+                ctypes.POINTER(ctypes.c_int32),
                 ctypes.POINTER(ctypes.c_int32),
                 ctypes.c_int32,
             ]
@@ -461,7 +470,15 @@ def set_encode_threads(n: Optional[int]) -> None:
 class NativeEncoder:
     """Owns one loaded native activation table; encodes raw SAR JSON batches."""
 
-    DEFAULT_EXTRAS_CAP = 32
+    # the widest extras list a natively encoded row may carry; a row past
+    # it is flagged F_EXTRAS_OVERFLOW and answered by the Python path. 256
+    # and not 32 since a principal's policy-known groups past the eight
+    # ancestor slots (compiler/table.py ANCESTOR_SLOTS) ride the extras
+    # list: an identity provider's token carries up to 200 groups. It is
+    # also the widest extras bucket of the engine's warm ladder
+    # (engine/evaluator.py EXTRAS_WIDTHS), so no row that stays native
+    # meets a shape the ladder did not compile.
+    DEFAULT_EXTRAS_CAP = 256
 
     def __init__(self, handle: int, n_slots: int, pad_value: int):
         self._handle = handle
@@ -505,6 +522,15 @@ class NativeEncoder:
         if width is not None and (arr.ndim != 2 or arr.shape[1] != width):
             raise ValueError(f"{name}: want shape [>= {rows}, {width}], got {arr.shape}")
 
+    def _anc_pointer(self, anc: Optional[np.ndarray], n: int):
+        """The optional [>= n, 3] int32 output of the *_into entries: each
+        row's principal groups by where they went (ANC_WHERE's order; zeros
+        for a row that was not encoded). None passes a null pointer."""
+        if anc is None:
+            return None
+        self._check_out("anc", anc, n, len(ANC_WHERE), np.int32)
+        return anc.ctypes.data_as(ctypes.POINTER(ctypes.c_int32))
+
     def encode_batch_into(
         self,
         bodies: Sequence[bytes],
@@ -513,6 +539,7 @@ class NativeEncoder:
         counts: np.ndarray,
         flags: np.ndarray,
         n_threads: int = 0,
+        anc: Optional[np.ndarray] = None,
     ) -> int:
         """Encode raw SAR bodies DIRECTLY into caller-provided buffers —
         the zero-copy staging path (engine/fastpath.py hands in the
@@ -523,7 +550,9 @@ class NativeEncoder:
         be C-contiguous; counts [>= n] int32, flags [>= n] uint8. Only the
         first len(bodies) rows are written (extras rows are pad-filled to
         the buffer's cap); rows beyond that — bucket padding — are the
-        caller's to fill. Returns the encoded row count."""
+        caller's to fill. `anc`, where given, is [>= n, 3] int32 and takes
+        each row's group tallies (_anc_pointer). Returns the encoded row
+        count."""
         lib = _load_library()
         assert lib is not None
         n = len(bodies)
@@ -534,6 +563,7 @@ class NativeEncoder:
         self._check_out("extras", extras, n, extras_cap, np.int32)
         self._check_out("counts", counts, n, None, np.int32)
         self._check_out("flags", flags, n, None, np.uint8)
+        c_anc = self._anc_pointer(anc, n)
         if n == 0:
             return 0
         c_codes = codes.ctypes.data_as(ctypes.POINTER(ctypes.c_int32))
@@ -554,6 +584,7 @@ class NativeEncoder:
                 self.pad_value,
                 c_counts,
                 c_flags,
+                c_anc,
                 n_threads,
             )
             return n
@@ -575,6 +606,7 @@ class NativeEncoder:
             extras_cap,
             c_counts,
             c_flags,
+            c_anc,
             n_threads,
         )
         return n
@@ -616,6 +648,7 @@ class NativeEncoder:
         counts: np.ndarray,
         flags: np.ndarray,
         n_threads: int = 0,
+        anc: Optional[np.ndarray] = None,
     ) -> List[str]:
         """Admission twin of encode_batch_into: encode raw AdmissionReview
         bodies into caller-provided buffers (same shape/layout contract)
@@ -631,6 +664,7 @@ class NativeEncoder:
         self._check_out("extras", extras, n, extras_cap, np.int32)
         self._check_out("counts", counts, n, None, np.int32)
         self._check_out("flags", flags, n, None, np.uint8)
+        c_anc = self._anc_pointer(anc, n)
         if n == 0:
             return []
         uid_buf = ctypes.create_string_buffer(n * 256)
@@ -653,6 +687,7 @@ class NativeEncoder:
                 c_flags,
                 uid_buf,
                 c_uid_lens,
+                c_anc,
                 n_threads,
             )
         else:
@@ -676,6 +711,7 @@ class NativeEncoder:
                 c_flags,
                 uid_buf,
                 c_uid_lens,
+                c_anc,
                 n_threads,
             )
         raw = uid_buf.raw

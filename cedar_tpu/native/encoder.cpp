@@ -1167,11 +1167,15 @@ struct ExtrasOut {
   int32_t cap;
   int32_t n = 0;
   bool overflow = false;
+  // where the row's principal groups went (push_ancestors): a code slot,
+  // the extras list, or nowhere because no policy names the group
+  int32_t anc[3] = {0, 0, 0};
   void push(int32_t v) {
     if (n < cap) buf[n++] = v;
     else overflow = true;
   }
 };
+enum { ANC_SLOT = 0, ANC_EXTRAS = 1, ANC_UNKNOWN = 2 };
 
 // Resolve a dyn template into the probe's canonical value key.
 // `slot_canon` is `bool(uint8_t var, const vector<string> &comps,
@@ -1390,6 +1394,37 @@ bool sar_slot_canon(Features &f, uint8_t var,
   return false;
 }
 
+// Principal ancestors: each group of the user is a k8s::Group parent
+// (user.go:23-27). The first anc_slots[0] groups some policy names take a
+// code slot each; every further one pushes its `principal in` literal ids
+// onto the extras list (compiler/table.py encode_request_codes: the same
+// activations). A group no policy names activates nothing.
+void push_ancestors(const Table &t, const std::vector<sv> &groups,
+                    int32_t *codes, ExtrasOut &extras, std::string &scratch) {
+  const auto &slots = t.anc_slots[0];
+  if (slots.empty()) {
+    extras.anc[ANC_UNKNOWN] += int32_t(groups.size());
+    return;
+  }
+  size_t filled = 0;
+  for (sv g : groups) {
+    scratch.assign("0\x1f");
+    scratch.append(kGroup.data(), kGroup.size());
+    scratch.push_back('\x1f');
+    scratch.append(g.data(), g.size());
+    const auto *entry = sv_find(t.anc_map, scratch);
+    if (!entry || entry->first == 0) {
+      ++extras.anc[ANC_UNKNOWN];
+    } else if (filled < slots.size()) {
+      codes[slots[filled++]] = entry->first;
+      ++extras.anc[ANC_SLOT];
+    } else {
+      for (int32_t lid : entry->second) extras.push(lid);
+      ++extras.anc[ANC_EXTRAS];
+    }
+  }
+}
+
 void encode_one(const Table &t, Features &f, int32_t *codes, ExtrasOut &extras,
                 std::string &scratch) {
   for (int32_t i = 0; i < t.n_slots; ++i) codes[i] = 0;
@@ -1421,23 +1456,7 @@ void encode_one(const Table &t, Features &f, int32_t *codes, ExtrasOut &extras,
 
   // principal ancestors: group parent entities (user.go:23-27). Actions and
   // resources have no parents in the authz domain.
-  if (!t.anc_slots[0].empty() && !f.groups.empty()) {
-    size_t filled = 0;
-    const auto &slots = t.anc_slots[0];
-    for (sv g : f.groups) {
-      scratch.assign("0\x1f");
-      scratch.append(kGroup.data(), kGroup.size());
-      scratch.push_back('\x1f');
-      scratch.append(g.data(), g.size());
-      const auto *entry = sv_find(t.anc_map, scratch);
-      if (!entry || entry->first == 0) continue;
-      if (filled < slots.size()) {
-        codes[slots[filled++]] = entry->first;
-      } else {
-        for (int32_t lid : entry->second) extras.push(lid);
-      }
-    }
-  }
+  push_ancestors(t, f.groups, codes, extras, scratch);
 
   std::string vcanon;  // the slot value's canon: vocab key + dyn eq operand
   for (const auto &s : t.slots) {
@@ -2218,23 +2237,7 @@ void encode_adm_one(const Table &t, AdmFeatures &f, int32_t *codes,
   }
 
   // principal ancestors: the group parents
-  if (!t.anc_slots[0].empty() && !f.groups.empty()) {
-    size_t filled = 0;
-    const auto &slots = t.anc_slots[0];
-    for (sv g : f.groups) {
-      scratch.assign("0\x1f");
-      scratch.append(kGroup.data(), kGroup.size());
-      scratch.push_back('\x1f');
-      scratch.append(g.data(), g.size());
-      const auto *entry = sv_find(t.anc_map, scratch);
-      if (!entry || entry->first == 0) continue;
-      if (filled < slots.size()) {
-        codes[slots[filled++]] = entry->first;
-      } else {
-        for (int32_t lid : entry->second) extras.push(lid);
-      }
-    }
-  }
+  push_ancestors(t, f.groups, codes, extras, scratch);
   // action ancestor: create/update/delete/connect all parent to "all"
   // (entities/admission.py admission_action_entities)
   if (!t.anc_slots[1].empty()) {
@@ -2577,15 +2580,17 @@ void drive_batch(uint64_t n, int32_t n_threads, Work &&work) {
   encode_pool().run(n, uint64_t(n_threads), fn);
 }
 
-// SAR encode over a request range. extras_pad >= 0 means the extras
-// buffer arrived UNinitialized (np.empty): fill every row's unused cells
-// up to extras_cap so outputs stay deterministic — batch results must be
-// bit-identical regardless of entry point or thread count
-// (tests/test_native_encoder.py pins this).
+// SAR encode over a request range. anc, where not null, is [n, 3] int32:
+// each row's principal groups by where they went (ANC_SLOT, ANC_EXTRAS,
+// ANC_UNKNOWN; zeros for a row that was not encoded). extras_pad >= 0
+// means the extras buffer arrived UNinitialized (np.empty): fill every
+// row's unused cells up to extras_cap so outputs stay deterministic —
+// batch results must be bit-identical regardless of entry point or thread
+// count (tests/test_native_encoder.py pins this).
 void encode_sar_rows(const Table &t, const ReqView *reqs, uint64_t lo,
                      uint64_t hi, int32_t *codes, int32_t *extras,
                      int32_t extras_cap, int32_t extras_pad,
-                     int32_t *extras_count, uint8_t *flags) {
+                     int32_t *extras_count, uint8_t *flags, int32_t *anc) {
   Arena arena;
   Features f;
   std::string scratch;
@@ -2621,6 +2626,7 @@ void encode_sar_rows(const Table &t, const ReqView *reqs, uint64_t lo,
     }
     if (extras_pad >= 0)
       for (int32_t k = eo.n; k < extras_cap; ++k) eo.buf[k] = extras_pad;
+    if (anc) memcpy(anc + i * 3, eo.anc, sizeof eo.anc);
   }
 }
 
@@ -2631,7 +2637,7 @@ void encode_adm_rows(const Table &t, const ReqView *reqs, uint64_t lo,
                      uint64_t hi, int32_t *codes, int32_t *extras,
                      int32_t extras_cap, int32_t extras_pad,
                      int32_t *extras_count, uint8_t *flags, char *uids,
-                     int32_t *uid_lens) {
+                     int32_t *uid_lens, int32_t *anc) {
   Arena arena;
   CPool cpool;
   AdmFeatures f;
@@ -2677,6 +2683,7 @@ void encode_adm_rows(const Table &t, const ReqView *reqs, uint64_t lo,
     }
     if (extras_pad >= 0)
       for (int32_t k = eo.n; k < extras_cap; ++k) eo.buf[k] = extras_pad;
+    if (anc) memcpy(anc + i * 3, eo.anc, sizeof eo.anc);
   }
 }
 
@@ -2746,12 +2753,12 @@ void ce_encode_sar_batch(void *handle, uint64_t n, const uint8_t *buf,
                          const uint64_t *offsets, const uint64_t *lens,
                          int32_t *codes, int32_t *extras, int32_t extras_cap,
                          int32_t *extras_count, uint8_t *flags,
-                         int32_t n_threads) {
+                         int32_t *anc, int32_t n_threads) {
   const Table &t = *static_cast<Table *>(handle);
   auto reqs = views_from_offsets(n, buf, offsets, lens);
   drive_batch(n, n_threads, [&](uint64_t lo, uint64_t hi) {
     encode_sar_rows(t, reqs.data(), lo, hi, codes, extras, extras_cap,
-                    /*extras_pad=*/-1, extras_count, flags);
+                    /*extras_pad=*/-1, extras_count, flags, anc);
   });
 }
 
@@ -2768,12 +2775,13 @@ void ce_encode_adm_batch(void *handle, uint64_t n, const uint8_t *buf,
                          const uint64_t *offsets, const uint64_t *lens,
                          int32_t *codes, int32_t *extras, int32_t extras_cap,
                          int32_t *extras_count, uint8_t *flags, char *uids,
-                         int32_t *uid_lens, int32_t n_threads) {
+                         int32_t *uid_lens, int32_t *anc, int32_t n_threads) {
   const Table &t = *static_cast<Table *>(handle);
   auto reqs = views_from_offsets(n, buf, offsets, lens);
   drive_batch(n, n_threads, [&](uint64_t lo, uint64_t hi) {
     encode_adm_rows(t, reqs.data(), lo, hi, codes, extras, extras_cap,
-                    /*extras_pad=*/-1, extras_count, flags, uids, uid_lens);
+                    /*extras_pad=*/-1, extras_count, flags, uids, uid_lens,
+                    anc);
   });
 }
 
@@ -2790,7 +2798,7 @@ void ce_encode_sar_pylist(void *handle, PyObject *list, uint64_t n_alloc,
                           int32_t *codes, int32_t *extras,
                           int32_t extras_cap, int32_t extras_pad,
                           int32_t *extras_count, uint8_t *flags,
-                          int32_t n_threads) {
+                          int32_t *anc, int32_t n_threads) {
   const Table &t = *static_cast<Table *>(handle);
   PyListViews views(list, n_alloc);
   uint64_t n = views.reqs.size();
@@ -2802,11 +2810,12 @@ void ce_encode_sar_pylist(void *handle, PyObject *list, uint64_t n_alloc,
       extras[i * uint64_t(extras_cap) + k] = extras_pad;
     extras_count[i] = 0;
     flags[i] = F_PARSE_ERROR;
+    if (anc) memset(anc + i * 3, 0, 3 * sizeof(int32_t));
   }
   PyThreadState *st = PyEval_SaveThread();
   drive_batch(n, n_threads, [&](uint64_t lo, uint64_t hi) {
     encode_sar_rows(t, views.reqs.data(), lo, hi, codes, extras,
-                    extras_cap, extras_pad, extras_count, flags);
+                    extras_cap, extras_pad, extras_count, flags, anc);
   });
   PyEval_RestoreThread(st);
   views.release();
@@ -2816,7 +2825,8 @@ void ce_encode_adm_pylist(void *handle, PyObject *list, uint64_t n_alloc,
                           int32_t *codes, int32_t *extras,
                           int32_t extras_cap, int32_t extras_pad,
                           int32_t *extras_count, uint8_t *flags, char *uids,
-                          int32_t *uid_lens, int32_t n_threads) {
+                          int32_t *uid_lens, int32_t *anc,
+                          int32_t n_threads) {
   const Table &t = *static_cast<Table *>(handle);
   PyListViews views(list, n_alloc);
   uint64_t n = views.reqs.size();
@@ -2827,12 +2837,13 @@ void ce_encode_adm_pylist(void *handle, PyObject *list, uint64_t n_alloc,
     extras_count[i] = 0;
     uid_lens[i] = 0;
     flags[i] = F_PARSE_ERROR;
+    if (anc) memset(anc + i * 3, 0, 3 * sizeof(int32_t));
   }
   PyThreadState *st = PyEval_SaveThread();
   drive_batch(n, n_threads, [&](uint64_t lo, uint64_t hi) {
     encode_adm_rows(t, views.reqs.data(), lo, hi, codes, extras,
                     extras_cap, extras_pad, extras_count, flags, uids,
-                    uid_lens);
+                    uid_lens, anc);
   });
   PyEval_RestoreThread(st);
   views.release();

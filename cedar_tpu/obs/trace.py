@@ -502,7 +502,11 @@ def span_windows(windows, times=None) -> list:
                 if k.startswith(phase + ".")
             }
         elif phase == "encode" and getattr(times, "extras_max", None) is not None:
-            attrs = {"extras_max": times.extras_max}
+            attrs = {
+                "extras_max": times.extras_max,
+                "groups": times.groups,
+                "known_groups": times.known_groups,
+            }
         out.append((name, t0, t1, attrs))
     return out
 
@@ -720,15 +724,18 @@ class _SubStage:
             self._scope.__exit__(exc_type, exc, tb)
 
 
-def note_encode_extras(extras_max: int) -> None:
-    """The widest encoded row's extras onto the batch record bound to this
-    worker thread (a stage may encode several chunks: the largest stays);
-    nothing outside a batcher's encode stage."""
+def note_encode_extras(extras_max: int, groups: int, known_groups: int) -> None:
+    """The widest encoded row's extras, the most groups a row's principal
+    carries and the most of them that some policy names, onto the batch
+    record bound to this worker thread (a stage may encode several chunks:
+    the largest of each stays); nothing outside a batcher's encode stage."""
     times = getattr(_stage_local, "times", None)
-    if times is not None and (
-        times.extras_max is None or extras_max > times.extras_max
-    ):
+    if times is None:
+        return
+    if times.extras_max is None or extras_max > times.extras_max:
         times.extras_max = extras_max
+    times.groups = max(times.groups, groups)
+    times.known_groups = max(times.known_groups, known_groups)
 
 
 def sub_stage(name: str):

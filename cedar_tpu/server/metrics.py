@@ -996,11 +996,25 @@ encode_extras = REGISTRY.register(
         "cedar_encode_extras",
         "Set-membership extras the native encoder emitted, by path: _sum "
         "over the extras of every encoded row, _count the rows. A row "
-        "past the encoder's cap (32) leaves the native path and is "
+        "past the encoder's cap (256) leaves the native path and is "
         "counted as encoder_fallback in "
         "cedar_authorizer_row_routing_total; this family says how near "
         "the rows come to it.",
         ["path"],
+    )
+)
+
+encode_ancestors_total = REGISTRY.register(
+    Counter(
+        "cedar_encode_ancestors_total",
+        "Groups of the principals of natively encoded rows, by path and "
+        "by where the encoder put each: slot (a policy-known group in one "
+        "of the eight ancestor code slots), extras (a policy-known group "
+        "past the slots: its `principal in` literals ride the row's "
+        "extras list), unknown (no policy names it: it activates "
+        "nothing). extras over slot + extras is the share of known "
+        "memberships that the extras plane carries.",
+        ["path", "where"],
     )
 )
 
@@ -1701,6 +1715,11 @@ def record_request_body_bytes(path: str, n: int) -> None:
 def record_encode_extras(path: str, extras: int, rows: int) -> None:
     if rows:
         encode_extras.observe(path, extras, rows)
+
+
+def record_encode_ancestors(path: str, where: str, n: int) -> None:
+    if n:
+        encode_ancestors_total.inc(n, path=path, where=where)
 
 
 def record_interpreter_wait(late_s: float, watched_s: float) -> None:

@@ -16,7 +16,8 @@ from cedar_tpu.engine.evaluator import TPUPolicyEngine
 from cedar_tpu.engine.fastpath import AdmissionFastPath
 from cedar_tpu.entities.admission import AdmissionRequest
 from cedar_tpu.lang import PolicySet
-from cedar_tpu.native import F_EXTRAS_OVERFLOW, F_OK, native_available
+from cedar_tpu.compiler.table import ANCESTOR_SLOTS
+from cedar_tpu.native import F_EXTRAS_OVERFLOW, F_OK, NativeEncoder, native_available
 from cedar_tpu.server import metrics
 from cedar_tpu.server.admission import (
     ALLOW_ALL_ADMISSION_POLICY_SOURCE,
@@ -147,7 +148,9 @@ def test_a_head_without_the_envelopes_names_sets_no_name():
 
 # ------------------------------------------------- the encoder's extras
 
-GROUPS = 40
+# one group more than a native row can carry: the eight ancestor slots, then
+# the extras list up to the encoder's cap
+GROUPS = ANCESTOR_SLOTS["principal"] + NativeEncoder.DEFAULT_EXTRAS_CAP + 1
 WIDE = "\n".join(
     f'forbid (principal in k8s::Group::"g{i}", action == k8s::admission::Action::"create", '
     'resource is core::v1::ConfigMap) when { resource.metadata has labels && '

@@ -29,7 +29,7 @@ from cedar_tpu.engine.batcher import (
     MicroBatcher,
     PipelinedBatcher,
 )
-from cedar_tpu.engine.evaluator import TPUPolicyEngine
+from cedar_tpu.engine.evaluator import EXTRAS_WIDTHS, TPUPolicyEngine
 from cedar_tpu.lang import PolicySet
 from cedar_tpu.native import native_available
 from cedar_tpu.ops.match import kernel_trace_count
@@ -301,9 +301,9 @@ permit (principal, action == k8s::Action::"get", resource is k8s::Resource)
         L = cs.packed.L
         tc0 = kernel_trace_count()
         for b in (1, 3, 8, 17, 32, 100, 128):
-            # every native-fastpath extras width (1/8/16/32): width 16/32
-            # selector-heavy traffic must be as trace-free as no-extras
-            for E in (1, 8, 16, 32):
+            # every native-fastpath extras width: selector-heavy and
+            # group-heavy traffic must be as trace-free as no-extras
+            for E in EXTRAS_WIDTHS:
                 codes = np.zeros((b, n_slots), dtype=cs.code_dtype)
                 extras = np.full((b, E), L, dtype=cs.active_dtype)
                 engine.match_arrays(codes, extras, cs=cs)
