@@ -157,7 +157,10 @@ def _env_fields() -> dict:
         jaxlib_version = "?"
     devs = jax.devices()
     return {
-        "format": 1,
+        # 2: a want_bits launch returns one packed buffer, not four arrays
+        # (ops/match.py _pack_out) — a format-1 executable of the same
+        # name, statics and operand shapes must never serve that call
+        "format": 2,
         "jax": jax.__version__,
         "jaxlib": jaxlib_version,
         "platform": devs[0].platform if devs else "none",

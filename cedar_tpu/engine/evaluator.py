@@ -79,6 +79,7 @@ from ..ops.match import (
     match_rules_codes_donated,
     match_rules_codes_wire,
     match_rules_codes_wire_donated,
+    unpack_out,
 )
 from . import aot
 
@@ -298,9 +299,10 @@ class _WordPacker:
 
     Single-chunk batches skip the concat — flush() just starts the same
     async copy the unpacked path would have, so a lone request's p99 is
-    byte-for-byte the old path. Not used for want_full/want_bits launches
-    (their payloads dominate the transfer) or mesh engines (concatenating
-    sharded outputs would force a reshard)."""
+    byte-for-byte the old path. Not used for want_bits launches (each
+    already brings everything home in its own one buffer), want_full
+    launches (the [B, G] matrices dominate the transfer) or mesh engines
+    (concatenating sharded outputs would force a reshard)."""
 
     def __init__(self):
         self._parts: list = []  # device word arrays, padded lengths
@@ -1700,9 +1702,14 @@ class TPUPolicyEngine:
 
         With want_bits a third element is returned: {row index: [R/32]
         uint32 bitset} for every flagged row (multi/err verdicts, or any
-        multi-distinct group under want_full), compacted on device and
-        fetched with the words — the diagnostics payload costs no extra
-        device round trip (ops/match.py BITS_TOPK).
+        multi-distinct group under want_full), compacted on device. The
+        served launch (want_bits alone, one device) gets words and bitsets
+        back as ONE buffer (ops/match.py _pack_out) whose single readback
+        starts here at launch: finish() waits for it, makes no device
+        call and starts no transfer, clean batch or flagged. With
+        want_full the compaction stays a separate payload, fetched by
+        finish() only when a row is flagged; a mesh launch has none
+        (resolve_flagged fetches its bitsets).
 
         `cs` pins the compiled set the codes were encoded against — callers
         that encoded against a snapshot MUST pass it, or a concurrent policy
@@ -1737,13 +1744,16 @@ class TPUPolicyEngine:
         extras_arr = extras_arr.astype(cs.active_dtype, copy=False)
 
         held: list = []  # pooled staging buffers, released by finish()
+        # words and flagged rows' bitsets come home as one buffer
+        one_buffer = want_bits and not want_full and cs.mesh is None
 
         # Both launch functions return (words_dev, full_dev_or_None,
-        # pack_dev_or_None); m is the VALID row count (excludes
-        # caller-side staging padding), used only to mask the want_bits
-        # compaction. Host staging (pad to the bucket, the u8 wire pack)
-        # and the launch (the jitted call and the H2D it implies) are
-        # timed apart: obs.trace sub_stage `dispatch.stage` — which is
+        # pack_dev_or_None) — or, for a one-buffer launch, (buffer_dev,
+        # None, its bucket-padded row count); m is the VALID row count
+        # (excludes caller-side staging padding), used only to mask the
+        # want_bits compaction. Host staging (pad to the bucket, the u8
+        # wire pack) and the launch (the jitted call and the H2D it
+        # implies) are timed apart: obs.trace sub_stage `dispatch.stage` — which is
         # also what a dispatch's time counts as outside every sub-stage —
         # and `dispatch.launch`.
 
@@ -1831,6 +1841,8 @@ class TPUPolicyEngine:
                     ),
                     aot.STATICS[layout],
                 )
+            if one_buffer:
+                return out, None, chunk_c.shape[0]
             return out if want_bits else (*out, None)
 
         launch = mesh_launch if cs.mesh is not None else device_launch
@@ -1838,27 +1850,25 @@ class TPUPolicyEngine:
         def trim_full(f, m):
             return (np.asarray(f[0])[:m], np.asarray(f[1])[:m])
 
-        def any_flagged(words_h, full_h):
-            """Host-side gate before materializing the [K, R/32] compaction
-            payload: words (and full, when requested) are already fetched,
-            so a clean batch — the overwhelming majority — skips the
-            payload transfer entirely."""
-            if full_h is not None:
-                first, last = full_h
-                return bool(((first != last) & (first != INT32_MAX)).any())
-            return bool(
-                (words_h.astype(np.uint32) & (WORD_ERR | WORD_MULTI)).any()
-            )
+        def live_rows(vals, idx, kbits, lo, bitmap):
+            """A compaction's live slots (vals > 0) into the bitmap, by
+            the row each slot came from."""
+            live = np.nonzero(vals > 0)[0]
+            for j, r in zip(live.tolist(), idx[live].tolist()):
+                bitmap[lo + r] = kbits[j]
+
+        def any_flagged(full_h):
+            """want_full launches only: the host-side gate before their
+            separate [K, R/32] compaction payload is fetched at all —
+            the full matrices are already here, and a batch with no
+            multi-distinct group skips that transfer."""
+            first, last = full_h
+            return bool(((first != last) & (first != INT32_MAX)).any())
 
         def pack_rows(pack, lo, bitmap):
-            if pack is None:
-                return
             for a in pack:  # one overlapped transfer, not 3 serial RTTs
                 a.copy_to_host_async()
-            vals, idx, kbits = (np.asarray(a) for a in pack)
-            live = vals > 0
-            for r, b in zip(idx[live].tolist(), kbits[live]):
-                bitmap[lo + r] = b
+            live_rows(*(np.asarray(a) for a in pack), lo, bitmap)
 
         # ---- launch: dispatch every sub-batch asynchronously. The returned
         # finish() materializes — callers that interleave host work (e.g.
@@ -1896,9 +1906,10 @@ class TPUPolicyEngine:
                 host = [
                     (
                         lo,
+                        m,
                         word_pack.view(part, m)
                         if part is not None
-                        else np.asarray(w)[:m],
+                        else np.asarray(w),
                         trim_full(f, m) if want_full else None,
                         p,
                     )
@@ -1909,22 +1920,27 @@ class TPUPolicyEngine:
             if held:
                 self._staging.release(*held)
                 del held[:]
+            words = []
+            for lo, m, wh, fh, p in host:
+                if one_buffer:
+                    # the bitsets are on the host already, behind the
+                    # words: views of the one buffer, no device call
+                    wh, *compaction = unpack_out(wh, p)
+                    live_rows(*compaction, lo, bitmap)
+                elif want_bits and p is not None and any_flagged(fh):
+                    pack_rows(p, lo, bitmap)
+                words.append(wh[:m])
             if len(host) == 1:
-                _, words, full, _ = host[0]
+                words, full = words[0], host[0][3]
             else:
-                words = np.concatenate([wh for _, wh, _, _ in host])
+                words = np.concatenate(words)
                 full = None
                 if want_full:
                     full = (
-                        np.concatenate([fh[0] for _, _, fh, _ in host]),
-                        np.concatenate([fh[1] for _, _, fh, _ in host]),
+                        np.concatenate([fh[0] for *_, fh, _ in host]),
+                        np.concatenate([fh[1] for *_, fh, _ in host]),
                     )
-            if want_bits:
-                for lo, wh, fh, p in host:
-                    if p is not None and any_flagged(wh, fh):
-                        pack_rows(p, lo, bitmap)
-                return words, full, bitmap
-            return words, full
+            return (words, full, bitmap) if want_bits else (words, full)
 
         return finish
 

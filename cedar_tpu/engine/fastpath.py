@@ -618,11 +618,11 @@ class _RawFastPath:
         fin = None
         if idx is not None:
             # small batches: rule bitsets for multi/err rows arrive
-            # compacted IN the same device call (zero extra round trips
-            # over the high-RTT link). Large batches skip the bits plane;
-            # the deferred resolve fetches the rare flagged rows' bitsets
-            # in a second fixed-shape call instead — and their words ride
-            # the packed batch transfer.
+            # compacted IN the same device call and the same readback as
+            # the words (one buffer a launch, no second trip). Large
+            # batches skip the bits plane; the deferred resolve fetches
+            # the rare flagged rows' bitsets in a second fixed-shape call
+            # instead — and their words ride the packed batch transfer.
             fin = self.engine.match_arrays_launch(
                 ok_codes, ok_extras, cs=snap.cs,
                 want_bits=len(idx) <= self._BITS_INCALL_MAX,
@@ -777,6 +777,7 @@ class _RawFastPath:
         cache = snap.word_cache
         decode_bits = self._decode_bits_payload
         key_bits = _gather_flag_bits(self.engine, snap, ctxs)
+        n_readback = n_cached = n_flagged = 0
         for ctx in ctxs:
             if not ctx["flag_rows"]:
                 continue
@@ -784,11 +785,14 @@ class _RawFastPath:
             fc = ctx["flag_cached"]
             fkeys = ctx["flag_keys"]
             aux = ctx["aux"]
+            n_flagged += len(ctx["flag_rows"])
             for k in ctx["flag_rows"]:
                 if bm and k in bm:
                     payload = decode_bits(snap, bm[k])
+                    n_readback += 1
                 elif k in fc:
                     payload = fc[k]
+                    n_cached += 1
                 else:
                     key = fkeys[k]
                     payload = cache.get(key)
@@ -796,6 +800,18 @@ class _RawFastPath:
                         payload = cache[key] = decode_bits(snap, key_bits[key])
                 i = int(ctx["idx"][k])
                 ctx["results"][i] = self._emit(payload, i, aux)
+        if n_flagged:
+            # once a batch, and only a batch with flagged rows: how each
+            # row's bitset reached the host (a second_call row's key was
+            # in a standalone bits fetch, launched or rescued)
+            from ..server.metrics import record_flagged_bits
+
+            p = self._METRIC_PATH
+            record_flagged_bits(p, "readback", n_readback)
+            record_flagged_bits(p, "word_cache", n_cached)
+            record_flagged_bits(
+                p, "second_call", n_flagged - n_readback - n_cached
+            )
 
         # every device readback for this batch has materialized and every
         # flagged row's feature bytes have been consumed: the pooled

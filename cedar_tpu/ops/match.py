@@ -314,13 +314,14 @@ def _lit_matrix_codes(codes, extras, act_rows):
 
 
 # flagged-row compaction width: the kernel returns rule bitsets for up to
-# this many flagged rows per call, fetched WITH the verdict words in the
-# same async readback — the diagnostics path costs zero extra device round
-# trips (a second call would be paid by every batch containing a
-# multi-match row). Overflow rows (> K flagged) fall back to
-# match_rules_codes_bits. 128 keeps the payload ~160KB at R=10240; the
-# in-call plane only serves latency-regime batches <= 4096 rows, where
-# >128 flagged rows is vanishingly rare.
+# this many flagged rows per call, IN the one result buffer that carries
+# the verdict words (_pack_out): one readback a launch, so an answer that
+# names several policies costs no second trip to the device. Overflow rows
+# (> K flagged) fall back to match_rules_codes_bits. The buffer is
+# 4 * (B + K * (2 + R/32)) bytes: 1.3 kB at one row and 164 kB from bucket
+# 128 up at R=10240, clean batch or not (the D2H reading by bucket is in
+# PERF.md section 6, PR 37); the in-call plane only serves latency-regime
+# batches <= 4096 rows, where >128 flagged rows is vanishingly rare.
 BITS_TOPK = 128
 
 
@@ -340,6 +341,26 @@ def _compact_flagged_bits(bits, flagged, n_valid):
     key = jnp.where(flagged, jnp.int32(B) - iota, jnp.int32(0))
     vals, idx = jax.lax.top_k(key, K)
     return vals, idx, jnp.take(bits, idx, axis=0)
+
+
+@jax.named_scope("cedar.match.out_pack")
+def _pack_out(packed, vals, idx, kbits):
+    """The one result buffer of a want_bits launch, a uint32 vector: the B
+    verdict words, then vals [K] and idx [K] (int32 bit patterns), then
+    kbits [K, R/32] row by row. unpack_out is its host-side inverse."""
+    meta = jax.lax.bitcast_convert_type(
+        jnp.concatenate([vals, idx]), jnp.uint32
+    )
+    return jnp.concatenate([packed, meta, kbits.reshape(-1)])
+
+
+def unpack_out(host, B: int):
+    """Zero-copy views of a fetched _pack_out buffer for a launch of B
+    (bucket-padded) rows: (words [B] uint32, vals [K] int32, idx [K]
+    int32, kbits [K, R/32] uint32)."""
+    K = min(B, BITS_TOPK)
+    meta = host[B : B + 2 * K].view("int32")
+    return host[:B], meta[:K], meta[K:], host[B + 2 * K :].reshape(K, -1)
 
 
 def _match_rules_codes_py(
@@ -367,13 +388,15 @@ def _match_rules_codes_py(
     complete diagnostics without a bitset fetch for rows where every group
     matched at most one distinct policy (min == max).
 
-    want_bits appends a (vals, idx, kbits) triple (_compact_flagged_bits):
+    want_bits adds the (vals, idx, kbits) triple of _compact_flagged_bits:
     rule bitsets for the rows whose verdict cannot be rendered from the
-    word/first matrices alone, computed in the SAME scan and fetched with
-    the words — the diagnostics contract of cedar-go (/root/reference
-    internal/server/store/store.go:31) without a second device call.
-    n_valid (dynamic scalar) masks bucket-padding rows out of the
-    compaction.
+    word/first matrices alone, computed in the SAME scan — the diagnostics
+    contract of cedar-go (/root/reference internal/server/store/store.go:31)
+    without a second device call. Alone (the served launch) it returns ONE
+    uint32 vector, words and triple together (_pack_out / unpack_out), so
+    a launch has one readback; with want_full it returns (packed, (first,
+    last), triple). n_valid (dynamic scalar) masks bucket-padding rows out
+    of the compaction.
 
     has_gate: the packed set carries fallback-scope gate rules in group
     n_tiers * 3; rows with a gate hit get WORD_GATE set in their word (and
@@ -413,7 +436,8 @@ def _match_from_lit(
     """Shared post-literal-expansion body of match_rules_codes and its wire
     variant: scores + first-match reduction (segmented when `segs` is
     given, masked scan otherwise) + tier walk + gate bit + (optional)
-    flagged-row bits compaction."""
+    flagged-row bits compaction, packed behind the words into the one
+    result buffer where the caller wants bits and no full matrices."""
     n_groups = n_tiers * _GPT + (1 if has_gate else 0)
     if segs is not None:
         first, last, bits = _first_match_seg(
@@ -437,10 +461,10 @@ def _match_from_lit(
         # group with >1 distinct matched policy may end up deciding, so
         # flag on the full min != max test, not the device walk's verdict
         flagged = ((first != last) & (first != INT32_MAX)).any(axis=1)
-    else:
-        flagged = (packed & jnp.uint32(WORD_ERR | WORD_MULTI)) != 0
-    pack = _compact_flagged_bits(bits, flagged, n_valid)
-    return (packed, (first, last) if want_full else None, pack)
+        pack = _compact_flagged_bits(bits, flagged, n_valid)
+        return packed, (first, last), pack
+    flagged = (packed & jnp.uint32(WORD_ERR | WORD_MULTI)) != 0
+    return _pack_out(packed, *_compact_flagged_bits(bits, flagged, n_valid))
 
 
 @jax.named_scope("cedar.match.activation")

@@ -1018,6 +1018,22 @@ encode_ancestors_total = REGISTRY.register(
     )
 )
 
+flagged_bits_total = REGISTRY.register(
+    Counter(
+        "cedar_flagged_bits_total",
+        "Flagged rows (row_class=\"flagged\" of "
+        "cedar_authorizer_row_routing_total: an answer that names several "
+        "policies, or an error beside a match) by how each row's rule "
+        "bitset reached the host: readback (it rode the launch's one "
+        "result buffer, behind the verdict words: no further device "
+        "call), word_cache (a row with the same feature bytes was "
+        "resolved before), second_call (the standalone bits kernel: "
+        "more flagged rows than the compaction holds, or a batch past "
+        "the in-call bits plane).",
+        ["path", "by"],
+    )
+)
+
 interpreter_wait_seconds = REGISTRY.register(
     Histogram(
         "cedar_interpreter_wait_seconds",
@@ -1720,6 +1736,11 @@ def record_encode_extras(path: str, extras: int, rows: int) -> None:
 def record_encode_ancestors(path: str, where: str, n: int) -> None:
     if n:
         encode_ancestors_total.inc(n, path=path, where=where)
+
+
+def record_flagged_bits(path: str, by: str, n: int) -> None:
+    if n:
+        flagged_bits_total.inc(n, path=path, by=by)
 
 
 def record_interpreter_wait(late_s: float, watched_s: float) -> None:

@@ -198,6 +198,40 @@ def test_stale_entry_refused_and_recompiled(tmp_path):
     assert aot.stats()["hits"] == 1 and aot.stats()["stale"] == 0
 
 
+def test_format_1_entry_is_refused(tmp_path):
+    """An entry written before a want_bits launch returned ONE buffer
+    (format 1: words and the compaction as four arrays) is never served,
+    by either road: its key is another (the name it was filed under is
+    not looked up), and one copied onto the new name is refused by its
+    meta header and recompiled."""
+    import jax
+
+    aot.set_cache_dir(str(tmp_path))
+    x = np.arange(4, dtype=np.float32)
+    meta = aot._key_meta("unit", (x,), ())
+    assert meta["format"] == 2
+    old_meta = dict(meta, format=1)
+    assert aot._key(old_meta) != aot._key(meta)
+
+    aot.dispatch("unit", jax.jit(lambda v: v - 1), (x,), ())
+    (path,) = glob.glob(str(tmp_path / "*.jexp"))
+    disk_meta, blob = aot._read_entry(path)
+    assert disk_meta == meta
+    # filed under its own (format 1) name beside the new one, and copied
+    # over the new name
+    aot._write_entry(aot._path("unit", aot._key(old_meta)), old_meta, blob)
+    aot._write_entry(path, old_meta, blob)
+
+    aot.set_cache_dir(str(tmp_path))
+    aot.reset_counters()
+    out = aot.dispatch("unit", jax.jit(lambda v: v - 1), (x,), ())
+    np.testing.assert_allclose(np.asarray(out), x - 1)
+    s = aot.stats()
+    assert s["stale"] == 1 and s["hits"] == 0
+    assert s["misses"] == 1 and s["exports"] == 1
+    assert aot._read_entry(path)[0] == meta  # rewritten under format 2
+
+
 def test_corrupt_entry_refused(tmp_path):
     import jax
 

@@ -5,8 +5,9 @@ behind two input layouts: the u8 wire (``match_rules_codes_wire``) and the
 flat codes (``match_rules_codes``). Both are held here, on the same random
 requests, to a first-match / tier-walk reference written in numpy below —
 verdict words (code, policy, err / multi flags, gate bit), the want_full
-first / last matrices and the in-call flagged-row bitsets — and the engine
-end to end to the interpreter. The last tests pin what a deleted plane
+first / last matrices and the in-call flagged-row bitsets, which a served
+launch returns behind its words as one buffer — and the engine end to end
+to the interpreter. The last tests pin what a deleted plane
 would bring back: a switch nothing reads, a key nothing reports, a second
 copy of W on the device.
 """
@@ -21,7 +22,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from cedar_tpu.engine.evaluator import TPUPolicyEngine
+from cedar_tpu.engine.evaluator import TPUPolicyEngine, _segment_plan
 from cedar_tpu.lang import PolicySet
 from cedar_tpu.ops.match import (
     BITS_TOPK,
@@ -32,6 +33,7 @@ from cedar_tpu.ops.match import (
     chunk_rules,
     match_rules_codes,
     match_rules_codes_wire,
+    unpack_out,
 )
 
 REPO = pathlib.Path(__file__).resolve().parents[1]
@@ -165,16 +167,78 @@ def test_kernel_matches_numpy_reference(B, L, R, T, gate, layout, want_bits):
         assert (np.asarray(last) == ref_last).all()
         return
     out = kernel(*lead, *plane, T, False, True, np.int32(n_valid), gate)
-    assert (np.asarray(out[0]) == ref_words).all()
-    assert out[1] is None
-    vals, idx, kbits = (np.asarray(a) for a in out[2])
+    _assert_one_buffer(out, B, n_valid, ref_words, ref_bits)
+
+
+def _assert_one_buffer(out, B, n_valid, ref_words, ref_bits):
+    """A want_bits launch's whole result is ONE uint32 vector, and it
+    unpacks to exactly the reference: every word, then the compaction —
+    the first K flagged valid rows in row order, each with its bitset,
+    every slot past them dead."""
+    K = min(B, BITS_TOPK)
+    w32 = ref_bits.shape[1]
+    assert isinstance(out, jax.Array) and out.dtype == jnp.uint32
+    assert out.shape == (B + K * (2 + w32),)
+    host = np.asarray(out)
+    words, vals, idx, kbits = unpack_out(host, B)
+    for view in (words, vals, idx, kbits):
+        assert np.shares_memory(view, host)  # views, not copies
+    assert vals.dtype == idx.dtype == np.int32 and kbits.shape == (K, w32)
+    assert (words == ref_words).all()
     flagged = np.nonzero(
         ((ref_words & np.uint32(WORD_ERR | WORD_MULTI)) != 0)
         & (np.arange(B) < n_valid)
     )[0]
     live = vals > 0
-    assert idx[live].tolist() == flagged[: min(B, BITS_TOPK)].tolist()
+    n_live = min(len(flagged), K)
+    assert live.tolist() == [True] * n_live + [False] * (K - n_live)
+    assert idx[live].tolist() == flagged[:K].tolist()
+    assert (vals[live] == B - idx[live]).all()
     assert (kbits[live] == ref_bits[idx[live]]).all()
+    return len(flagged)
+
+
+@pytest.mark.parametrize("B", [1, 8, 32, 128, 512])
+@pytest.mark.parametrize("segs", [False, True], ids=["scan", "segs"])
+@pytest.mark.parametrize("gate", [False, True], ids=["nogate", "gate"])
+@pytest.mark.parametrize("layout", ["wire", "codes"])
+def test_one_result_buffer_unpacks_to_the_reference(layout, gate, segs, B):
+    """The served launch's one buffer at the served buckets: K = B up to
+    128 rows and K = 128 < B past it, with more flagged rows than K at
+    512 (the overflow the host sends to the standalone bits kernel); a
+    row past n_valid is never in the compaction."""
+    L, R, T = 128, 512, 2
+    rng = np.random.default_rng(1000 + B)
+    n_groups = T * 3 + (1 if gate else 0)
+    W, thresh, group, policy, act, lo8, c8, cw, extras = _random_problem(
+        rng, max(B, 64), L, R, n_groups
+    )
+    # group-contiguous rules, as compiler.pack lays them out: what the
+    # segmented reduction's static column runs need
+    group = np.sort(group)
+    g8 = np.where(c8 == 0, 0, c8.astype(np.int32) + lo8[None, :] - 1)
+    codes = np.concatenate([g8, cw.astype(np.int32)], axis=1).astype(np.int16)
+    ref_words, _, _, ref_bits = _reference(
+        W, thresh, group, policy, act, codes, extras, T, gate
+    )
+    flags = (ref_words & np.uint32(WORD_ERR | WORD_MULTI)) != 0
+    # the rows the launch gets: flagged rows first where there are few
+    # rows (a one-row batch is a flagged row), so every case has some
+    order = np.argsort(~flags, kind="stable")[:B] if B < 64 else np.arange(B)
+    c8, cw, codes, extras = (a[order] for a in (c8, cw, codes, extras))
+    ref_words, ref_bits = ref_words[order], ref_bits[order]
+    chunks = chunk_rules(W, thresh, group, policy)
+    plan = _segment_plan(chunks[2], R) if segs else None
+    plane = tuple(jnp.asarray(a) for a in (act, *chunks))
+    n_valid = B - 3 if B >= 8 else B
+    if layout == "wire":
+        kernel, lead = match_rules_codes_wire, (c8, cw, jnp.asarray(lo8), extras)
+    else:
+        kernel, lead = match_rules_codes, (codes, extras)
+    out = kernel(*lead, *plane, T, False, True, np.int32(n_valid), gate, plan)
+    n_flagged = _assert_one_buffer(out, B, n_valid, ref_words, ref_bits)
+    assert n_flagged >= 1
+    assert B < 512 or n_flagged > BITS_TOPK
 
 
 def test_engine_matches_interpreter_on_the_random_corpus():

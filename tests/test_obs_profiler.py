@@ -111,10 +111,10 @@ def _kernel_args(want_bits: bool, wire: bool):
 SERVING = ("activation", "score", "scan", "tier_walk", "word_pack")
 KERNELS = [
     ("match_rules_codes", False, False, SERVING),
-    ("match_rules_codes", True, False, SERVING + ("bits_pack", "bits_compact")),
+    ("match_rules_codes", True, False, SERVING + ("bits_pack", "bits_compact", "out_pack")),
     ("match_rules_codes_donated", False, False, SERVING),
     ("match_rules_codes_wire", False, True, SERVING),
-    ("match_rules_codes_wire", True, True, SERVING + ("bits_pack", "bits_compact")),
+    ("match_rules_codes_wire", True, True, SERVING + ("bits_pack", "bits_compact", "out_pack")),
     ("match_rules_codes_wire_donated", False, True, SERVING),
     ("match_rules_codes_bits", None, False, ("activation", "score", "scan", "bits_pack")),
 ]

@@ -319,6 +319,211 @@ permit (principal, action == k8s::Action::"get", resource is k8s::Resource)
             TPUPolicyEngine().warmup()
 
 
+# ---------------------------------------------------- one result buffer
+
+FLAG_POLICIES = """
+permit (principal, action, resource) when { principal.name == "sam" };
+permit (principal, action, resource) when { resource.resource == "pods" };
+forbid (principal, action, resource) when { resource.resource == "nodes" };
+"""
+
+
+def _flag_rows(engine, kinds):
+    """Python-encoded feature rows: "flag" (sam gets pods: two permits
+    decide, the word's multi bit), "clean" (one permit), "none"."""
+    from cedar_tpu.compiler.table import encode_request_codes
+    from cedar_tpu.entities.attributes import Attributes, UserInfo
+    from cedar_tpu.server.authorizer import record_to_cedar_resource
+
+    what = {"flag": ("sam", "pods"), "clean": ("bob", "pods"),
+            "none": ("bob", "secrets")}
+    cs = engine._compiled
+    encoded = [
+        encode_request_codes(
+            cs.packed.plan, cs.packed.table,
+            *record_to_cedar_resource(Attributes(
+                user=UserInfo(name=what[k][0], uid="u"), verb="get",
+                resource=what[k][1], api_version="v1", resource_request=True,
+            )),
+        )
+        for k in kinds
+    ]
+    return engine._encode_batch_arrays(cs, encoded, len(encoded))
+
+
+@pytest.fixture
+def device_calls(monkeypatch):
+    """Every transfer a device array starts and every kernel the engine
+    dispatches, in order, as (what, phase): the test sets the phase."""
+    import jax.numpy as jnp
+
+    from cedar_tpu.engine import evaluator
+
+    log = {"phase": "launch", "calls": []}
+    array_type = type(jnp.zeros((1,)))
+    start_copy = array_type.copy_to_host_async
+    dispatch = evaluator.aot.dispatch
+
+    def counted_copy(self):
+        log["calls"].append(("copy", log["phase"]))
+        return start_copy(self)
+
+    def counted_dispatch(name, *args):
+        log["calls"].append((name, log["phase"]))
+        return dispatch(name, *args)
+
+    monkeypatch.setattr(array_type, "copy_to_host_async", counted_copy)
+    monkeypatch.setattr(evaluator.aot, "dispatch", counted_dispatch)
+    return log
+
+
+class TestOneResultBuffer:
+    """A want_bits launch brings words and flagged rows' bitsets home in
+    ONE buffer: one transfer, started at launch; finish() touches the
+    device no more (ISSUE 37)."""
+
+    @pytest.fixture(scope="class")
+    def engine(self):
+        engine = TPUPolicyEngine()
+        engine.load([PolicySet.from_source(FLAG_POLICIES, "flag")], warm="off")
+        return engine
+
+    # (the rows, valid_rows): a flagged one-row batch; a mixed 32-row
+    # batch; the same staged as the fast paths stage it, its last three
+    # rows bucket padding that WOULD be flagged were they counted
+    CASES = {
+        "one_flagged_row": (["flag"], None),
+        "mixed_32": ((["flag", "clean", "none", "clean"] * 8), None),
+        "mixed_32_staged_29_valid": (
+            (["flag", "clean", "none", "clean"] * 8)[:29] + ["flag"] * 3, 29
+        ),
+    }
+
+    @pytest.mark.parametrize("case", CASES)
+    def test_one_transfer_at_launch_and_none_in_finish(
+        self, engine, device_calls, case
+    ):
+        from cedar_tpu.ops.match import WORD_MULTI
+
+        kinds, valid = self.CASES[case]
+        codes, extras = _flag_rows(engine, kinds)
+        cs = engine._compiled
+        fin = engine.match_arrays_launch(
+            codes, extras, cs=cs, want_bits=True, valid_rows=valid
+        )
+        at_launch = list(device_calls["calls"])
+        device_calls["phase"] = "finish"
+        words, full, bitmap = fin()
+        assert [c for c in at_launch if c[0] == "copy"] == [("copy", "launch")]
+        assert len(at_launch) == 2  # the one kernel, the one transfer
+        assert device_calls["calls"] == at_launch  # finish(): nothing
+        assert full is None and words.shape == (len(kinds),)
+        n = len(kinds) if valid is None else valid
+        want = [i for i, k in enumerate(kinds[:n]) if k == "flag"]
+        assert sorted(bitmap) == want  # every flagged row, no padding row
+        multi = (words.astype(np.uint32) & WORD_MULTI) != 0
+        assert np.nonzero(multi)[0].tolist() == [
+            i for i, k in enumerate(kinds) if k == "flag"
+        ]
+        device_calls["phase"] = "reference"
+        ref_words, _ = engine.match_arrays(codes, extras, cs=cs)
+        assert (words == ref_words).all()
+        ref_bits = engine.match_bits_arrays(codes, extras, cs=cs)
+        for i, row in bitmap.items():
+            assert row.dtype == np.uint32 and (row == ref_bits[i]).all()
+
+    def test_want_full_keeps_its_outputs_and_its_gated_fetch(
+        self, engine, device_calls
+    ):
+        """want_full + want_bits (no served path): words, the two full
+        matrices, and the compaction fetched by finish() only where a
+        group matched two policies."""
+        cs = engine._compiled
+        for kinds, fetched in ((["clean", "none"], 0), (["flag", "clean"], 3)):
+            del device_calls["calls"][:]
+            device_calls["phase"] = "launch"
+            codes, extras = _flag_rows(engine, kinds)
+            fin = engine.match_arrays_launch(
+                codes, extras, cs=cs, want_full=True, want_bits=True
+            )
+            device_calls["phase"] = "finish"
+            words, (first, last), bitmap = fin()
+            calls = device_calls["calls"]
+            assert calls.count(("copy", "launch")) == 3
+            assert calls.count(("copy", "finish")) == fetched
+            assert sorted(bitmap) == ([0] if fetched else [])
+            assert first.shape == last.shape and first.shape[0] == len(kinds)
+
+
+def _flagged_bits() -> dict:
+    from cedar_tpu.server import metrics
+
+    with metrics.flagged_bits_total._lock:
+        return {
+            by: metrics.flagged_bits_total._values.get(
+                (("path", "authorization"), ("by", by)), 0.0
+            )
+            for by in ("readback", "word_cache", "second_call")
+        }
+
+
+def _multi_sar(i: int, ns: str) -> bytes:
+    """sam, in viewers, gets pods: two permits of SAR_POLICIES decide."""
+    return json.dumps({
+        "apiVersion": "authorization.k8s.io/v1",
+        "kind": "SubjectAccessReview",
+        "spec": {
+            "user": "sam", "uid": "u", "groups": ["viewers"],
+            "resourceAttributes": {
+                "verb": "get", "version": "v1", "resource": "pods",
+                "namespace": ns, "name": f"pod-{i}",
+            },
+        },
+    }).encode()
+
+
+@needs_native
+def test_the_counter_says_how_each_flagged_rows_bits_came_home():
+    """cedar_flagged_bits_total{path,by}: `readback` for the rows the
+    launch's buffer carried, `second_call` for the rows past the 128 it
+    holds, `word_cache` for such a row whose feature bytes were resolved
+    before; a batch with no flagged row counts nothing."""
+    from cedar_tpu.ops.match import BITS_TOPK
+
+    _engine, _stores, _auth, fast = _sar_stack(SAR_POLICIES)
+    clean = [_sar_body(i * 11 + 3) for i in range(5)]  # forbids on nodes
+    before = _flagged_bits()
+    fast.authorize_raw(clean)
+    assert _flagged_bits() == before
+
+    def moved():
+        now = _flagged_bits()
+        return {by: int(now[by] - before[by]) for by in now}
+
+    # a lone flagged request, then a mixed small batch: all readback
+    (res,) = fast.authorize_raw([_multi_sar(0, "solo")])
+    assert res[0] == "allow" and len(json.loads(res[1])["reasons"]) == 2
+    assert moved() == {"readback": 1, "word_cache": 0, "second_call": 0}
+    fast.authorize_raw([_multi_sar(i, "small") for i in range(3)] + clean)
+    assert moved() == {"readback": 4, "word_cache": 0, "second_call": 0}
+
+    # more flagged rows than a launch's buffer holds: the two past it
+    # (one feature row, so one standalone fetch) are second_call rows
+    over = [_multi_sar(0, "over")] * (BITS_TOPK + 2)
+    first = fast.authorize_raw(over)
+    assert moved() == {
+        "readback": 4 + BITS_TOPK, "word_cache": 0, "second_call": 2
+    }
+    # the same batch again: the overflow rows' feature bytes are in the
+    # word cache now
+    again = fast.authorize_raw(over)
+    assert moved() == {
+        "readback": 4 + 2 * BITS_TOPK, "word_cache": 2, "second_call": 2
+    }
+    assert _sar_bytes(first) == _sar_bytes(again)
+    assert len({b for b in _sar_bytes(first)}) == 1
+
+
 class _StubStages:
     """Controllable stages for batcher-semantics tests: encode tags, the
     dispatch stage sleeps (simulating in-flight device work), decode
