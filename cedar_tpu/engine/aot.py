@@ -67,6 +67,10 @@ import threading
 import warnings
 from typing import Callable, Optional, Sequence, Tuple
 
+import numpy as np
+
+from ..obs.trace import note_launch, profiler_scope
+
 log = logging.getLogger("cedar_tpu.aot")
 
 _MAGIC = b"CDRAOT1\n"
@@ -332,6 +336,18 @@ def _compile_and_export(name, key, meta, jit_fn, args) -> Optional[Callable]:
     return compiled
 
 
+# an argument the jitted call has to send up on its own: a host array or
+# a numpy scalar (what is already on the device is a jax.Array), told by
+# its type — a set lookup, half an isinstance's cost on a launch's 15
+_HOST = frozenset([np.ndarray, *np.sctypeDict.values()])
+
+
+# the jitted call alone — the runtime's uploads, its execute enqueue, the
+# call's return — is ``cedar.dispatch.call`` on a running profiler's clock,
+# inside the caller's ``cedar.dispatch.launch``
+_CALL = "cedar.dispatch.call"
+
+
 def dispatch(
     name: str,
     jit_fn: Callable,
@@ -342,9 +358,19 @@ def dispatch(
 
     ``name`` identifies the entry-point family (a STATICS key or any
     distinct label); ``static_argnums`` are the positions jax.jit treats
-    as static. Disabled cache = straight passthrough."""
+    as static. Disabled cache = straight passthrough. The host arrays
+    among ``args`` and their bytes go onto the calling batch's record
+    (obs.trace.note_launch: cedar_launch_uploads_total and
+    cedar_launch_upload_bytes_total)."""
+    uploads = upload_bytes = 0
+    for a in args:
+        if type(a) in _HOST:
+            uploads += 1
+            upload_bytes += a.nbytes
+    note_launch(uploads, upload_bytes)
     if not enabled():
-        return jit_fn(*args)
+        with profiler_scope(_CALL, uploads=uploads, upload_bytes=upload_bytes):
+            return jit_fn(*args)
     try:
         meta = _key_meta(name, args, static_argnums)
         key = _key(meta)
@@ -385,10 +411,13 @@ def dispatch(
                 return jit_fn(*args)
     kind, fn = hit
     if kind == "jit":
-        return jit_fn(*args)
+        with profiler_scope(_CALL, uploads=uploads, upload_bytes=upload_bytes):
+            return jit_fn(*args)
     _count("hits")
     try:
-        return fn(*_dynamic(args, static_argnums))
+        dynamic = _dynamic(args, static_argnums)
+        with profiler_scope(_CALL, uploads=uploads, upload_bytes=upload_bytes):
+            return fn(*dynamic)
     except Exception as e:  # noqa: BLE001 — a bad executable must not 500
         _count("errors")
         log.warning(

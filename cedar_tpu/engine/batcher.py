@@ -41,7 +41,7 @@ import time
 from typing import Callable, List, Optional, Sequence, TypeVar
 
 from ..chaos.registry import chaos_fire
-from ..obs.trace import batch_stage, note_batch_result
+from ..obs.trace import batch_stage, note_batch_result, profiler_on
 from ..server.supervisor import Heartbeat
 
 log = logging.getLogger(__name__)
@@ -133,6 +133,11 @@ class DeadlineExceeded(Exception):
     queued = False
 
 
+# a decode's wait for the device's result past this is counted and logged
+# (cedar_long_device_waits_total): a lone batch's is 0.4-0.5 ms on the chip
+LONG_DEVICE_WAIT_S = 0.1
+
+
 class _StageTimes:
     """Per-batch monotonic stage stamps, shared by every slot the batch
     claimed. ONE source of truth for both the request traces
@@ -149,19 +154,26 @@ class _StageTimes:
     sites in engine/evaluator.py and engine/fastpath.py while this record
     is bound to the worker's thread (``part`` / ``part_t0``: the running
     segment); a stage's parts sum to its window. ``lingered``: the claim
-    slept out a forming window first (_form_batch)."""
+    slept out a forming window first (_form_batch). ``seq``: the batch's
+    number in its batcher, given at the claim — every ``cedar.*``
+    annotation of the batch carries it, on whichever thread. ``launches``,
+    ``uploads``, ``upload_bytes``, ``readback_bytes``: what the dispatch's
+    launches sent up and started home (obs.trace.note_launch /
+    note_readback), counted once the batch is done."""
 
     __slots__ = (
-        "claimed", "first_enq", "lingered", "rows", "extras_max", "groups",
-        "known_groups", "sub", "part", "part_t0",
+        "claimed", "first_enq", "lingered", "seq", "rows", "extras_max",
+        "groups", "known_groups", "sub", "part", "part_t0",
+        "launches", "uploads", "upload_bytes", "readback_bytes",
         "encode0", "encode1", "dispatch0", "dispatch1",
         "decode0", "decode1", "eval0", "eval1",
     )
 
-    def __init__(self, claimed: float, lingered: bool):
+    def __init__(self, claimed: float, lingered: bool, seq: int = 0):
         self.claimed = claimed
         self.first_enq: Optional[float] = None
         self.lingered = lingered
+        self.seq = seq
         self.rows = 0
         # the widest row's set-membership extras, where a native encode ran
         # (obs.trace.note_encode_extras): `extras_max` on batch.encode,
@@ -173,6 +185,8 @@ class _StageTimes:
         self.sub: dict = {}
         self.part: Optional[str] = None
         self.part_t0 = 0.0
+        self.launches = self.uploads = self.upload_bytes = 0
+        self.readback_bytes = 0
         self.encode0 = self.encode1 = None
         self.dispatch0 = self.dispatch1 = None
         self.decode0 = self.decode1 = None
@@ -266,6 +280,8 @@ class MicroBatcher:
         # withdraws), so post-claim submitters enqueue fresh work
         self._pending: dict = {}
         self._stopped = False
+        # the number the next claimed batch gets (_StageTimes.seq)
+        self._seq = 0
         self._threads: List[threading.Thread] = []
         # worker generation: revive() bumps it, and every worker loop
         # checks its captured epoch so a superseded (dead-and-replaced, or
@@ -502,11 +518,15 @@ class MicroBatcher:
     def _record_batch_stages(self, times: "_StageTimes") -> None:
         """Publish one claimed batch's stage windows to the
         cedar_pipeline_stage_seconds histograms — same stamps the traces
-        consume; advisory like every metrics hook here."""
+        consume — and what its launches sent up and started home to the
+        cedar_launch_* counters; a decode that waited over
+        LONG_DEVICE_WAIT_S for the device's result is counted by whether a
+        profiler session is open, and logged with the batch's number.
+        Advisory like every metrics hook here."""
         if self.metrics_path is None or times is None:
             return
         try:
-            from ..server.metrics import record_pipeline_stage
+            from ..server.metrics import record_launch_io, record_pipeline_stage
 
             p = self.metrics_path
             if times.first_enq is not None:
@@ -523,6 +543,21 @@ class MicroBatcher:
                     record_pipeline_stage(p, stage, b - a)
             for stage, seconds in times.sub.items():
                 record_pipeline_stage(p, stage, seconds)
+            if times.launches:
+                record_launch_io(
+                    p, times.uploads, times.upload_bytes, times.readback_bytes
+                )
+            wait = times.sub.get("decode.device_wait", 0.0)
+            if wait > LONG_DEVICE_WAIT_S:
+                from ..server.metrics import record_long_device_wait
+
+                profiler = "on" if profiler_on() else "off"
+                record_long_device_wait(p, profiler)
+                log.warning(
+                    "long device wait: batch seq=%d (%d rows) waited %.3f s "
+                    "for its result; profiler %s",
+                    times.seq, times.rows, wait, profiler,
+                )
         except Exception:  # noqa: BLE001 — metrics must never break serving
             pass
 
@@ -621,9 +656,10 @@ class MicroBatcher:
             # snapshot. The same pass stamps the batch's shared stage
             # record (queue-wait measured from the OLDEST member — the
             # worst wait in the batch is what the claim latency cost).
-            times = (
-                _StageTimes(time.monotonic(), window > 0) if batch else None
-            )
+            times = None
+            if batch:
+                self._seq += 1
+                times = _StageTimes(time.monotonic(), window > 0, self._seq)
             for _, slot in batch:
                 slot.times = times
                 if times.first_enq is None or slot.t_enq < times.first_enq:

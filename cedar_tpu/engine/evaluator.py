@@ -62,7 +62,7 @@ from ..lang.entities import EntityMap
 from ..lang.eval import Env, Request, policy_matches
 from ..chaos.registry import chaos_fire
 from ..lang.values import EvalError
-from ..obs.trace import sub_stage
+from ..obs.trace import note_readback, sub_stage
 from ..compiler.table import encode_request_codes
 from ..ops.match import (
     CODE_DENY,
@@ -340,7 +340,8 @@ class _WordPacker:
         try:
             self._packed.copy_to_host_async()
         except AttributeError:  # non-jax array (tests)
-            pass
+            return
+        note_readback(self._packed.size * self._packed.dtype.itemsize)
 
     def view(self, part: int, m: int) -> np.ndarray:
         """Rows [0, m) of `part` as a view of the packed host buffer
@@ -1893,6 +1894,8 @@ class TPUPolicyEngine:
                     part = word_pack.add(w)
                 else:
                     w.copy_to_host_async()
+                    # size x itemsize: a jax.Array's nbytes costs 1 us
+                    note_readback(w.size * w.dtype.itemsize)
                 if f is not None:
                     f[0].copy_to_host_async()
                     f[1].copy_to_host_async()

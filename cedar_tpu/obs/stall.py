@@ -18,7 +18,8 @@ watcher (docs/observability.md "Process stalls"):
     ``interpreter_held`` (the process burnt CPU, this thread could not
     run). Counted in ``cedar_process_stalls_total{cause}`` and
     ``cedar_process_stall_seconds_total{cause}``, kept in a ring of 32
-    behind ``/debug/stalls``, and logged at WARNING;
+    behind ``/debug/stalls`` with ``profiler`` ``on`` or ``off`` (whether
+    a profiler session was open), and logged at WARNING;
   * the moment the watcher gets the interpreter back it snapshots every
     thread's Python stack (``sys._current_frames()``, under the lock like
     any Python code). A thread that kept the lock inside one long call
@@ -54,6 +55,8 @@ import threading
 import time
 from collections import deque
 from typing import Optional
+
+from .trace import profiler_on
 
 log = logging.getLogger(__name__)
 
@@ -225,6 +228,9 @@ class StallRecorder:
             "run_queue_delay_ms": round((after[1] - before[1]) * 1e3, 1),
             "involuntary_switches": after[2] - before[2],
             "major_faults": after[3] - before[3],
+            # whether a profiler session was open as it ended: the stops of
+            # seconds seen so far all fell in traced windows (PERF.md §7)
+            "profiler": "on" if profiler_on() else "off",
         }
         record_process_stall(cause, length)
         log.warning(

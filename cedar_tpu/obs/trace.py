@@ -501,6 +501,10 @@ def span_windows(windows, times=None) -> list:
                 for k, v in sub.items()
                 if k.startswith(phase + ".")
             }
+            if phase == "dispatch":
+                # the batch's number on the profiler's clock: joins a kept
+                # trace to the profile's cedar.* events of its batch
+                attrs["seq"] = times.seq
         elif phase == "encode" and getattr(times, "extras_max", None) is not None:
             attrs = {
                 "extras_max": times.extras_max,
@@ -625,21 +629,41 @@ def _annotation_cls():
     return _ANNOTATION or None
 
 
-def _annotation(name: str, rows: int):
-    """``TraceAnnotation(name, batch=rows)`` while a profiler session
-    runs, else None (one atomic load)."""
+def _annotation(name: str, times):
+    """``TraceAnnotation(name, batch=rows, seq=n)`` while a profiler
+    session runs, else None (one atomic load). ``seq`` is the batch's
+    number (engine/batcher.py ``_StageTimes``): the stages of one batch
+    on the collector's, the dispatch and the decode thread carry the same
+    one, and a batch's several launches carry ``chunk`` besides — each
+    annotation begins and ends on its own thread, and the round trip is
+    joined offline by ``seq`` (tools/xplane_stages.py ``launch``). With
+    no batch record bound (the warm ladder, bench.py, tests) ``batch`` is
+    0 and there is no ``seq``."""
     cls = _ANNOTATION or _annotation_cls()
     if cls is not None and cls.is_enabled():
-        return cls(name, batch=rows)
+        if times is None:
+            return cls(name, batch=0)
+        if name == "cedar.dispatch.launch":
+            return cls(name, batch=times.rows, seq=times.seq, chunk=times.launches)
+        return cls(name, batch=times.rows, seq=times.seq)
     return None
 
 
-def profiler_scope(name: str):
-    """A context manager that puts ``name`` on a running profiler's clock
+def profiler_on() -> bool:
+    """Whether a profiler session is open now; False where jax was never
+    imported (the stall recorder's and the long-wait counter's
+    ``profiler`` word)."""
+    cls = _ANNOTATION or _annotation_cls()
+    return cls is not None and cls.is_enabled()
+
+
+def profiler_scope(name: str, **kwargs):
+    """A context manager that puts ``name`` (and ``kwargs``, as the
+    event's stats) on a running profiler's clock
     (``jax.profiler.TraceAnnotation``); the shared no-op with no session."""
     cls = _ANNOTATION or _annotation_cls()
     if cls is not None and cls.is_enabled():
-        return cls(name)
+        return cls(name, **kwargs)
     return _NULL_CTX
 
 
@@ -670,7 +694,7 @@ class batch_stage:
     def __enter__(self):
         times = self._times
         _stage_local.times = times
-        self._scope = _annotation("cedar.batch." + self._name, times.rows)
+        self._scope = _annotation("cedar.batch." + self._name, times)
         if self._scope is not None:
             self._scope.__enter__()
         times.part = _STAGE_REST.get(self._name)
@@ -738,6 +762,28 @@ def note_encode_extras(extras_max: int, groups: int, known_groups: int) -> None:
     times.known_groups = max(times.known_groups, known_groups)
 
 
+def note_launch(uploads: int, upload_bytes: int) -> None:
+    """One launch's host arguments — each goes up on its own — and their
+    bytes (engine/aot.py ``dispatch``), onto the batch record bound to
+    this worker thread; a batch of several chunks adds up. Nothing
+    outside a batcher's dispatch stage."""
+    times = getattr(_stage_local, "times", None)
+    if times is None:
+        return
+    times.launches += 1
+    times.uploads += uploads
+    times.upload_bytes += upload_bytes
+
+
+def note_readback(nbytes: int) -> None:
+    """The bytes of a device result whose copy home a launch started
+    (engine/evaluator.py: since PR 37 one buffer a launch), onto the
+    bound batch record."""
+    times = getattr(_stage_local, "times", None)
+    if times is not None:
+        times.readback_bytes += nbytes
+
+
 def sub_stage(name: str):
     """``with sub_stage("dispatch.launch"):`` inside a batch stage — the
     enclosed seconds go to the bound batch record's ``sub[name]`` instead
@@ -747,7 +793,7 @@ def sub_stage(name: str):
     nothing is bound and, with no session, the cost is a thread-local read
     and an atomic load."""
     times = getattr(_stage_local, "times", None)
-    scope = _annotation("cedar." + name, times.rows if times is not None else 0)
+    scope = _annotation("cedar." + name, times)
     if times is None and scope is None:
         return _NULL_CTX
     return _SubStage(name, times, scope)
@@ -969,8 +1015,11 @@ __all__ = [
     "new_trace_id",
     "note_batch_result",
     "note_encode_extras",
+    "note_launch",
+    "note_readback",
     "parse_traceparent",
     "pipeline_stamps",
+    "profiler_on",
     "profiler_scope",
     "set_current",
     "set_phases",

@@ -71,6 +71,13 @@ class Counter:
         with self._lock:
             self._values[key] = self._values.get(key, 0.0) + amount
 
+    def add(self, key: Tuple, amount: float) -> None:
+        """``inc`` by a series key a caller built once (``(("path",
+        p),)`` for one label), for a once-a-batch site that updates
+        several families of the same labels."""
+        with self._lock:
+            self._values[key] = self._values.get(key, 0.0) + amount
+
     def set_total(self, value: float, **labels) -> None:
         """Mirror a total that its owner counts under a lock of its own
         (refreshed at scrape time): never mixed with ``inc`` on a series."""
@@ -1034,6 +1041,49 @@ flagged_bits_total = REGISTRY.register(
     )
 )
 
+launch_uploads_total = REGISTRY.register(
+    Counter(
+        "cedar_launch_uploads_total",
+        "Host arrays the device launches sent up, by path: each argument "
+        "of the jitted call that is not already on the device (the wire "
+        "codes, the extras, the valid-row count) goes up on its own. Over "
+        "the batches it is the transfers a launch makes before it can "
+        "execute.",
+        ["path"],
+    )
+)
+
+launch_upload_bytes_total = REGISTRY.register(
+    Counter(
+        "cedar_launch_upload_bytes_total",
+        "Bytes of the host arrays the device launches sent up, by path "
+        "(cedar_launch_uploads_total's arrays).",
+        ["path"],
+    )
+)
+
+launch_readback_bytes_total = REGISTRY.register(
+    Counter(
+        "cedar_launch_readback_bytes_total",
+        "Bytes of the device results whose copy home the launches "
+        "started, by path: one buffer a launch on the served path (the "
+        "verdict words and the flagged rows' rule bits).",
+        ["path"],
+    )
+)
+
+long_device_waits_total = REGISTRY.register(
+    Counter(
+        "cedar_long_device_waits_total",
+        "Batches whose decode waited over 100 ms for the device's result "
+        "(the decode.device_wait stage), by path and by whether a "
+        "profiler session was open as the wait was counted (profiler on "
+        "or off; off where jax was never imported). Each is logged at "
+        "WARNING with the batch's seq and seconds.",
+        ["path", "profiler"],
+    )
+)
+
 interpreter_wait_seconds = REGISTRY.register(
     Histogram(
         "cedar_interpreter_wait_seconds",
@@ -1741,6 +1791,21 @@ def record_encode_ancestors(path: str, where: str, n: int) -> None:
 def record_flagged_bits(path: str, by: str, n: int) -> None:
     if n:
         flagged_bits_total.inc(n, path=path, by=by)
+
+
+def record_launch_io(
+    path: str, uploads: int, upload_bytes: int, readback_bytes: int
+) -> None:
+    """One batch's launches: host arrays sent up, their bytes, and the
+    bytes started home."""
+    key = (("path", path),)
+    launch_uploads_total.add(key, uploads)
+    launch_upload_bytes_total.add(key, upload_bytes)
+    launch_readback_bytes_total.add(key, readback_bytes)
+
+
+def record_long_device_wait(path: str, profiler: str) -> None:
+    long_device_waits_total.inc(path=path, profiler=profiler)
 
 
 def record_interpreter_wait(late_s: float, watched_s: float) -> None:
