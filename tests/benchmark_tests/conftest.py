@@ -1,45 +1,22 @@
-"""Room in the admission rehearsal's pool of bodies (added by PR 35; no
-file that was here is edited).
+"""The native encoder, built once before the suite's workers ask for it.
 
-``test_benchmark_e2e_admission.py`` rehearses ``pss-admit.admit-lone`` on
-the CPU from a copy of the data files with the tenancy cut to 30. A closed
-loop sends from a pool of ``pool_per_s`` x (warm-up + window) distinct
-bodies and the run fails once a connection has sent them all
-(``benchmark/run.py``: "the mix's pool_per_s is too small"). The mix's 330/s
-is 1.5 x what the chip's server answers (211-218 requests/s: my chip runs,
-PR 30), but a CPU server over a tenth of the tenancy has no launch to pay
-and answered 287/s here before PR 35 and 333/s after it (sandbox runs, PR
-35: the lone request lost its forming window and a hand-off), so the
-rehearsal ran out of bodies two seconds from its end.
-``test_benchmark_e2e_rbac.py``'s ``small_root`` gives its own copy a larger
-pool for the same reason; this does the same for the admission rehearsal's
-copy, from outside, because a PR that claims a gain edits no file of the
-benchmark. The committed mix, which the chip's runs read, is untouched. A
-``benchmark`` PR should fold this into that test's ``small_root`` and size
-``admit-lone``'s pool against the chip's rate after PR 35 (PERF.md, Open
-questions).
+The benchmark's rehearsals serve through the program's native encoder, as
+do many tests beside them, and each test process builds it on first use
+when the checkout has none (``cedar_tpu/native/_build/``). Under
+``pytest -n 6`` on a fresh checkout the six workers did so at the same
+moment and raced on one temporary file: one or more of them lost, fell
+back to the Python path, and skipped their native tests
+(``native_once.py``). pytest collects ``tests/benchmark_tests`` before the
+``tests/test_*.py`` files (one directory's entries are collected in name
+order), so every worker loads this file before any module that asks for
+the encoder; the first builds it under a lock on a file, the others wait
+and find it built. Where there is no toolchain the native tests skip as
+they always did.
 """
 
-import json
+from native_once import build_native_once
 
-import pytest
-
-REHEARSAL_POOL_PER_S = 600
-
-
-@pytest.fixture(autouse=True)
-def _room_in_the_admission_rehearsals_pool(request, monkeypatch):
-    module = request.module
-    if module.__name__ != "test_benchmark_e2e_admission":
-        return
-    small_root = module.small_root
-
-    def roomy_root(tmp_path):
-        root = small_root(tmp_path)
-        mix = root / "benchmark" / "traffic" / "admit-lone.json"
-        doc = json.loads(mix.read_text())
-        doc["pool_per_s"] = doc["precompute_per_s"] = REHEARSAL_POOL_PER_S
-        mix.write_text(json.dumps(doc))
-        return root
-
-    monkeypatch.setattr(module, "small_root", roomy_root)
+try:
+    build_native_once()
+except Exception:  # noqa: BLE001 - no toolchain: the native tests skip
+    pass
